@@ -1,4 +1,4 @@
-"""The five BASELINE.md target configs, measured end to end.
+"""The five BASELINE.json target configs, measured end to end.
 
 1. README "x + 3" map_blocks on a 10-row double frame (latency config —
    measures per-call overhead, reference ``README.md:56-87``);
@@ -110,7 +110,7 @@ def config4_resnet_inference(batch: int = 32, image: int = 224,
 
 def config5_logreg_step(n: int = 262_144, d: int = 64) -> Dict:
     """One SGD step: map_blocks per-block grads + reduce_blocks combine;
-    the v5e-8 config of BASELINE.md runs the same step over the mesh."""
+    the v5e-8 config of BASELINE.json runs the same step over the mesh."""
     from tensorframes_tpu.models.logreg import LogisticRegression
 
     rng = np.random.default_rng(2)

@@ -6,7 +6,7 @@ dominated by key transfer + host sort, and compares the device-side key
 path (``max_groups=``), where keys never leave the mesh.
 
 Prints one JSON line per variant. Runs on whatever backend is live
-(8-virtual-CPU mesh for relative numbers; the real chip for BASELINE.md).
+(8-virtual-CPU mesh for relative numbers; the chip for device numbers).
 
 Run:  [JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8]
       python benchmarks/daggregate_bench.py [n_rows] [n_groups]
@@ -27,9 +27,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 def main() -> int:
     import jax
 
-    from benchmarks._platform import force_cpu_if_requested
+    from tensorframes_tpu.utils.platform import place_compile_cache
 
-    force_cpu_if_requested()
+    place_compile_cache()
 
     n_rows = int(sys.argv[1]) if len(sys.argv) > 1 else 1_000_000
     n_groups = int(sys.argv[2]) if len(sys.argv) > 2 else 100_000

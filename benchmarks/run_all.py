@@ -4,7 +4,7 @@
 counts down ~100x for a fast correctness pass (the sizes the reference's
 suites used are kept as the defaults). ``bench.py`` at the repo root stays
 the driver's single headline metric; this is the full sweep behind
-BASELINE.md.
+BASELINE.json's configs.
 """
 
 from __future__ import annotations
@@ -17,9 +17,9 @@ def main(argv=None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
     light = "--light" in argv
 
-    from ._platform import force_cpu_if_requested
+    from tensorframes_tpu.utils.platform import place_compile_cache
 
-    force_cpu_if_requested()
+    place_compile_cache()
 
     from . import baseline_configs, e2e_bench, marshal_bench
 

@@ -7,10 +7,10 @@ own subprocess (``xla_force_host_platform_device_count`` must be set
 before backend init), and reports per-mesh throughput + parallel
 efficiency vs the 1-device run.
 
-Only one real TPU chip exists in this environment, so the sweep uses the
-8-virtual-CPU mesh — it validates the SHARDING path's scaling behavior
-(the programs are the same ones a v5e-8 would run), not silicon speed;
-BASELINE.md flags it as such.
+CPU-only: the parent never touches jax, and every child forces the CPU
+backend with virtual devices. It validates the SHARDING path's scaling
+behavior (the programs are the same ones a v5e-8 would run), not silicon
+speed, and its numbers are stamped ``cpu-virtual``.
 
 Run:  python benchmarks/scaling_bench.py
 """

@@ -78,7 +78,7 @@ def main() -> Dict:
 
 
 if __name__ == "__main__":
-    from tensorframes_tpu.utils.platform import force_cpu_if_requested
+    from tensorframes_tpu.utils.platform import place_compile_cache
 
-    force_cpu_if_requested()
+    place_compile_cache()
     main()

@@ -231,7 +231,7 @@ def kmeans_native_resident(dist, init_centers: np.ndarray,
     """
     import jax
     import jax.numpy as jnp
-    from tensorframes_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from tensorframes_tpu.parallel import native_mesh
@@ -355,7 +355,7 @@ def main():
 
 
 if __name__ == "__main__":
-    from tensorframes_tpu.utils.platform import force_cpu_if_requested
+    from tensorframes_tpu.utils.platform import place_compile_cache
 
-    force_cpu_if_requested()
+    place_compile_cache()
     main()

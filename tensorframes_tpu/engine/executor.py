@@ -90,10 +90,7 @@ _backend_cpu: Optional[bool] = None
 def _backend_is_cpu() -> bool:
     global _backend_cpu
     if _backend_cpu is None:
-        try:
-            _backend_cpu = jax.default_backend() == "cpu"
-        except Exception:  # backend probe failed; assume host-only
-            _backend_cpu = True
+        _backend_cpu = jax.default_backend() == "cpu"
     return _backend_cpu
 
 

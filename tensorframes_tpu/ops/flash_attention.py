@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..utils.compat import shape_dtype_struct, tpu_compiler_params
 from ._pallas_mesh import interpret_blocked_by_vma, vma_union
 
 __all__ = ["flash_attention"]
@@ -128,7 +127,7 @@ def _pallas_attention(q, k, v, *, causal: bool, scale: float,
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=shape_dtype_struct(
+        out_shape=jax.ShapeDtypeStruct(
             (bh, qp.shape[1], d), q.dtype,
             # shard_map(check_vma=True) requires declaring the mesh axes the
             # output varies over — the attention output varies like q/k/v
@@ -138,7 +137,7 @@ def _pallas_attention(q, k, v, *, causal: bool, scale: float,
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # l
             pltpu.VMEM((block_q, d), jnp.float32),       # acc
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
     )(qp, kp, vp)

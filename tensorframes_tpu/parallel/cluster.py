@@ -35,7 +35,6 @@ from ..resilience import (ClusterInitError, DeadlineExceeded, deadline,
                           default_policy, env_bool, env_float, faults,
                           is_transient, remaining_time)
 from ..schema import Schema
-from ..utils.compat import distributed_is_initialized
 from ..utils.logging import get_logger
 from ..utils.tracing import counters
 from .distributed import DistributedFrame
@@ -84,7 +83,7 @@ def initialize(coordinator_address: Optional[str] = None,
     """
     import os
 
-    if distributed_is_initialized():  # already up
+    if jax.distributed.is_initialized():  # already up
         return jax.process_count() > 1
 
     coordinator_address = coordinator_address or os.environ.get(
@@ -130,7 +129,7 @@ def initialize(coordinator_address: Optional[str] = None,
 
     def attempt() -> None:
         faults.check("cluster_init")
-        if distributed_is_initialized():
+        if jax.distributed.is_initialized():
             return  # a slow earlier attempt won the race after all
         left = remaining_time()
         if coordinator_address is not None and process_id not in (None, 0):

@@ -32,7 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from ..utils.compat import shard_map
+from jax import shard_map
 
 from .. import dtypes as _dt
 from .. import memory as _memory

@@ -58,7 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from ..utils.compat import shard_map
+from jax import shard_map
 from .. import memory as _memory
 from ..engine import ops as _ops
 from ..frame import TensorFrame

@@ -89,7 +89,7 @@ def executor_for(mesh) -> Optional["NativeMeshExecutor"]:
     runtime, by construction, not omission). The native client needs at least as many
     devices as the mesh: ``TFT_PJRT_MESH_BACKEND`` overrides the spec;
     by default a ``cpu`` backend is widened to ``cpu:<n_devices>`` and a
-    plugin backend is used as-is (its device count is the grant's).
+    plugin backend is used as-is (its device count is the plugin's).
     """
     global _unavailable_logged
     if os.environ.get("TFT_EXECUTOR") != "pjrt":
@@ -471,7 +471,7 @@ class NativeMeshExecutor:
         + combiners + shapes). Outputs are replicated (one numpy array
         per reduced column).
         """
-        from ..utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         mesh = dist.mesh

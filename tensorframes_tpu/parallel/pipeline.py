@@ -22,7 +22,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from ..utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .mesh import DeviceMesh

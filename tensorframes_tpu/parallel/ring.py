@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from ..utils import compat as _compat
-from ..utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from .mesh import DeviceMesh
@@ -62,8 +62,6 @@ def _varying(a, *axes: Optional[str]):
     both). A carry must be cast over EVERY axis its updates vary on — e.g.
     ring attention's (m, l, o) vary over the batch/head axes too as soon
     as they combine with the sharded q block."""
-    if not hasattr(jax.lax, "pcast"):
-        return a
     have = _compat.vma_of(a)
     need = tuple(ax for ax in axes if ax is not None and ax not in have)
     if not need:

@@ -64,7 +64,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..observability import flight as _flight
 from ..observability.events import add_event, current_trace, traced_query
-from ..utils.compat import shard_map
+from jax import shard_map
 from ..utils.logging import get_logger
 from ..utils.tracing import counters, span
 from .adaptive import record_stream_feedback, stream_feedback

@@ -23,6 +23,7 @@ from typing import List, NamedTuple, Optional
 import numpy as np
 
 from ..utils.logging import get_logger
+from ..utils.tracing import counters
 
 __all__ = ["Atom", "extract_atoms", "refutes"]
 
@@ -83,6 +84,9 @@ def extract_atoms(comp) -> List[Atom]:
     try:
         atoms = _extract(comp)
     except Exception as e:  # noqa: BLE001 - unextractable means unpushed
+        # counted: a jax API drift that breaks extraction must fail a
+        # test (tests/test_plan.py), not quietly switch pushdown off
+        counters.inc("plan.predicate_extract_failures")
         _log.debug("predicate extraction failed (%s: %s); no pushdown",
                    type(e).__name__, e)
         atoms = []
@@ -95,6 +99,7 @@ def extract_atoms(comp) -> List[Atom]:
 
 def _extract(comp) -> List[Atom]:
     import jax
+    from jax.extend.core import Literal
 
     from .. import dtypes as _dt
 
@@ -112,7 +117,6 @@ def _extract(comp) -> List[Atom]:
         src[v] = ("col", name)
 
     def resolve(v):
-        from jax.core import Literal
         if isinstance(v, Literal):
             lit = _literal_scalar(v.val)
             return ("lit", lit) if lit is not None else None

@@ -1,112 +1,32 @@
-"""Cross-version jax compatibility shims.
+"""The jax symbols the package reaches through one place.
 
-The package tracks a moving jax API surface: ``shard_map`` graduated from
-``jax.experimental`` to a top-level export, avals grew ``vma``
-(varying-manual-axes) tracking, ``jax.typeof`` appeared, and
-``ShapeDtypeStruct`` learned a ``vma=`` parameter. Everything
-version-sensitive is probed ONCE here; the rest of the package imports
-the symbols instead of sniffing jax inline.
+Pinned to the installed jax (0.9.0): no version probes. The StableHLO
+artifact serializers are private to jax, so their import path lives here
+only; ``vma_of`` is a small idiom several modules share.
 """
 
 from __future__ import annotations
 
-import inspect
 from typing import FrozenSet
 
 import jax
 
-__all__ = ["shard_map", "typeof", "vma_of", "shape_dtype_struct",
-           "tpu_compiler_params", "HAS_VMA"]
-
-try:  # jax >= 0.6: top-level export
-    from jax import shard_map as shard_map  # type: ignore[attr-defined]
-except ImportError:  # older jax: experimental home
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-
-def typeof(a):
-    """``jax.typeof`` where it exists, else the abstract value — the same
-    duck type for our purposes (shape / dtype / maybe ``vma``)."""
-    fn = getattr(jax, "typeof", None)
-    if fn is not None:
-        return fn(a)
-    return jax.core.get_aval(a)
+__all__ = ["vma_of", "serialize_stablehlo_artifact",
+           "deserialize_stablehlo_artifact"]
 
 
 def vma_of(a) -> FrozenSet[str]:
-    """The mesh axes ``a`` varies over, empty on jax builds without vma
-    tracking (where shard_map's rep checker has no such concept)."""
-    return frozenset(getattr(typeof(a), "vma", None) or ())
-
-
-try:
-    _SDS_HAS_VMA = "vma" in inspect.signature(
-        jax.ShapeDtypeStruct.__init__).parameters
-except (ValueError, TypeError):  # pragma: no cover - C-impl signature
-    _SDS_HAS_VMA = True
-
-# True when this jax tracks varying-manual-axes through shard_map (and so
-# pallas out_shapes must declare them); vma-specific code paths and tests
-# gate on this.
-HAS_VMA = _SDS_HAS_VMA and hasattr(jax, "typeof")
-
-
-def shape_dtype_struct(shape, dtype, vma=None):
-    """``jax.ShapeDtypeStruct`` that forwards ``vma=`` only where the
-    running jax accepts it (older builds have no vma to declare)."""
-    if _SDS_HAS_VMA and vma is not None:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-    return jax.ShapeDtypeStruct(shape, dtype)
-
-
-def distributed_is_initialized() -> bool:
-    """``jax.distributed.is_initialized()`` where it exists; older jax
-    exposes the same fact as the private distributed state's client."""
-    fn = getattr(jax.distributed, "is_initialized", None)
-    if fn is not None:
-        return bool(fn())
-    from jax._src.distributed import global_state
-    return getattr(global_state, "client", None) is not None
+    """The mesh axes ``a`` varies over (empty outside ``shard_map``)."""
+    return frozenset(getattr(jax.typeof(a), "vma", None) or ())
 
 
 def serialize_stablehlo_artifact(module, version) -> bytes:
-    """MLIR text/bytecode → portable StableHLO artifact, across the move
-    of ``serialize_portable_artifact`` from the stablehlo dialect module
-    into jax's private ``_jax`` extension."""
-    try:
-        from jax._src.lib import _jax as _jaxlib
-        return _jaxlib.mlir.serialize_portable_artifact(module, version)
-    except ImportError:
-        from jaxlib.mlir.dialects import stablehlo as _sh
-        if isinstance(module, bytes):
-            module = module.decode()
-        return _sh.serialize_portable_artifact_str(module, version)
+    """MLIR text/bytecode → portable StableHLO artifact."""
+    from jax._src.lib import _jax as _jaxlib
+    return _jaxlib.mlir.serialize_portable_artifact(module, version)
 
 
 def deserialize_stablehlo_artifact(bytecode: bytes):
-    """Portable StableHLO artifact → MLIR text, across the same API move
-    as :func:`serialize_stablehlo_artifact`."""
-    try:
-        from jax._src.lib import _jax as _jaxlib
-        return _jaxlib.mlir.deserialize_portable_artifact(bytecode)
-    except ImportError:
-        # the older binding returns a parsed module (its _str sibling
-        # returns raw MLIR bytecode, not text)
-        from jaxlib.mlir import ir
-        from jaxlib.mlir.dialects import stablehlo as _sh
-
-        with ir.Context() as ctx:
-            ctx.allow_unregistered_dialects = True
-            module = _sh.deserialize_portable_artifact(ctx, bytecode)
-            return str(module)
-
-
-def tpu_compiler_params(**kwargs):
-    """Pallas TPU compiler params across the ``TPUCompilerParams`` →
-    ``CompilerParams`` rename."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    """Portable StableHLO artifact → MLIR text."""
+    from jax._src.lib import _jax as _jaxlib
+    return _jaxlib.mlir.deserialize_portable_artifact(bytecode)

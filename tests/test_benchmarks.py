@@ -70,19 +70,3 @@ def test_tpu_pallas_smoke_fails_gracefully_off_chip():
     out = proc.stdout.strip().splitlines()
     assert out and json.loads(out[-1]).get("ok") is False
     assert proc.returncode == 1
-
-
-def test_tpu_native_smoke_runs_on_cpu():
-    # the native-core smoke runs off-chip too (cpu backend for both the
-    # jax path and the C++ core), exiting 0 with parity
-    from tensorframes_tpu import native_pjrt
-
-    if not native_pjrt.available():
-        pytest.skip("libtfrpjrt.so unavailable (no TF C++ libs)")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(REPO, "benchmarks",
-                                      "tpu_native_smoke.py")],
-        capture_output=True, text=True, timeout=500, env=_CPU_ENV)
-    assert proc.returncode == 0, (proc.stdout[-500:], proc.stderr[-1000:])
-    rec = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert rec["ok"] is True and rec["native_platform"] == "cpu"

@@ -11,7 +11,6 @@ import numpy as np
 import pytest
 
 from tensorframes_tpu.ops import flash_attention, segment_sum
-from tensorframes_tpu.utils.compat import HAS_VMA
 
 
 def _qkv(rng, b=2, s=64, h=2, d=16, dtype=jnp.float32):
@@ -143,9 +142,6 @@ class TestSegmentSum:
             segment_sum(vals, ids, 1, impl="bogus")
 
 
-@pytest.mark.skipif(
-    not HAS_VMA,
-    reason="this jax has no vma tracking (no jax.shard_map check_vma)")
 class TestShardMapVma:
     """Pallas kernels inside shard_map(check_vma=True).
 

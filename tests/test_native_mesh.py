@@ -351,7 +351,7 @@ class TestResidentLoop:
 
     def test_loop_matches_per_call_dispatch(self, mesh4, pjrt_routing):
         import jax.numpy as jnp
-        from tensorframes_tpu.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         ex = _executor(mesh4)
@@ -386,7 +386,7 @@ class TestResidentLoop:
 
     def test_loop_multi_arg_mixed_dtypes(self, mesh4, pjrt_routing):
         # two-state loop (f64 vector + i32 counter), both resident
-        from tensorframes_tpu.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         ex = _executor(mesh4)
@@ -409,7 +409,7 @@ class TestResidentLoop:
         np.testing.assert_array_equal(outs[1], np.full(8, 3, np.int32))
 
     def test_loop_rejects_signature_mismatch(self, mesh4, pjrt_routing):
-        from tensorframes_tpu.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         ex = _executor(mesh4)

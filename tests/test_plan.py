@@ -553,3 +553,40 @@ class TestParquetPruning:
         blocks = df.blocks()
         assert set(blocks[0].columns) == set(cols)
         assert df.num_partitions == 4
+
+
+class TestPredicateExtraction:
+    """Atom extraction must not fail silently: a jax API drift once
+    turned every filter into "no atoms" and switched pushdown off."""
+
+    def test_plain_filter_extracts_without_failure(self):
+        from tensorframes_tpu.engine.ops import cached_map_computation
+        from tensorframes_tpu.plan.predicates import extract_atoms
+
+        df = tft.frame({"x": np.arange(8.0)})
+        comp = cached_map_computation(lambda x: {"keep": x > 3.0},
+                                      df.schema, block_level=True)
+        before = counters.snapshot().get("plan.predicate_extract_failures",
+                                         0)
+        atoms = extract_atoms(comp)
+        assert [(a.column, a.op, a.value) for a in atoms] == \
+            [("x", "gt", 3.0)]
+        assert counters.snapshot().get("plan.predicate_extract_failures",
+                                       0) == before
+
+    def test_extraction_failure_is_counted(self, monkeypatch):
+        from tensorframes_tpu.engine.ops import cached_map_computation
+        from tensorframes_tpu.plan import predicates
+
+        def broken(comp):
+            raise ImportError("symbol removed from jax")
+
+        monkeypatch.setattr(predicates, "_extract", broken)
+        df = tft.frame({"x": np.arange(8.0)})
+        comp = cached_map_computation(lambda x: {"keep": x > 4.0},
+                                      df.schema, block_level=True)
+        before = counters.snapshot().get("plan.predicate_extract_failures",
+                                         0)
+        assert predicates.extract_atoms(comp) == []
+        assert counters.snapshot()["plan.predicate_extract_failures"] == \
+            before + 1
