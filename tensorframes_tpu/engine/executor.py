@@ -33,8 +33,8 @@ from ..observability.events import current_trace as _obs_current_trace
 from ..resilience import (default_policy, env_bool, faults, is_oom,
                           is_permanent)
 from ..utils.logging import get_logger
-from ..utils.tracing import (counters, enabled as _tracing_enabled,
-                             histograms, span)
+from ..utils.tracing import (begin, counters, enabled as _tracing_enabled,
+                             end_after, histograms, span)
 
 __all__ = ["BlockExecutor", "PaddingExecutor", "PendingBlock",
            "default_executor", "default_padding_executor",
@@ -242,10 +242,37 @@ def _timed_first_dispatch(fn, dev_arrays):
     ``compile_seconds`` histogram and, when a query trace listens, a
     ``compile`` event."""
     t0 = time.perf_counter()
-    out = fn(dev_arrays)
+    with span("compile.executor"):
+        out = fn(dev_arrays)
     dt = time.perf_counter() - t0
     histograms.observe("compile_seconds", dt, engine="jax")
     _obs_event("compile", name="jax", dur=dt, engine="jax")
+    return out
+
+
+def _upload(dev_arrays: Mapping) -> Mapping:
+    """With tracing on, the host-to-device half of a dispatch made
+    explicit: one ``jax.device_put`` of the host arrays among the inputs
+    (device arrays pass through) under an ``executor.h2d`` span
+    (``attrs["bytes"]``) that runs from the put until its bytes are on
+    the device. A waiter thread ends it, so the dispatching
+    thread never waits for the transfer and a pipelined stream keeps its
+    upload/compute overlap. A dispatch that donates the inputs consumes
+    them; the span then ends when the waiter finds them gone.
+
+    With tracing off the inputs go to the dispatch as they are, and the
+    jitted call uploads them: on one v5e the explicit put cost an
+    offline ResNet-50 pass about 1% of its images per second."""
+    sp = begin("executor.h2d")
+    if sp is None:
+        return dev_arrays
+    host = [k for k, a in dev_arrays.items()
+            if not isinstance(a, jax.Array)]
+    put = jax.device_put([dev_arrays[k] for k in host])
+    out = dict(dev_arrays)
+    out.update(zip(host, put))
+    sp.attrs = {"bytes": sum(int(a.nbytes) for a in put)}
+    end_after(sp, lambda: jax.block_until_ready(put))
     return out
 
 
@@ -535,11 +562,14 @@ class BlockExecutor:
             fn, fresh = self._compiled(comp, sig, donate=donate)
             faults.check("dispatch")
             faults.check("oom")
+            # each attempt uploads anew: a donating dispatch consumes
+            # its inputs
+            inputs = _upload(dev_arrays)
             with span("executor.dispatch"):
                 if fresh:
-                    out = _timed_first_dispatch(fn, dev_arrays)
+                    out = _timed_first_dispatch(fn, inputs)
                 else:
-                    out = fn(dev_arrays)
+                    out = fn(inputs)
                 # JAX dispatch is async: an execution failure would
                 # otherwise surface at convert_back's np.asarray, OUTSIDE
                 # this retry and the OOM-split handlers (it also keeps
@@ -731,6 +761,7 @@ class BlockExecutor:
                                        donate=donate)
             faults.check("dispatch")
             faults.check("oom")
+            dev_arrays = _upload(dev_arrays)
             with span("executor.dispatch_async"):
                 # a fresh signature compiles synchronously inside this
                 # call even on the async path — worth attributing

@@ -562,11 +562,16 @@ def query_trace(op: str, **meta) -> Iterator[Optional[QueryTrace]]:
         return
     t = QueryTrace(op, meta)
     token = _current.set(t)
-    try:  # HBM watermark at query start (None fallback on CPU)
-        from . import device as _device
-        _device.sample(t, "query_start", per_device=True)
-    except Exception as e:
-        _log.debug("query-start memory sample failed: %s", e)
+    # the spans of this forcing carry its id, unless it runs for a query
+    # that already has one (the serve scheduler's)
+    qtoken = (tracing.set_query(t.query_id)
+              if tracing.current_query() is None else None)
+    with tracing.span("query_trace.open"):
+        try:  # HBM watermark at query start (None fallback on CPU)
+            from . import device as _device
+            _device.sample(t, "query_start", per_device=True)
+        except Exception as e:
+            _log.debug("query-start memory sample failed: %s", e)
     err = None
     try:
         yield t
@@ -575,7 +580,13 @@ def query_trace(op: str, **meta) -> Iterator[Optional[QueryTrace]]:
         raise
     finally:
         _current.reset(token)
-        t._finish(error=err)
+        # the close's own work (memory samples, the history append) is
+        # the trace's cost, not the query's: its span is credited to no
+        # trace
+        with tracing.span("query_trace.close"):
+            t._finish(error=err)
+        if qtoken is not None:
+            tracing.reset_query(qtoken)
 
 
 def add_event(etype: str, name: Optional[str] = None,

@@ -113,6 +113,7 @@ def _pallas_segment_sum(values, segment_ids, num_segments: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="segment_sum_onehot",       # the kernel's name in a trace
     )(ids, vals_t)
     return out.T.astype(values.dtype)
 

@@ -24,6 +24,7 @@ collectives ride ICI within a slice and DCN across slices.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from collections import OrderedDict
 from typing import Dict, Mapping, Optional
@@ -43,8 +44,8 @@ from ..schema import Schema
 from .collectives import COMBINERS
 from .mesh import DeviceMesh
 from . import elastic as _elastic
-from ..observability.events import (DEVICE_TRACK_BASE, current_trace,
-                                    traced_query)
+from ..observability.events import (DEVICE_TRACK_BASE, add_event,
+                                    current_trace, traced_query)
 from ..utils.logging import get_logger
 from ..utils.tracing import counters, span
 
@@ -1339,6 +1340,8 @@ def _dreduce_blocks_eager(fetches, dist: DistributedFrame):
         "dreduce_blocks", dist, lambda d: _generic_reduce(fetches, d))
 
 
+_NO_SPAN = contextlib.nullcontext()
+
 # Compiled collective-reduce programs, keyed by everything that shapes the
 # program (mesh, axis, column names/padded shapes/dtypes, combiners). The
 # valid-row count is a traced scalar argument, not baked in, so frames whose
@@ -1348,6 +1351,23 @@ from collections import OrderedDict
 
 _collective_cache: "OrderedDict[tuple, object]" = OrderedDict()
 _COLLECTIVE_CACHE_CAP = 64
+
+
+def _collective_program(key: tuple, build):
+    """``(fn, fresh)``: the cached jitted program under ``key``, or a new
+    one from ``build()`` (``fresh``: its first call compiles; a miss
+    counts in ``compile_cache.misses``)."""
+    fn = _collective_cache.get(key)
+    if fn is not None:
+        _collective_cache.move_to_end(key)
+        return fn, False
+    fn = _collective_cache[key] = build()
+    while len(_collective_cache) > _COLLECTIVE_CACHE_CAP:
+        _collective_cache.popitem(last=False)
+    counters.inc("compile_cache.misses")
+    add_event("compile_cache", hit=False)
+    return fn, True
+
 
 _native_mesh_warned = False
 
@@ -1433,17 +1453,9 @@ def _collective_reduce(col_combiners: Mapping[str, str],
             _native_mesh_fallback(e)
             outs = None
     if outs is None:
-        fn = _collective_cache.get(key)
-        if fn is not None:
-            _collective_cache.move_to_end(key)
-        else:
-            out_specs = tuple(P() for _ in arrays)
-            fn = jax.jit(shard_map(
-                _collective_shard_fn(names, combs, axis), mesh=mesh.mesh,
-                in_specs=in_specs, out_specs=out_specs))
-            _collective_cache[key] = fn
-            while len(_collective_cache) > _COLLECTIVE_CACHE_CAP:
-                _collective_cache.popitem(last=False)
+        fn, fresh = _collective_program(key, lambda: jax.jit(shard_map(
+            _collective_shard_fn(names, combs, axis), mesh=mesh.mesh,
+            in_specs=in_specs, out_specs=tuple(P() for _ in arrays))))
         nv_dev = jax.make_array_from_callback(
             (mesh.num_data_shards,), mesh.row_sharding(1),
             lambda idx: dist.per_shard_valid().astype(np.int32)[idx])
@@ -1454,7 +1466,8 @@ def _collective_reduce(col_combiners: Mapping[str, str],
             for name in names:
                 trace.add("collective", name=combs[name].ici, ts=t0,
                           column=name, op="dreduce_blocks")
-        with span("dreduce_blocks.collective_dispatch"):
+        with span("dreduce_blocks.collective_dispatch"), \
+                span("compile.dreduce_blocks") if fresh else _NO_SPAN:
             outs = fn(nv_dev, *arrays)
         if trace is not None:
             _trace_mesh_done(trace, list(outs), t0, "dreduce_blocks",
@@ -1477,6 +1490,11 @@ def _cached_group_ids(dist: DistributedFrame, keys, max_groups):
     ``uniques`` is None on the device path, ``uniq_dev``/``count_dev``
     are None on the host path.
     """
+    with span("daggregate.group_ids"):
+        return _group_ids(dist, keys, max_groups)
+
+
+def _group_ids(dist: DistributedFrame, keys, max_groups):
     if max_groups is not None:
         ckey = ("device", tuple(keys), max_groups)
         hit = _group_ids_cache_get(dist, ckey)
@@ -1545,7 +1563,9 @@ def _monoid_agg_shard_fn(fetch_names, col_combiners, axis,
     drift."""
     from ..ops.segment_reduce import segment_sum as _segsum
 
-    def shard_fn(ids_local, *vals_local):
+    # the compiled program is named after this function (jit_<name>):
+    # a stable name the device trace shows
+    def daggregate_shard_fn(ids_local, *vals_local):
         outs = []
         for f, v in zip(fetch_names, vals_local):
             cname = col_combiners[f]
@@ -1574,7 +1594,7 @@ def _monoid_agg_shard_fn(fetch_names, col_combiners, axis,
             outs.append(COMBINERS[cname].collective(local, axis))
         return tuple(outs)
 
-    return shard_fn
+    return daggregate_shard_fn
 
 
 def _monoid_agg_result(schema: Schema, keys, fetch_names, tables,
@@ -1953,7 +1973,7 @@ def _daggregate(fetches, dist: DistributedFrame, keys,
     pkey = ("daggregate", mesh.mesh, axis, prog_groups,
             tuple((f, col_combiners[f]) for f in fetch_names),
             tuple((a.shape, str(a.dtype)) for a in arrays))
-    tables = None
+    tables = trace = None
     # salted programs stay on the jax path: the host-side fold below is
     # the salting's second half, and the native route re-marshals anyway
     nm = None if salt_plan is not None else _native_mesh(mesh)
@@ -1977,17 +1997,10 @@ def _daggregate(fetches, dist: DistributedFrame, keys,
     if tables is None:
         # cache the jitted program (the closure is fresh per call, so
         # jax's own jit cache would miss and retrace every dispatch)
-        fn = _collective_cache.get(pkey)
-        if fn is not None:
-            _collective_cache.move_to_end(pkey)
-        else:
-            fn = jax.jit(shard_map(
-                _monoid_agg_shard_fn(fetch_names, col_combiners, axis,
-                                     prog_groups),
-                mesh=mesh.mesh, in_specs=in_specs, out_specs=out_specs))
-            _collective_cache[pkey] = fn
-            while len(_collective_cache) > _COLLECTIVE_CACHE_CAP:
-                _collective_cache.popitem(last=False)
+        fn, fresh = _collective_program(pkey, lambda: jax.jit(shard_map(
+            _monoid_agg_shard_fn(fetch_names, col_combiners, axis,
+                                 prog_groups),
+            mesh=mesh.mesh, in_specs=in_specs, out_specs=out_specs)))
         trace = current_trace()
         t0 = 0.0
         if trace is not None:
@@ -1995,24 +2008,30 @@ def _daggregate(fetches, dist: DistributedFrame, keys,
             for f in fetch_names:
                 trace.add("collective", name=COMBINERS[col_combiners[f]].ici,
                           ts=t0, column=f, op="daggregate")
-        with span("daggregate.dispatch"):
+        with span("daggregate.dispatch"), \
+                span("compile.daggregate") if fresh else _NO_SPAN:
             tables = fn(prog_ids, *arrays)
+    counters.inc("mesh.dispatches")
+
+    # the host waits here for the device (in the query trace's per-shard
+    # readiness wait when one listens, else in the table copy), then
+    # copies the tables and key columns into the result frame
+    with span("daggregate.collect"):
         if trace is not None:
             _trace_mesh_done(trace, list(tables), t0, "daggregate",
                              mesh=mesh)
-    counters.inc("mesh.dispatches")
-
-    if salt_plan is not None:
-        tables = [_elastic.fold_salted(t, salt_plan[2], col_combiners[f])
-                  for f, t in zip(fetch_names, tables)]
-    if device_keys:
-        key_cols, num_out = _device_key_columns(dist, keys, uniq_dev,
-                                                count_dev, max_groups)
-    else:
-        key_cols = {k: u for k, u in zip(keys, uniques)}
-        num_out = num_groups
-    out = _monoid_agg_result(schema, keys, fetch_names, tables,
-                             key_cols, num_out)
+        if salt_plan is not None:
+            tables = [_elastic.fold_salted(t, salt_plan[2],
+                                           col_combiners[f])
+                      for f, t in zip(fetch_names, tables)]
+        if device_keys:
+            key_cols, num_out = _device_key_columns(
+                dist, keys, uniq_dev, count_dev, max_groups)
+        else:
+            key_cols = {k: u for k, u in zip(keys, uniques)}
+            num_out = num_groups
+        out = _monoid_agg_result(schema, keys, fetch_names, tables,
+                                 key_cols, num_out)
     if salt_plan is not None:
         attach_hot_keys(out, keys, uniques, salt_plan)
     return out

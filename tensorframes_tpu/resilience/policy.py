@@ -14,7 +14,6 @@ caller's time budget.
 
 Observability contract (used by the tier-1 resilience suite):
 
-- every attempt runs inside a ``resilience.<op>.attempt`` tracing span;
 - every retry increments ``retry.<op>.retries`` in
   :data:`~..utils.tracing.counters` and logs a WARNING;
 - every giveup increments ``retry.<op>.giveups`` and logs an ERROR
@@ -38,7 +37,7 @@ from ..observability import flight as _flight
 from ..observability.events import add_event as _obs_event
 from ..observability.events import current_trace as _current_trace
 from ..utils.logging import get_logger
-from ..utils.tracing import counters, span
+from ..utils.tracing import counters
 
 __all__ = ["RetryPolicy", "DeadlineExceeded",
            "ClusterInitError", "DEFAULT_POLICY", "default_policy",
@@ -221,8 +220,7 @@ class RetryPolicy:
             for attempt in range(self.max_attempts):
                 check_deadline(op)
                 try:
-                    with span(f"resilience.{op}.attempt"):
-                        return fn()
+                    return fn()
                 except BaseException as e:  # noqa: BLE001 - reclassified
                     if not classify(e):
                         raise

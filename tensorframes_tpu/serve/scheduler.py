@@ -99,7 +99,8 @@ from ..resilience import (AdmissionDeadline, DeadlineExceeded, OverQuota,
 from ..resilience import invariants as _invariants
 from ..resilience.classify import InvariantViolation
 from ..utils.logging import get_logger
-from ..utils.tracing import counters, gauge, histograms
+from ..utils.tracing import (counters, gauge, histograms, reset_query,
+                             set_query, span)
 from .cache import SharedCompileCache
 from . import quarantine as _quarantine
 
@@ -558,6 +559,16 @@ class QueryScheduler:
         chain across workers and the resume re-dispatches only the
         blocks the dead worker never finished.
         """
+        with span("serve.submit") as sp:
+            q = self._enqueue(frame, fetches, tenant, deadline, est_rows,
+                              est_bytes, query_id, _checkpoint)
+            if sp is not None:
+                sp.query = q.query_id
+        return q
+
+    def _enqueue(self, frame, fetches, tenant, deadline, est_rows,
+                 est_bytes, query_id, _checkpoint) -> SubmittedQuery:
+        """The admission checks and the enqueue of :meth:`submit`."""
         if fetches is None:
             def thunk(frame=frame):
                 frame.blocks()
@@ -807,10 +818,15 @@ class QueryScheduler:
         # the cost capture rides INSIDE the flight scope: the sentinel's
         # regression record correlates to the same query id, and a
         # preempted run that requeues discards its partial capture at
-        # this context exit (partial runs must not calibrate baselines)
-        with _flight.scope(q.query_id, worker=self.worker_id):
-            with _baseline.capture(q.query_id, tenant=q.tenant):
-                self._execute_scoped(q)
+        # this context exit (partial runs must not calibrate baselines).
+        # Every span of the run carries the query id.
+        token = set_query(q.query_id)
+        try:
+            with _flight.scope(q.query_id, worker=self.worker_id):
+                with _baseline.capture(q.query_id, tenant=q.tenant):
+                    self._execute_scoped(q)
+        finally:
+            reset_query(token)
 
     def _execute_scoped(self, q: SubmittedQuery) -> None:
         # everything inside runs under the flight-recorder correlation
@@ -836,7 +852,8 @@ class QueryScheduler:
                     f"query {q.query_id} (tenant {q.tenant!r}) spent "
                     f"{queue_wait:.3f}s queued and missed its deadline "
                     f"before starting")
-            self._admit(q)
+            with span("serve.admit"):
+                self._admit(q)
             with self._cond:
                 t.counts["admitted"] += 1
             counters.inc("serve.admitted")
@@ -867,7 +884,8 @@ class QueryScheduler:
                     tr.add("sched_start", name=q.query_id,
                            tenant=q.tenant, queue_wait_s=queue_wait,
                            resumed=q.preemptions > 0)
-                with _deadline(remaining), _preempt.activate(scope):
+                with _deadline(remaining), _preempt.activate(scope), \
+                        span("serve.run"):
                     try:
                         result = q._thunk()
                     except Exception as e:
@@ -1110,16 +1128,29 @@ class QueryScheduler:
     def _finish(self, q: SubmittedQuery, t: _Tenant,
                 result: Any = None,
                 error: Optional[BaseException] = None) -> None:
+        # the worker takes no next query until this returns: serve.finish
+        # times all of it, and each always-on hook that runs has a child
+        with span("serve.finish") as sp:
+            key = self._finish_hooks(q, t, result, error)
+            if sp is not None:
+                sp.attrs = {"outcome": key}
+
+    def _finish_hooks(self, q: SubmittedQuery, t: _Tenant, result: Any,
+                      error: Optional[BaseException]) -> str:
+        """Resolve the future, book the outcome and run the always-on
+        hooks; returns the outcome's key (``completed``, ``failed``,
+        ...)."""
         # cross-cutting audit at the query-finish quiesce point
         # (resilience/invariants.py): in strict (chaos/test) mode a
         # violation fails THIS query, classified 'invariant', instead
         # of resolving its future green over books just proven wrong
         if _invariants.enabled():
-            try:
-                _invariants.audit("serve.finish")
-            except InvariantViolation as iv:
-                if error is None:
-                    result, error = None, iv
+            with span("serve.finish.audit"):
+                try:
+                    _invariants.audit("serve.finish")
+                except InvariantViolation as iv:
+                    if error is None:
+                        result, error = None, iv
         q._complete(result=result, error=error)
         from ..memory import persist as _persist
         if _persist.enabled():
@@ -1156,9 +1187,11 @@ class QueryScheduler:
         # resilience layer's own outcomes (transient retries, OOM
         # splits, preempts, sheds) are not evidence the plan is poison
         if key == "completed":
-            _quarantine.note_success(q.fingerprint)
+            with span("serve.finish.quarantine"):
+                _quarantine.note_success(q.fingerprint)
         elif key == "failed" and outcome == "permanent":
-            _quarantine.note_failure(q.fingerprint, error)
+            with span("serve.finish.quarantine"):
+                _quarantine.note_failure(q.fingerprint, error)
         histograms.observe("query_latency_seconds", dur, op="serve",
                            tenant=t.name, outcome=outcome)
         counters.inc(f"serve.{key}")
@@ -1172,29 +1205,35 @@ class QueryScheduler:
         # a plan regression
         run_s = dur if q.started_at is None \
             else q.finished_at - q.started_at
-        vec = _baseline.finalize(latency_s=run_s, outcome=key)
-        _flight.record("serve.finish", query=q.query_id, tenant=t.name,
-                       outcome=key, latency_s=round(dur, 6))
+        with span("serve.finish.baseline"):
+            vec = _baseline.finalize(latency_s=run_s, outcome=key)
+        with span("serve.finish.flight"):
+            _flight.record("serve.finish", query=q.query_id,
+                           tenant=t.name, outcome=key,
+                           latency_s=round(dur, 6))
         # durable query history: fold this completion — cost vector,
         # flight-decision digest, worker stamp — into the on-disk
         # archive, AFTER the serve.finish record so the digest carries
         # the terminal decision too (best-effort; never raises)
-        _history.record_finish(
-            q.query_id, tenant=t.name, fingerprint=q.fingerprint,
-            outcome=key,
-            error=(f"{type(error).__name__}: {error}"
-                   if error is not None else None),
-            error_kind=outcome if error is not None else None,
-            worker=self.worker_id, cost=vec,
-            queued_s=(q.started_at - q.submitted_at
-                      if q.started_at is not None else None),
-            run_s=run_s, total_s=dur,
-            est_rows=q.est_rows, est_bytes=q.est_bytes,
-            preemptions=q.preemptions, source="serve",
-            decisions=_flight.for_query(q.query_id))
+        with span("serve.finish.history"):
+            _history.record_finish(
+                q.query_id, tenant=t.name, fingerprint=q.fingerprint,
+                outcome=key,
+                error=(f"{type(error).__name__}: {error}"
+                       if error is not None else None),
+                error_kind=outcome if error is not None else None,
+                worker=self.worker_id, cost=vec,
+                queued_s=(q.started_at - q.submitted_at
+                          if q.started_at is not None else None),
+                run_s=run_s, total_s=dur,
+                est_rows=q.est_rows, est_bytes=q.est_bytes,
+                preemptions=q.preemptions, source="serve",
+                decisions=_flight.for_query(q.query_id))
         # SLO burn-rate callbacks evaluate off the completion path
         # (throttled per tenant; docs/observability.md)
-        _slo.note_completion(t.name)
+        with span("serve.finish.slo"):
+            _slo.note_completion(t.name)
+        return key
 
     def request_park_all(self, reason: str = "drain") -> int:
         """Ask every RUNNING query to park at its next block boundary

@@ -8,7 +8,12 @@ replacement is real instrumentation:
  - :func:`span` — a context manager timing a named stage on the host AND
    annotating it into the XLA device trace via
    ``jax.profiler.TraceAnnotation``, so host stages line up with device ops
-   in the profiler UI;
+   in the profiler UI; :func:`begin` opens a span that another thread
+   may end (an upload whose completion a waiter observes);
+ - :class:`Span` records — every span, once ended, is kept in a bounded
+   in-memory buffer (:func:`span_records`) with its start and end on
+   ``time.monotonic_ns()``, the enclosing span, the query it served
+   (:func:`set_query`), its thread and optional attributes;
  - :class:`Timings` — a process-wide registry of per-stage statistics
    (count / total / min / max seconds) plus dimensionless gauges, the
    structured replacement for the reference's log-line narration; the
@@ -17,9 +22,9 @@ replacement is real instrumentation:
  - :func:`profile` — wraps ``jax.profiler.start_trace/stop_trace`` for a
    whole-program device trace dump viewable in TensorBoard/XProf.
 
-All hooks are zero-cost-when-off: ``span`` skips stat collection and device
-annotation unless tracing is enabled (it is during :func:`profile`, under
-``TFT_TRACE=1``, or after :func:`enable`).
+All hooks are zero-cost-when-off: ``span`` is one flag check — no
+allocation, no clock read — unless tracing is enabled (it is during
+:func:`profile`, under ``TFT_TRACE=1``, or after :func:`enable`).
 
 Per-QUERY attribution (which query's block 17, which query's retry) lives
 one layer up in :mod:`tensorframes_tpu.observability`, which registers a
@@ -30,16 +35,22 @@ credited to the active query trace.
 from __future__ import annotations
 
 import contextlib
+import contextvars
+import itertools
 import os
+import queue
 import threading
 import time
-from typing import Callable, Dict, Iterator, Optional
+from collections import deque
+from typing import Any, Callable, Dict, Iterator, List, Optional
 
 from .logging import get_logger
 
 __all__ = ["Timings", "timings", "Counters", "counters", "Histograms",
-           "histograms", "span", "gauge", "enable", "disable", "enabled",
-           "profile", "dump_stats", "set_span_observer"]
+           "histograms", "Span", "span", "begin", "end_after", "join_waits",
+           "span_records", "set_query", "reset_query", "current_query",
+           "gauge", "enable", "disable", "enabled", "profile",
+           "dump_stats", "set_span_observer"]
 
 _log = get_logger("utils.tracing")
 
@@ -140,9 +151,12 @@ class Timings:
         return out
 
     def reset(self) -> None:
+        """Clear the stage statistics, the gauges and the span records."""
         with self._lock:
             self._stats.clear()
             self._gauges.clear()
+        with _records_lock:
+            _records.clear()
 
     def report(self, include_counters: bool = True) -> str:
         """One merged human-readable report: spans, gauges, and (by
@@ -349,41 +363,210 @@ def _device_annotation(name: str):
         return contextlib.nullcontext()
 
 
-@contextlib.contextmanager
-def span(name: str) -> Iterator[None]:
-    """Time a named stage; no-op (two dict lookups) when tracing is off.
+class Span:
+    """One span: a timer while its work runs, then the record of it.
 
-    Monotonic-safe against the device annotation: a trace-annotation
-    context that fails on entry or exit (some backends raise once the
-    profiler session is torn down) can neither lose the host timing nor
-    mask the body's own exception — annotation failures are logged and
-    swallowed.
+    Fields: ``id``; ``name``; ``start_ns`` and ``end_ns`` on
+    ``time.monotonic_ns()`` (``end_ns`` is None while it runs);
+    ``parent``, the ``id`` of the span that was current where this one
+    opened (None at the top); ``query``, the query id current there
+    (:func:`set_query`; a caller that learns the id later may set it
+    before the end); ``thread``, the ident of the thread that opened it;
+    ``attrs``, a dict (bytes, rows, outcome) or None.
+
+    Used as a context manager (:func:`span`) it is the current span of
+    its body, and annotates the device trace. Opened by :func:`begin`, it
+    is neither, and any one thread may :meth:`end` it.
     """
-    if not _enabled:
-        yield
-        return
-    ann = _device_annotation(name)
-    try:
-        ann.__enter__()
-    except Exception as e:  # annotation is best-effort decoration
-        _log.debug("trace annotation enter failed for %s: %s", name, e)
-        ann = None
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        dt = time.perf_counter() - t0
-        timings.add(name, dt)
-        obs = _span_observer
-        if obs is not None:
-            obs(name, dt)
-        _log.trace("span %s: %.6fs", name, dt)
+
+    __slots__ = ("id", "name", "start_ns", "end_ns", "parent", "query",
+                 "thread", "attrs", "_token", "_ann")
+
+    def __init__(self, name: str, attrs: Optional[Dict[str, Any]] = None):
+        parent = _current_span.get()
+        self.id = next(_span_ids)
+        self.name = name
+        self.parent = None if parent is None else parent.id
+        self.query = _current_query.get()
+        self.thread = threading.get_ident()
+        self.attrs = attrs
+        self.end_ns: Optional[int] = None
+        self._token = self._ann = None
+        self.start_ns = time.monotonic_ns()
+
+    def __enter__(self) -> "Span":
+        # a trace-annotation context that fails on entry or exit (some
+        # backends raise once the profiler has stopped) can neither lose
+        # the host timing nor mask the body's exception
+        self._token = _current_span.set(self)
+        ann = _device_annotation(self.name)
+        try:
+            ann.__enter__()
+        except Exception as e:  # annotation is best-effort decoration
+            _log.debug("trace annotation enter failed for %s: %s",
+                       self.name, e)
+            ann = None
+        self._ann = ann
+        self.start_ns = time.monotonic_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.end()
+        try:
+            _current_span.reset(self._token)
+        except ValueError:  # exited in another context than it entered
+            pass
+        ann, self._ann, self._token = self._ann, None, None
         if ann is not None:
             try:
                 ann.__exit__(None, None, None)
             except Exception as e:
                 _log.debug("trace annotation exit failed for %s: %s",
-                           name, e)
+                           self.name, e)
+        return False
+
+    def end(self) -> None:
+        """End the span on the calling thread (a second call does
+        nothing): keep its record, add its duration to :data:`timings`
+        and call the span observer with ``(name, seconds)``."""
+        if self.end_ns is not None:
+            return
+        self.end_ns = end = time.monotonic_ns()
+        dt = (end - self.start_ns) * 1e-9
+        timings.add(self.name, dt)
+        with _records_lock:
+            full = len(_records) == _records.maxlen
+            _records.append(self)
+        if full:
+            counters.inc("trace.spans_dropped")
+        obs = _span_observer
+        if obs is not None:
+            obs(self.name, dt)
+
+    def __repr__(self):
+        return (f"Span({self.name!r}, id={self.id}, parent={self.parent}, "
+                f"query={self.query!r}, start_ns={self.start_ns}, "
+                f"end_ns={self.end_ns}, attrs={self.attrs!r})")
+
+
+class _NullSpan:
+    """What :func:`span` returns with tracing off: enters to None."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+_NULL_SPAN = _NullSpan()
+
+
+# the newest ended spans; an eviction counts trace.spans_dropped. On one
+# v5e a traced 10-s window of four tenants' served ResNet-50 queries
+# ends about 60k spans.
+_RECORDS_KEPT = 1 << 17
+_records: "deque[Span]" = deque(maxlen=_RECORDS_KEPT)
+_records_lock = threading.Lock()
+_span_ids = itertools.count(1)
+_current_span: "contextvars.ContextVar[Optional[Span]]" = \
+    contextvars.ContextVar("tft_span", default=None)
+_current_query: "contextvars.ContextVar[Optional[str]]" = \
+    contextvars.ContextVar("tft_span_query", default=None)
+
+
+def span(name: str, attrs: Optional[Dict[str, Any]] = None):
+    """Time a named stage: ``with span("executor.convert"):``.
+
+    Enters to the running :class:`Span` (to stamp ``query`` or ``attrs``
+    before it ends), or to None with tracing off, when the call is one
+    flag check: no allocation, no clock read.
+    """
+    if not _enabled:
+        return _NULL_SPAN
+    return Span(name, attrs)
+
+
+def begin(name: str, attrs: Optional[Dict[str, Any]] = None
+          ) -> Optional[Span]:
+    """Open a span whose work ends on another thread (None with tracing
+    off). It never becomes the current span; end it with
+    :meth:`Span.end` or :func:`end_after`."""
+    if not _enabled:
+        return None
+    return Span(name, attrs)
+
+
+_waits: "Optional[queue.Queue]" = None
+_waits_lock = threading.Lock()
+
+
+def end_after(sp: Span, wait: Callable[[], Any]) -> None:
+    """End ``sp`` when ``wait()`` returns, on a waiter thread, so the
+    caller never blocks on it. One daemon thread, started at the first
+    call, serves the calls in order. A ``wait`` that raises ends the span
+    when it raises."""
+    global _waits
+    q = _waits
+    if q is None:
+        with _waits_lock:
+            if _waits is None:
+                _waits = queue.Queue()
+                threading.Thread(target=_waiter, args=(_waits,),
+                                 name="tft-span-waiter",
+                                 daemon=True).start()
+            q = _waits
+    q.put((sp, wait))
+
+
+def _waiter(q: "queue.Queue") -> None:
+    while True:
+        sp, wait = q.get()
+        try:
+            wait()
+        except Exception as e:
+            _log.debug("span %s: its wait raised (%s)", sp.name, e)
+        try:
+            sp.end()
+        except Exception as e:  # the waiter must outlive an observer
+            _log.warning("span %s: end failed: %s", sp.name, e)
+        finally:
+            q.task_done()
+
+
+def join_waits() -> None:
+    """Block until every span handed to :func:`end_after` has ended."""
+    q = _waits
+    if q is not None:
+        q.join()
+
+
+def span_records(since_ns: Optional[int] = None) -> List[Span]:
+    """The kept span records, oldest first; with ``since_ns``, those that
+    ended at or after it (``time.monotonic_ns()``). The buffer keeps the
+    newest 131072 and counts each eviction in ``trace.spans_dropped``;
+    :meth:`Timings.reset` clears it."""
+    with _records_lock:
+        recs = list(_records)
+    if since_ns is None:
+        return recs
+    return [r for r in recs if r.end_ns >= since_ns]
+
+
+def set_query(query_id: Optional[str]) -> contextvars.Token:
+    """Make ``query_id`` the query that spans opened in the calling
+    context serve; returns the token :func:`reset_query` takes."""
+    return _current_query.set(query_id)
+
+
+def reset_query(token: contextvars.Token) -> None:
+    _current_query.reset(token)
+
+
+def current_query() -> Optional[str]:
+    return _current_query.get()
 
 
 def gauge(name: str, value: float) -> None:
