@@ -1229,7 +1229,8 @@ def aggregate(fetches: Fetches, grouped: GroupedFrame,
 
     - ``fetches`` is a mapping ``{column: combiner-name}`` (sum/min/max/
       prod): host key factorization + ONE segment-reduce device launch per
-      column (the Pallas one-hot-matmul kernel for float sums on TPU) —
+      column (the Pallas one-hot-matmul kernel for float and 32-bit
+      integer sums on TPU) —
       O(1) dispatches for any number of groups;
     - ``fetches`` is a computation: host-side sort-by-key (the Catalyst
       groupBy shuffle of the reference, ``DebugRowOps.scala:533-578``),

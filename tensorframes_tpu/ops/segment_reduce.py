@@ -15,9 +15,22 @@ values ``[d, N]``), so a scalar column is not padded to 128 lanes in HBM.
 Out-of-range ids (e.g. -1 pad rows) produce an all-zero one-hot column and
 contribute nothing, for free.
 
+Integer values of 32 bits or fewer are summed exactly by the same kernel.
+Each value, read as int32, is split into byte limbs: the low limbs are the
+unsigned bytes ``(w >> 8l) & 0xFF``, the top limb the arithmetic
+``w >> 8(L-1)``. Every limb and every one-hot entry is exact in bf16, so
+one bf16 pass with f32 accumulation is exact within a block while
+``block_rows * 255 < 2^24``. Each limb's partial is converted to int32,
+shifted by ``8l`` and added into an int32 output block; these operations
+wrap modulo 2^32 as the sum does, so the result equals the int32
+scatter-add bit for bit, overflow included. 64-bit integers and bools
+take ``jax.ops.segment_sum``.
+
 Route: the kernel holds the one-hot block and the whole ``[d, S]`` output
 in fast memory, so it is picked on TPU only where that footprint fits
 (:func:`pallas_fits`); larger group counts take ``jax.ops.segment_sum``.
+Each program built with the integer kernel counts
+``segment_sum.onehot_int``.
 """
 
 from __future__ import annotations
@@ -30,12 +43,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils.tracing import counters
 from ._pallas_mesh import interpret_blocked_by_vma, vma_union
 
 __all__ = ["segment_sum", "pallas_fits"]
 
 # v5e's default scoped VMEM limit; the kernel's blocks must fit inside it
 VMEM_BUDGET_BYTES = 16 << 20
+
+# the integer route's block partials stay exact in f32 while
+# block_rows * 255 < 2^24
+INT_MAX_BLOCK_ROWS = 1 << 16
 
 
 def _round_up(v: int, m: int) -> int:
@@ -59,7 +77,7 @@ def pallas_fits(num_segments: int, d: int, block_rows: int = 512) -> bool:
 
 
 def _kernel(ids_ref, vals_ref, out_ref, *, block_rows: int,
-            num_segments: int):
+            num_segments: int, limbs: int):
     step = pl.program_id(0)
 
     @pl.when(step == 0)
@@ -69,6 +87,22 @@ def _kernel(ids_ref, vals_ref, out_ref, *, block_rows: int,
     ids = ids_ref[:]                       # [1, bn] int32
     vals = vals_ref[:]                     # [d, bn]
     seg = jax.lax.broadcasted_iota(jnp.int32, (num_segments, block_rows), 0)
+    if limbs:
+        # integers: one exact bf16 pass per byte limb, summed in wrapping
+        # int32
+        onehot = (ids == seg).astype(jnp.bfloat16)           # [S, bn]
+        acc = out_ref[:]
+        for limb in range(limbs):
+            part = vals >> (8 * limb)
+            if limb < limbs - 1:
+                part = part & 0xFF
+            partial = jax.lax.dot_general(
+                part.astype(jnp.float32).astype(jnp.bfloat16), onehot,
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc = acc + (partial.astype(jnp.int32) << (8 * limb))
+        out_ref[:] = acc
+        return
     onehot = (ids == seg).astype(jnp.float32)            # [S, bn]
     partial = jax.lax.dot_general(
         vals.astype(jnp.float32), onehot,
@@ -81,10 +115,18 @@ def _kernel(ids_ref, vals_ref, out_ref, *, block_rows: int,
 def _pallas_segment_sum(values, segment_ids, num_segments: int,
                         block_rows: int, interpret: bool):
     n, d = values.shape
-    # callers guarantee floating values (segment_sum routes ints to XLA)
-    acc_dtype = jnp.float32
+    out_dtype = values.dtype
     if n == 0:
-        return jnp.zeros((num_segments, d), values.dtype)
+        return jnp.zeros((num_segments, d), out_dtype)
+    # floats accumulate in f32; integers of 32 bits or fewer are read as
+    # int32 (unsigned ones bit for bit) and summed limb by limb
+    limbs = 0
+    acc_dtype = jnp.float32
+    if jnp.issubdtype(out_dtype, jnp.integer):
+        limbs = out_dtype.itemsize
+        acc_dtype = jnp.int32
+        values = (jax.lax.bitcast_convert_type(values, jnp.int32)
+                  if limbs == 4 else values.astype(jnp.int32))
     block_rows = min(block_rows, n)
     pad = (-n) % block_rows
     vals_t = values.T                      # rows on lanes: [d, N]
@@ -99,7 +141,7 @@ def _pallas_segment_sum(values, segment_ids, num_segments: int,
     # axes it varies over; the reduction output varies wherever its inputs do
     vma = vma_union(values, segment_ids)
     kern = functools.partial(_kernel, block_rows=block_rows,
-                             num_segments=num_segments)
+                             num_segments=num_segments, limbs=limbs)
     out = pl.pallas_call(
         kern,
         grid=(nblocks,),
@@ -115,7 +157,10 @@ def _pallas_segment_sum(values, segment_ids, num_segments: int,
         interpret=interpret,
         name="segment_sum_onehot",       # the kernel's name in a trace
     )(ids, vals_t)
-    return out.T.astype(values.dtype)
+    out = out.T
+    if limbs == 4:
+        return jax.lax.bitcast_convert_type(out, out_dtype)
+    return out.astype(out_dtype)       # narrow ints wrap as their sums do
 
 
 def segment_sum(values: jax.Array, segment_ids: jax.Array,
@@ -128,7 +173,9 @@ def segment_sum(values: jax.Array, segment_ids: jax.Array,
     out-of-range ids are dropped. Returns [num_segments, ...].
 
     ``impl``: ``"pallas"`` / ``"xla"`` / ``"interpret"``; None picks Pallas
-    on TPU where :func:`pallas_fits`, else XLA.
+    on TPU where :func:`pallas_fits`, else XLA. Floats and integers of 32
+    bits or fewer take the kernel (integers exactly, with the XLA route's
+    wraparound); 64-bit integers and bools always take XLA.
     """
     if impl not in (None, "pallas", "interpret", "xla"):
         raise ValueError(f"Unknown segment_sum impl {impl!r}")
@@ -138,20 +185,27 @@ def segment_sum(values: jax.Array, segment_ids: jax.Array,
     d = 1
     for t in tail:
         d *= t
-    if not jnp.issubdtype(values.dtype, jnp.floating):
-        # the one-hot matmul accumulates in f32, which is only exact to
-        # 2^24 — integer aggregation must stay exact, so it always takes
-        # the scatter-add path
+    exact_int = (jnp.issubdtype(values.dtype, jnp.integer)
+                 and values.dtype.itemsize <= 4)
+    if not (exact_int or jnp.issubdtype(values.dtype, jnp.floating)):
+        # 64-bit integers and bools have no exact limb route: scatter-add
         if impl in ("pallas", "interpret"):
             raise ValueError(
-                f"segment_sum impl={impl!r} accumulates in f32 and is "
-                "inexact for integer values; use impl='xla'")
+                f"segment_sum impl={impl!r} sums integers of 32 bits or "
+                f"fewer exactly, not {values.dtype}; use impl='xla'")
         impl = "xla"
     elif impl is None:
         impl = ("pallas" if jax.default_backend() == "tpu"
                 and pallas_fits(num_segments, d, block_rows) else "xla")
+    if exact_int and impl != "xla" and block_rows > INT_MAX_BLOCK_ROWS:
+        raise ValueError(
+            f"segment_sum on integers takes block_rows <= "
+            f"{INT_MAX_BLOCK_ROWS} (f32 block partials are exact below "
+            f"2^24), got {block_rows}")
     if impl == "interpret" and interpret_blocked_by_vma(values, segment_ids):
         impl = "xla"  # see ops/_pallas_mesh.py: interpreter can't do vma
+    if exact_int and impl != "xla":
+        counters.inc("segment_sum.onehot_int")
     if impl == "xla":
         valid = (segment_ids >= 0) & (segment_ids < num_segments)
         shaped = jnp.where(

@@ -1841,7 +1841,8 @@ def daggregate(fetches, dist: DistributedFrame, keys,
 
     - ``fetches`` is a mapping ``{column: combiner-name}`` (sum/min/max/
       prod): one segment-reduce launch per column (the Pallas one-hot
-      matmul for float sums) + one ``psum``-family collective;
+      matmul for float sums and, exactly, for integer sums of 32 bits or
+      fewer) + one ``psum``-family collective;
     - ``fetches`` is a computation (block-level ``<col>_input`` reduce,
       the UDAF contract): per-shard sort-by-id + segmented
       ``associative_scan`` whose pair-combiner IS the user computation on

@@ -14,8 +14,8 @@ Per batch, per live window present in the batch:
 2. each fetch column reduces in ONE device dispatch through
    ``engine.ops._segment_reduce`` — the same kernels the finite
    ``aggregate`` and the mesh ``daggregate`` program dispatch (the
-   one-hot-matmul Pallas ``segment_sum`` for float sums on TPU, XLA
-   segment primitives otherwise);
+   one-hot-matmul Pallas ``segment_sum`` for float and 32-bit-or-narrower
+   integer sums on TPU, XLA segment primitives otherwise);
 3. the per-batch partial merges into the window's **device-resident
    state table** with one cached compiled merge program (scatter-set of
    the old table + scatter-combine of the partial into the key-union

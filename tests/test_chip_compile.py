@@ -56,8 +56,10 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("groups,pallas", [(1_000, True), (100_000, False)])
-def test_segment_sum_routes_and_compiles(one_chip, groups, pallas):
+@pytest.mark.parametrize("groups,pallas,dtype", [
+    (1_000, True, jnp.float32), (100_000, False, jnp.float32),
+    (1_000, True, jnp.int32)])
+def test_segment_sum_routes_and_compiles(one_chip, groups, pallas, dtype):
     # the route is segment_sum's own rule on what the kernel holds in
     # fast memory; 100k groups would not compile in minutes as a kernel
     n = 1 << 20
@@ -65,11 +67,17 @@ def test_segment_sum_routes_and_compiles(one_chip, groups, pallas):
     impl = "pallas" if pallas else "xla"
     compiled = jax.jit(
         lambda v, i: segment_sum(v, i, groups, impl=impl)).lower(
-            _spec((n, 1), jnp.float32, one_chip),
+            _spec((n, 1), dtype, one_chip),
             _spec((n,), jnp.int32, one_chip)).compile()
     assert ("tpu_custom_call" in compiled.as_text()) is pallas
     # rows ride the lanes: no [N, 1] -> [N, 128] padded copy in HBM
-    assert compiled.memory_analysis().temp_size_in_bytes < 64 * n
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 64 * n
+    if dtype == jnp.int32:
+        # the kernel splits limbs from the block it reads: no [4, N] limb
+        # copy, nor even one int32 copy of the column
+        assert temp < 4 * n
+    assert compiled.out_info.dtype == dtype
 
 
 def test_flash_attention_compiles(one_chip):

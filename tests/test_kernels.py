@@ -124,16 +124,67 @@ class TestSegmentSum:
         out = segment_sum(vals, ids, 4, impl="interpret")
         np.testing.assert_array_equal(np.asarray(out), np.zeros((4, 3)))
 
-    def test_int_values_routed_to_exact_path(self, rng):
-        vals = jnp.asarray(rng.integers(-5, 5, (40, 2)), jnp.int32)
+    @pytest.mark.parametrize("dtype", [np.int8, np.int16, np.int32,
+                                       np.uint8, np.uint16, np.uint32])
+    def test_int_matches_xla_bit_for_bit(self, rng, dtype):
+        # values over the dtype's whole range; ids -1 and >= num_segments
+        # are dropped; 1000 rows are not a multiple of the 64-row block
+        info = np.iinfo(dtype)
+        vals = jnp.asarray(rng.integers(info.min, info.max, (1000, 2),
+                                        dtype=dtype, endpoint=True))
+        ids = jnp.asarray(rng.integers(-1, 9, 1000), jnp.int32)
+        ref = segment_sum(vals, ids, 7, impl="xla")
+        out = segment_sum(vals, ids, 7, impl="interpret", block_rows=64)
+        assert out.dtype == ref.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+    @pytest.mark.parametrize("edge", ["max", "min"])
+    def test_int32_sums_wrap_as_xla_does(self, edge):
+        info = np.iinfo(np.int32)
+        w = info.max - 3 if edge == "max" else info.min + 3
+        vals = jnp.full((300,), w, jnp.int32)
+        ids = jnp.asarray(np.arange(300) % 3, jnp.int32)
+        ref = segment_sum(vals, ids, 3, impl="xla")
+        out = segment_sum(vals, ids, 3, impl="interpret", block_rows=32)
+        # 100 rows a group: the true sum lies far past int32's range
+        assert int(np.asarray(out)[0]) != 100 * w
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+    def test_64bit_ints_stay_on_xla(self, rng):
+        vals = jnp.asarray(rng.integers(-5, 5, (40, 2)), jnp.int64)
         ids = jnp.asarray(rng.integers(0, 3, 40), jnp.int32)
-        out = segment_sum(vals, ids, 3)  # default impl: ints -> scatter-add
+        out = segment_sum(vals, ids, 3)  # default impl: int64 -> scatter-add
         ref = np.zeros((3, 2), np.int64)
         np.add.at(ref, np.asarray(ids), np.asarray(vals, np.int64))
         np.testing.assert_array_equal(np.asarray(out, np.int64), ref)
-        # an explicit f32-accumulating impl on ints is an error, not silent
-        with pytest.raises(ValueError, match="inexact for integer"):
-            segment_sum(vals, ids, 3, impl="interpret", block_rows=16)
+        # the limb route holds 32 bits: a kernel impl on int64 is an error
+        for impl in ("interpret", "pallas"):
+            with pytest.raises(ValueError, match="32 bits or fewer"):
+                segment_sum(vals, ids, 3, impl=impl, block_rows=16)
+
+    def test_int_block_rows_past_exact_f32_rejected(self):
+        vals = jnp.ones((8,), jnp.int32)
+        ids = jnp.zeros((8,), jnp.int32)
+        with pytest.raises(ValueError, match="block_rows <= 65536"):
+            segment_sum(vals, ids, 2, impl="interpret", block_rows=65537)
+        out = segment_sum(vals, ids, 2, impl="interpret", block_rows=65536)
+        np.testing.assert_array_equal(np.asarray(out), [8, 0])
+
+    @pytest.mark.parametrize("dtype,counted", [(jnp.int32, True),
+                                               (jnp.float32, False),
+                                               (jnp.int64, False)])
+    def test_int_kernel_route_counted_per_program(self, dtype, counted):
+        from tensorframes_tpu.utils.tracing import counters
+
+        vals = jnp.arange(20, dtype=dtype)
+        ids = jnp.asarray(np.arange(20) % 4, jnp.int32)
+        impl = "interpret" if dtype != jnp.int64 else None
+        fn = jax.jit(lambda v, i: segment_sum(v, i, 4, impl=impl,
+                                              block_rows=8))
+        before = counters.get("segment_sum.onehot_int")
+        fn(vals, ids).block_until_ready()
+        fn(vals, ids).block_until_ready()  # cached program: no new count
+        assert counters.get("segment_sum.onehot_int") - before == counted
 
     def test_unknown_impl_rejected(self, rng):
         vals = jnp.asarray(rng.integers(-5, 5, (4, 2)), jnp.int32)
@@ -176,6 +227,23 @@ class TestShardMapVma:
             out_specs=P("shards"), check_vma=True)
         out = jax.eval_shape(sharded, vals, ids)  # raises pre-fix
         assert out.shape == (5 * 4, 3)
+
+    def test_int_segment_sum_pallas_traces_under_shard_map(self):
+        from jax.sharding import PartitionSpec as P
+
+        mesh = self._mesh(4)
+        vals = jnp.ones((32, 3), jnp.int32)
+        ids = jnp.zeros((32,), jnp.int32)
+
+        def fn(v, i):
+            return segment_sum(v, i, 5, impl="pallas", block_rows=8)
+
+        sharded = jax.shard_map(
+            fn, mesh=mesh, in_specs=(P("shards"), P("shards")),
+            out_specs=P("shards"), check_vma=True)
+        out = jax.eval_shape(sharded, vals, ids)
+        assert out.shape == (5 * 4, 3)
+        assert out.dtype == jnp.int32
 
     def test_flash_attention_pallas_traces_under_shard_map(self, rng):
         from jax.sharding import PartitionSpec as P

@@ -296,8 +296,8 @@ class TestNativeDaggregate:
 
     def test_integer_sum_exact(self, mesh4, pjrt_routing):
         # int64 sums must stay exact through the native route (the XLA
-        # scatter-add flavor is forced exactly because the Pallas one-hot
-        # matmul accumulates in f32)
+        # scatter-add flavor: the Pallas kernel's exact integer route
+        # holds 32 bits)
         rng = np.random.default_rng(35)
         k = rng.integers(0, 6, 64).astype(np.int64)
         # values near 2^53: per-key sums leave f64's exact-integer range,
