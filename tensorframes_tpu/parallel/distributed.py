@@ -1607,7 +1607,7 @@ def _monoid_agg_result(schema: Schema, keys, fetch_names, tables,
 
     cols = dict(key_cols)
     for f, t in zip(fetch_names, tables):
-        v = np.asarray(t)[:num_out]
+        v = jax.device_get(t)[:num_out]
         fld = schema[f]
         if v.dtype != fld.dtype.np_storage and fld.dtype is not _dt.bfloat16:
             v = v.astype(fld.dtype.np_storage)
@@ -1676,13 +1676,14 @@ def _device_group_ids(dist: DistributedFrame, key: str, max_groups: int,
     ``DebugRowOps.scala:533-578``); at 100k+ groups that transfer and the
     host lexsort dominate. Here the key column never leaves the mesh: a
     device sort-unique (``jnp.unique`` with a static size cap) builds the
-    group table and a ``searchsorted`` maps rows to ids — XLA inserts the
-    cross-shard gather for the sort, which IS the shuffle, on ICI.
+    group table and a ``searchsorted`` maps rows to ids; on a mesh of
+    several data shards each chip sort-uniques its own rows first and
+    only the per-chip tables cross ICI (:func:`_device_ids`).
 
     ``max_groups`` caps the static table size (XLA needs static shapes);
     ``valid`` (row-sharded bool [padded]) is built when absent so
-    composite-key callers upload it once. Returns the raw
-    ``(ids_dev, uniques_dev, count_dev, sentinel_hit)`` from
+    composite-key callers build it once. Returns the raw
+    ``(ids_dev, uniques_dev, count_dev, sentinel_hit)`` of
     :func:`_build_device_ids` — ids are ``-1`` for pad rows; cap overflow
     and the sentinel flag are the CALLER's to read back and raise on.
     """
@@ -1705,11 +1706,11 @@ def _device_group_ids(dist: DistributedFrame, key: str, max_groups: int,
     # NB: returns traced/async values incl. the sentinel flag — callers
     # read back and raise (lets the composite path dispatch every key's
     # program before the first synchronization)
-    return _build_device_ids(kcol, valid, max_groups)
+    return _device_ids(dist.mesh, kcol, valid, max_groups)
 
 
 def _sentinel_check(sentinel_hit, key: str) -> None:
-    if bool(sentinel_hit):
+    if bool(jax.device_get(sentinel_hit)):
         raise _ops.InvalidTypeError(
             f"key column {key!r} contains the dtype's max value, which the "
             f"device path reserves as its pad sentinel; use the host path "
@@ -1717,10 +1718,22 @@ def _sentinel_check(sentinel_hit, key: str) -> None:
 
 
 def _valid_dev(dist: DistributedFrame):
+    """Row-sharded bool [padded]: True where the row is real. A frame
+    whose real rows are a prefix builds it on the device (at 1e9 rows the
+    host mask takes gigabytes of index temporaries to build)."""
+    if dist.shard_valid is None:
+        return _prefix_mask(dist.padded_rows, dist.num_rows,
+                            dist.mesh.row_sharding(1))
     valid_host = dist.valid_row_mask()
     return jax.make_array_from_callback(
         (dist.padded_rows,), dist.mesh.row_sharding(1),
         lambda idx: valid_host[idx])
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2))
+def _prefix_mask(rows: int, num_valid: int, sharding):
+    return jax.lax.with_sharding_constraint(
+        jnp.arange(rows) < num_valid, sharding)
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
@@ -1735,6 +1748,83 @@ def _build_device_ids(kc, vm, max_groups: int):
     ids = jnp.searchsorted(uniq, masked).astype(jnp.int32)
     ids = jnp.where(vm, ids, -1)
     return ids, uniq, count, sentinel_hit
+
+
+def _device_ids(mesh: DeviceMesh, kc, vm, max_groups: int):
+    """What :func:`_build_device_ids` returns, for a key column sharded
+    over ``mesh``. One data shard runs that program itself. Over several,
+    XLA cannot sort along the sharded dimension and would gather the
+    whole column onto every chip, so the table is built in two levels
+    (:func:`_build_device_ids_per_chip`)."""
+    if mesh.num_data_shards == 1:
+        return _build_device_ids(kc, vm, max_groups)
+    return _build_device_ids_per_chip(mesh, kc, vm, max_groups)
+
+
+def _build_device_ids_per_chip(mesh: DeviceMesh, kc, vm, max_groups: int):
+    """:func:`_build_device_ids`, bit for bit, in two programs.
+
+    - ``device_ids_local``: each chip sort-uniques its own rows into a
+      ``[max_groups + 1]`` table padded with the sentinel;
+    - ``device_ids_merge``: the tables are all-gathered over the data
+      axis and sort-uniqued once more, and each chip ``searchsorted``s
+      its own rows against the merged table.
+
+    The merged table is the global one even when a chip's table
+    overflows: a value among the ``max_groups + 1`` smallest distinct
+    keys has fewer smaller keys on any one chip, so it is in that chip's
+    table. A chip holding more than ``max_groups`` keys thus shows as a
+    merged ``count`` above the cap, which the callers raise on.
+    """
+    axis = mesh.data_axis
+    chips = mesh.num_data_shards
+    size = max_groups + 1
+    local, merge = _per_chip_id_programs(mesh.mesh, axis, size)
+    attrs = {"chips": chips, "rows": int(kc.shape[0]),
+             "gathered_bytes": chips * size * kc.dtype.itemsize}
+    counters.inc("mesh.group_ids_two_level")
+    with span("daggregate.group_ids.local", attrs) as sp:
+        tables, counts = local(kc, vm)
+        if sp is not None:
+            attrs["local_groups_max"] = int(np.max(jax.device_get(counts)))
+    with span("daggregate.group_ids.merge", attrs) as sp:
+        out = merge(kc, vm, tables)
+        if sp is not None:
+            jax.block_until_ready(out)
+    return out
+
+
+@functools.lru_cache(maxsize=16)
+def _per_chip_id_programs(mesh, axis: str, size: int):
+    """The two jitted programs of :func:`_build_device_ids_per_chip`."""
+
+    def device_ids_local(kc, vm):
+        sentinel = jnp.iinfo(kc.dtype).max
+        table = jnp.unique(jnp.where(vm, kc, sentinel), size=size,
+                           fill_value=sentinel)
+        return table, jnp.sum(table != sentinel)[None]
+
+    def device_ids_merge(kc, vm, table):
+        sentinel = jnp.iinfo(kc.dtype).max
+        hit = jnp.any(vm & (kc == sentinel)).astype(jnp.int32)
+        sentinel_hit = jax.lax.psum(hit, axis) > 0
+        uniq = jnp.unique(jax.lax.all_gather(table, axis, tiled=True),
+                          size=size, fill_value=sentinel)
+        count = jnp.sum(uniq != sentinel)
+        ids = jnp.searchsorted(uniq, jnp.where(vm, kc, sentinel))
+        ids = jnp.where(vm, ids.astype(jnp.int32), -1)
+        return ids, uniq, count, sentinel_hit
+
+    rows = P(axis)
+    local = jax.jit(shard_map(device_ids_local, mesh=mesh,
+                              in_specs=(rows, rows), out_specs=(rows, rows)))
+    # the merged table is the same on every chip (a sort of all-gathered
+    # tables), which the replication check cannot infer through the sort
+    merge = jax.jit(shard_map(device_ids_merge, mesh=mesh,
+                              in_specs=(rows, rows, rows),
+                              out_specs=(rows, P(), P(), P()),
+                              check_vma=False))
+    return local, merge
 
 
 @functools.partial(jax.jit, static_argnums=(2,))
@@ -1782,10 +1872,11 @@ def _device_key_ids(dist: DistributedFrame, keys, max_groups: int):
     for ids_k, _, _, _ in per:
         combined = (ids_k if combined is None
                     else _combine_ids(combined, ids_k, radix))
-    ids, uniq_c, count, _ = _build_device_ids(combined, valid, max_groups)
+    ids, uniq_c, count, _ = _device_ids(dist.mesh, combined, valid,
+                                        max_groups)
     for k, (_, _, count_k, sent_k) in zip(keys, per):
         _sentinel_check(sent_k, k)
-        if int(count_k) > max_groups:
+        if int(jax.device_get(count_k)) > max_groups:
             # a truncated per-key table would silently merge distinct
             # keys before the final overflow check could see them
             raise ValueError(
@@ -1800,7 +1891,7 @@ def _device_key_columns(dist: DistributedFrame, keys, key_table,
                         count_dev, max_groups: int):
     """Overflow check + host materialization of the device group table(s).
     Returns ``({key name: values}, num_groups)``."""
-    count = int(count_dev)
+    count = int(jax.device_get(count_dev))
     if count > max_groups:
         raise ValueError(
             f"more than max_groups={max_groups} distinct keys in "
@@ -1813,15 +1904,16 @@ def _device_key_columns(dist: DistributedFrame, keys, key_table,
         return vals
 
     if key_table[0] == "single":
-        return {keys[0]: cast(np.asarray(key_table[1])[:count],
+        return {keys[0]: cast(jax.device_get(key_table[1])[:count],
                               keys[0])}, count
     _, uniq_c, per_uniq, radix = key_table
-    comb = np.asarray(uniq_c)[:count].astype(np.int64)
+    comb = jax.device_get(uniq_c)[:count].astype(np.int64)
     digits = []
     for _ in keys:                       # least-significant digit first
         digits.append(comb % radix)
         comb = comb // radix
-    return {k: cast(np.asarray(per_uniq[i])[digits[len(keys) - 1 - i]], k)
+    return {k: cast(jax.device_get(per_uniq[i])[digits[len(keys) - 1 - i]],
+                    k)
             for i, k in enumerate(keys)}, count
 
 
@@ -2009,10 +2101,15 @@ def _daggregate(fetches, dist: DistributedFrame, keys,
             for f in fetch_names:
                 trace.add("collective", name=COMBINERS[col_combiners[f]].ici,
                           ts=t0, column=f, op="daggregate")
-        with span("daggregate.dispatch"), \
+        with span("daggregate.dispatch", {"chips": mesh.num_data_shards,
+                                          "table_groups": prog_groups}), \
                 span("compile.daggregate") if fresh else _NO_SPAN:
             tables = fn(prog_ids, *arrays)
     counters.inc("mesh.dispatches")
+    if mesh.num_data_shards > 1:
+        counters.inc("mesh.allreduce_bytes", sum(
+            prog_groups * int(np.prod(a.shape[1:])) * a.dtype.itemsize
+            for a in arrays))
 
     # the host waits here for the device (in the query trace's per-shard
     # readiness wait when one listens, else in the table copy), then

@@ -8,7 +8,10 @@ every xdist worker collects the same tests and only the worker given this
 file loads libtpu.
 """
 
+import collections
+import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -19,13 +22,17 @@ import tensorframes_tpu as tft
 from tensorframes_tpu.engine.ops import cached_map_computation
 from tensorframes_tpu.ops import flash_attention, segment_sum
 from tensorframes_tpu.ops.segment_reduce import pallas_fits
+from tensorframes_tpu.parallel import distributed as D
+from tensorframes_tpu.parallel.mesh import DeviceMesh
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+HBM = 16e9
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
-    from jax.sharding import SingleDeviceSharding
 
     prev_log = os.environ.get("TPU_LOG_DIR")
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -43,7 +50,7 @@ def one_chip():
                                                 topology_name="v5e:2x2")
         except Exception as e:  # noqa: BLE001 - no libtpu: nothing to test
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-        yield SingleDeviceSharding(topo.devices[0])
+        yield topo
     finally:
         jax.config.update("jax_enable_x64", prev_x64)
         jax.config.update("jax_enable_compilation_cache", prev_cache)
@@ -52,8 +59,86 @@ def one_chip():
             os.environ.pop("TPU_LOG_DIR", None)
 
 
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
 def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _memory(name, compiled) -> dict:
+    m = compiled.memory_analysis()
+    got = {k: getattr(m, k) for k in (
+        "argument_size_in_bytes", "output_size_in_bytes",
+        "temp_size_in_bytes", "generated_code_size_in_bytes")}
+    print(f"MEMORY {name} {json.dumps(got)}")
+    return got
+
+
+def _ops(compiled) -> collections.Counter:
+    """Each (result shape, opcode) of a compiled program, counted."""
+    return collections.Counter(
+        re.findall(r"= (\S+) ([a-z][a-z0-9-]*)\(", compiled.as_text()))
+
+
+def _cell_sizes():
+    """Rows and group cap of the h2o1e9.q5_k10m_4chip cell."""
+    with open(os.path.join(ROOT, "cellbench", "configs",
+                           "h2o_groupby_g1e9_k100.json")) as f:
+        rows = json.load(f)["rows"]
+    with open(os.path.join(ROOT, "cellbench", "traffic",
+                           "q5_k10m.json")) as f:
+        return rows, json.load(f)["request"]["max_groups"]
+
+
+def test_four_chip_group_ids_and_aggregate_fit(topo, monkeypatch):
+    # 1e9 rows over a 2x2 mesh, 1e7 groups: the per-chip id build (the
+    # one-program build asks 15.8 GB of temp a chip and is refused) and the
+    # aggregate program each fit a chip's 16 GB, arguments and temp
+    from jax import shard_map
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    n, cap = _cell_sizes()
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    rows = NamedSharding(mesh, P("data"))
+    keys = _spec((n,), jnp.int32, rows)
+    valid = _spec((n,), jnp.bool_, rows)
+    tables = _spec((4 * (cap + 1),), jnp.int32, rows)
+    local, merge = D._per_chip_id_programs(mesh, "data", cap + 1)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    names = ["v1", "v2", "v3"]
+    agg = jax.jit(shard_map(
+        D._monoid_agg_shard_fn(names, {c: "sum" for c in names}, "data",
+                               cap + 1),
+        mesh=mesh, in_specs=(P("data"),) * 4,
+        out_specs=tuple(P() for _ in names)))
+    for name, fn, args in (
+            ("device_ids_local", local, (keys, valid)),
+            ("device_ids_merge", merge, (keys, valid, tables)),
+            ("aggregate", agg, (keys, keys, keys,
+                                _spec((n,), jnp.float32, rows)))):
+        compiled = fn.lower(*args).compile()
+        mem = _memory(name, compiled)
+        assert mem["argument_size_in_bytes"] + mem["temp_size_in_bytes"] < HBM
+        if name != "device_ids_local":
+            assert "all-reduce" in compiled.as_text()
+
+
+def test_one_chip_id_program_is_unchanged(topo):
+    # on a one-shard mesh the routed build compiles to the one program
+    dm = DeviceMesh(jax.sharding.Mesh(np.array(topo.devices[:1]), ("data",)))
+    n, cap = 1 << 20, 1000
+    keys = _spec((n,), jnp.int32, dm.row_sharding(1))
+    valid = _spec((n,), jnp.bool_, dm.row_sharding(1))
+    direct = D._build_device_ids.lower(keys, valid, cap).compile()
+    routed = jax.jit(lambda k, v: D._device_ids(dm, k, v, cap)).lower(
+        keys, valid).compile()
+    assert _ops(routed) == _ops(direct)
+    assert "all-reduce" not in routed.as_text()
 
 
 @pytest.mark.parametrize("groups,pallas,dtype", [
