@@ -12,7 +12,10 @@ Two facts every kernel here must honor when traced inside
   do not, so every mixed ``eq``/``add`` trips the checker. The Mosaic
   (real-TPU) path is unaffected — kernels trace with plain ref avals.
   Interpreted runs inside a mesh must therefore fall back to the kernel's
-  XLA oracle (:func:`interpret_blocked_by_vma`).
+  XLA oracle (:func:`interpret_blocked_by_vma`), or run in Pallas's TPU
+  interpreter (``interpret=pltpu.InterpretParams()``), which does replay
+  them there: ``segment_reduce.py``'s window kernel, which has no oracle
+  of its own, takes that one.
 
 Any new Pallas kernel should route through both helpers; see
 ``segment_reduce.py`` / ``flash_attention.py`` for the pattern.

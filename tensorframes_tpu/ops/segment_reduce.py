@@ -26,11 +26,36 @@ wrap modulo 2^32 as the sum does, so the result equals the int32
 scatter-add bit for bit, overflow included. 64-bit integers and bools
 take ``jax.ops.segment_sum``.
 
-Route: the kernel holds the one-hot block and the whole ``[d, S]`` output
-in fast memory, so it is picked on TPU only where that footprint fits
-(:func:`pallas_fits`); larger group counts take ``jax.ops.segment_sum``.
-Each program built with the integer kernel counts
-``segment_sum.onehot_int``.
+Three routes, by the group count:
+
+- **one-hot** (``segment_sum_onehot``): the kernel holds the one-hot block
+  and the whole ``[d, S]`` output in fast memory, so :func:`segment_sum`
+  picks it on TPU only where that footprint fits (:func:`pallas_fits`,
+  about 4,000 groups). Each program built with the integer kernel counts
+  ``segment_sum.onehot_int``.
+- **sorted window** (``segment_sum_window``, :func:`segment_sums` given a
+  ``window``): past ``pallas_fits``, one ``lax.sort`` keyed on the ids
+  carries every column into key order. A block of ``bn`` sorted rows then
+  spans a few groups from its first valid id, so the kernel sums it into
+  a ``[d, W]`` window of lanes, ``W`` a multiple of 128, one partial per
+  block; one scatter-add per column adds the ``nblocks x span``
+  partials into the table, a few entries a group in place of one a row.
+  ``bn`` follows from the rows and the group count
+  (:func:`window_block_rows`). The window must hold the largest block
+  span of the sorted ids (:func:`sorted_block_span`), which depends on
+  the ids alone, so a caller computes it once per id set
+  (``daggregate`` caches it beside its group ids, on TPU only:
+  :func:`window_wanted`). Floats and integers of 32 bits or fewer take
+  it, each as in the one-hot kernel.
+- **scatter-add** (``jax.ops.segment_sum``): off the TPU, for 64-bit
+  integers and bools, past ``pallas_fits`` without a window, and where
+  the span is wider than a window may be (:func:`window_fits`: skew, or
+  groups absent from a shard).
+
+Both kernels sum only the rows whose ids are in range, and leave the
+values that are not finite out of their one-hot dot, where 0 x NaN would
+make every group of the block NaN; a block that holds one adds NaN or
+inf to that value's own group alone, as scatter-add does.
 """
 
 from __future__ import annotations
@@ -46,7 +71,8 @@ from jax.experimental.pallas import tpu as pltpu
 from ..utils.tracing import counters
 from ._pallas_mesh import interpret_blocked_by_vma, vma_union
 
-__all__ = ["segment_sum", "pallas_fits"]
+__all__ = ["segment_sum", "segment_sums", "pallas_fits", "sorted_block_span",
+           "window_block_rows", "window_fits", "window_wanted"]
 
 # v5e's default scoped VMEM limit; the kernel's blocks must fit inside it
 VMEM_BUDGET_BYTES = 16 << 20
@@ -76,6 +102,53 @@ def pallas_fits(num_segments: int, d: int, block_rows: int = 512) -> bool:
     return _vmem_bytes(num_segments, d, block_rows) <= VMEM_BUDGET_BYTES
 
 
+def _limb_sums(vals, hit, limbs: int, acc=None):
+    """``[d, S]`` int32 sums of int32 ``vals`` ``[d, bn]`` over the
+    one-hot ``hit`` ``[S, bn]``, added to ``acc`` where given: one exact
+    bf16 pass per byte limb, summed in wrapping int32."""
+    onehot = hit.astype(jnp.bfloat16)
+    for limb in range(limbs):
+        part = vals >> (8 * limb)
+        if limb < limbs - 1:
+            part = part & 0xFF
+        partial = jax.lax.dot_general(
+            part.astype(jnp.float32).astype(jnp.bfloat16), onehot,
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        term = partial.astype(jnp.int32) << (8 * limb)
+        acc = term if acc is None else acc + term
+    return acc
+
+
+def _float_sums(vals, valid, hit):
+    """``[d, S]`` f32 sums of the ``valid`` rows of ``vals`` ``[d, bn]``
+    over the one-hot ``hit`` ``[S, bn]``.
+
+    In the one-hot dot a 0 x inf or 0 x NaN term would make every group
+    of the block NaN. So the dot takes such a value as 0, and three 0/1
+    rows a column, dotted beside the values in the same pass, count each
+    group's NaN, inf and -inf; each kind found is added to that group
+    alone, where IEEE addition gives what a sum in row order gives (inf +
+    -inf is NaN)."""
+    vals = vals.astype(jnp.float32)
+    d = vals.shape[0]
+    finite = jnp.abs(vals) < jnp.inf          # false for inf and NaN
+    kinds = ((vals != vals, jnp.nan), (vals == jnp.inf, jnp.inf),
+             (vals == -jnp.inf, -jnp.inf))
+    rows = jnp.concatenate(
+        [jnp.where(valid & finite, vals, 0.0)]
+        + [jnp.where(valid & kind, 1.0, 0.0) for kind, _ in kinds], axis=0)
+    out = jax.lax.dot_general(
+        rows, hit.astype(jnp.float32),
+        (((1,), (1,)), ((), ())),          # contract the row dim: [4d, S]
+        precision=jax.lax.Precision.HIGHEST,  # exact f32: this is an
+        preferred_element_type=jnp.float32)   # aggregation, not attention
+    sums = out[:d]
+    for k, (_, fill) in enumerate(kinds, 1):
+        sums = sums + jnp.where(out[k * d:(k + 1) * d] > 0, fill, 0.0)
+    return sums
+
+
 def _kernel(ids_ref, vals_ref, out_ref, *, block_rows: int,
             num_segments: int, limbs: int):
     step = pl.program_id(0)
@@ -87,29 +160,13 @@ def _kernel(ids_ref, vals_ref, out_ref, *, block_rows: int,
     ids = ids_ref[:]                       # [1, bn] int32
     vals = vals_ref[:]                     # [d, bn]
     seg = jax.lax.broadcasted_iota(jnp.int32, (num_segments, block_rows), 0)
+    hit = ids == seg                       # [S, bn]
     if limbs:
-        # integers: one exact bf16 pass per byte limb, summed in wrapping
-        # int32
-        onehot = (ids == seg).astype(jnp.bfloat16)           # [S, bn]
-        acc = out_ref[:]
-        for limb in range(limbs):
-            part = vals >> (8 * limb)
-            if limb < limbs - 1:
-                part = part & 0xFF
-            partial = jax.lax.dot_general(
-                part.astype(jnp.float32).astype(jnp.bfloat16), onehot,
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            acc = acc + (partial.astype(jnp.int32) << (8 * limb))
-        out_ref[:] = acc
-        return
-    onehot = (ids == seg).astype(jnp.float32)            # [S, bn]
-    partial = jax.lax.dot_general(
-        vals.astype(jnp.float32), onehot,
-        (((1,), (1,)), ((), ())),          # contract the row dim: [d, S]
-        precision=jax.lax.Precision.HIGHEST,  # exact f32: this is an
-        preferred_element_type=jnp.float32)   # aggregation, not attention
-    out_ref[:] = out_ref[:] + partial.astype(out_ref.dtype)
+        out_ref[:] = _limb_sums(vals, hit, limbs, out_ref[:])
+    else:
+        valid = (ids >= 0) & (ids < num_segments)
+        out_ref[:] = out_ref[:] + _float_sums(vals, valid, hit).astype(
+            out_ref.dtype)
 
 
 def _pallas_segment_sum(values, segment_ids, num_segments: int,
@@ -182,9 +239,7 @@ def segment_sum(values: jax.Array, segment_ids: jax.Array,
     values = jnp.asarray(values)
     segment_ids = jnp.asarray(segment_ids)
     tail = values.shape[1:]
-    d = 1
-    for t in tail:
-        d *= t
+    d = _width(values.shape)
     exact_int = (jnp.issubdtype(values.dtype, jnp.integer)
                  and values.dtype.itemsize <= 4)
     if not (exact_int or jnp.issubdtype(values.dtype, jnp.floating)):
@@ -216,3 +271,308 @@ def segment_sum(values: jax.Array, segment_ids: jax.Array,
     out = _pallas_segment_sum(flat, segment_ids, num_segments,
                               block_rows, interpret=(impl == "interpret"))
     return out.reshape((num_segments,) + tail)
+
+
+# -- the sorted-window route ------------------------------------------------
+
+# a block of key-sorted rows is sized to span about this many groups, half
+# of a 128-lane window
+WINDOW_EXPECTED_SPAN = 64
+# a 1-D block of 32-bit rows is whole (8, 128) tiles: 1,024 rows or more
+WINDOW_MIN_BLOCK_ROWS = 1024
+WINDOW_MAX_BLOCK_ROWS = 4096
+# the widest window the route takes; a larger block span takes scatter-add
+WINDOW_MAX = 512
+
+
+def window_block_rows(rows: int, num_segments: int) -> int:
+    """Rows a block of the sorted-window route holds, for ``rows`` rows
+    (one shard's) over ``num_segments`` groups: the largest power of two
+    in [1024, 4096] whose expected span, ``block_rows * num_segments /
+    rows`` groups, stays within :data:`WINDOW_EXPECTED_SPAN`."""
+    bn = WINDOW_MIN_BLOCK_ROWS
+    while (bn < WINDOW_MAX_BLOCK_ROWS
+           and 2 * bn * num_segments <= WINDOW_EXPECTED_SPAN * rows):
+        bn *= 2
+    return bn
+
+
+def _window_lanes(span: int) -> int:
+    return _round_up(max(span, 1), 128)
+
+
+def _window_vmem_bytes(span: int, d: int, block_rows: int) -> int:
+    """Fast-memory footprint of one window-kernel step, generously:
+    double-buffered id and value row blocks (each counted at 8 sublanes),
+    the values stacked, the compare mask and its f32 and bf16 one-hots
+    ``[W, bn]``, and the double-buffered ``[d, W]`` partials."""
+    w = _window_lanes(span)
+    rows = 2 * (1 + d) * 8 * block_rows * 4
+    stacked = _round_up(d, 8) * block_rows * 4
+    onehot = 3 * w * block_rows * 4
+    out = 2 * _round_up(d, 8) * w * 4
+    return rows + stacked + onehot + out
+
+
+def _window_dtype(dtype) -> Optional[str]:
+    """The window kernel's route for a column dtype: ``"int"`` (exact byte
+    limbs), ``"float"`` (f32) or None (scatter-add: 64-bit and bool)."""
+    if dtype.itemsize > 4:
+        return None
+    if jnp.issubdtype(dtype, jnp.integer):
+        return "int"
+    if jnp.issubdtype(dtype, jnp.floating):
+        return "float"
+    return None
+
+
+def window_wanted(num_segments: int, columns,
+                  backend: Optional[str] = None) -> bool:
+    """Whether the sorted-window route would sum some of ``columns``
+    (arrays or shape structs): on a TPU (``backend``, by default JAX's;
+    elsewhere the kernel runs only in the interpreter and scatter-add is
+    faster), past :func:`pallas_fits`, a column of a dtype the window
+    kernel takes."""
+    return ((backend or jax.default_backend()) == "tpu"
+            and not pallas_fits(num_segments, 1)
+            and any(_window_dtype(c.dtype) is not None for c in columns))
+
+
+def window_fits(span: int, block_rows: int, columns) -> bool:
+    """Whether the sorted-window route takes ``columns`` (arrays or shape
+    structs) whose largest block span is ``span``: the window is at most
+    :data:`WINDOW_MAX` lanes and one step's blocks fit
+    :data:`VMEM_BUDGET_BYTES`."""
+    d = {"int": 0, "float": 0}
+    for c in columns:
+        route = _window_dtype(c.dtype)
+        if route is not None:
+            d[route] += _width(c.shape)
+    return (span <= WINDOW_MAX and _window_vmem_bytes(
+        span, max(d.values()), block_rows) <= VMEM_BUDGET_BYTES)
+
+
+def _width(shape) -> int:
+    d = 1
+    for t in shape[1:]:
+        d *= t
+    return d
+
+
+def _clean_ids(segment_ids, num_segments: int):
+    """int32 ids with every id outside ``[0, num_segments)`` made -1."""
+    ids = segment_ids.astype(jnp.int32)
+    return jnp.where((ids >= 0) & (ids < num_segments), ids, -1)
+
+
+def _block_ends(sorted_ids, block_rows: int):
+    """First valid id and last id of each block of ascending ids whose
+    invalid rows (-1) lead. A block with no valid row has a negative last
+    id."""
+    n = sorted_ids.shape[0]
+    nblocks = -(-n // block_rows)
+    npad = jnp.sum(sorted_ids < 0, dtype=jnp.int32)
+    starts = jnp.arange(nblocks, dtype=jnp.int32) * block_rows
+    first = sorted_ids[jnp.minimum(jnp.maximum(starts, npad), n - 1)]
+    last = sorted_ids[jnp.minimum(starts + block_rows, n) - 1]
+    return first, last
+
+
+def sorted_block_span(segment_ids: jax.Array, num_segments: int,
+                      block_rows: int) -> jax.Array:
+    """The largest span, last id - first id + 1, of any ``block_rows``
+    block of the valid ``segment_ids`` in ascending order: the window
+    the sorted-window route needs for these ids. A scalar int32; 0 where
+    no id is valid. It depends on the ids alone."""
+    n = segment_ids.shape[0]
+    if n == 0:
+        return jnp.zeros((), jnp.int32)
+    srt = jnp.sort(_clean_ids(segment_ids, num_segments), stable=False)
+    first, last = _block_ends(srt, block_rows)
+    return jnp.max(jnp.where(last >= 0, last - first + 1, 0))
+
+
+def _window_kernel(ids_ref, *refs, rows: int, block_rows: int, lanes: int,
+                   n_int: int, n_float: int, limbs: int):
+    """Block sums of key-sorted rows into a ``lanes``-wide window that
+    starts at the block's first valid id. Refs: the sorted ids ``[bn]``,
+    ``n_int`` int32 then ``n_float`` f32 value rows ``[bn]``, then the int
+    and/or float ``[d, W]`` partials of this block. The rows are read as
+    1-D blocks, the layout the sort leaves them in, and ride the lanes."""
+    step = pl.program_id(0)
+    ids = ids_ref[:].reshape(1, block_rows)              # [1, bn] int32
+    valid = ids >= 0
+    if rows % block_rows:
+        # the last block reads past the rows: unspecified data there
+        row = step * block_rows + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_rows), 1)
+        valid = valid & (row < rows)
+    base = jnp.min(jnp.where(valid, ids, jnp.iinfo(jnp.int32).max),
+                   axis=1, keepdims=True)
+    local = jnp.where(valid, ids - base, -1)
+    seg = jax.lax.broadcasted_iota(jnp.int32, (lanes, block_rows), 0)
+    hit = local == seg                                   # [W, bn]
+    ins, outs = refs[:n_int + n_float], refs[n_int + n_float:]
+
+    def stacked(group):
+        vals = [r[:].reshape(1, block_rows) for r in group]
+        return vals[0] if len(vals) == 1 else jnp.concatenate(vals, axis=0)
+
+    if n_int:
+        # invalid rows, and garbage past the rows, have a zero one-hot
+        # column and finite limbs
+        outs[0][:] = _limb_sums(stacked(ins[:n_int]), hit, limbs)
+    if n_float:
+        # pad rows (id -1) may hold any value, NaN included
+        outs[-1][:] = _float_sums(stacked(ins[n_int:]), valid, hit)
+
+
+def _window_partials(sorted_ids, int_rows, float_rows, *, block_rows: int,
+                     lanes: int, limbs: int, interpret: bool):
+    """Per-block window partials of sorted rows by the
+    ``segment_sum_window`` kernel: ``[d, nblocks * W]`` for the int32 rows
+    and for the f32 rows (None where there are none). ``interpret`` runs
+    it in Pallas's TPU interpreter, which also runs inside a mesh."""
+    n = sorted_ids.shape[0]
+    nblocks = -(-n // block_rows)
+    args = [sorted_ids] + list(int_rows) + list(float_rows)
+    vma = vma_union(*args)
+    out_shape, out_specs = [], []
+    for rows_, dtype in ((int_rows, jnp.int32), (float_rows, jnp.float32)):
+        if rows_:
+            out_shape.append(jax.ShapeDtypeStruct(
+                (len(rows_), nblocks * lanes), dtype, vma=vma))
+            out_specs.append(pl.BlockSpec((len(rows_), lanes),
+                                          lambda i: (0, i)))
+    kern = functools.partial(
+        _window_kernel, rows=n, block_rows=block_rows, lanes=lanes,
+        n_int=len(int_rows), n_float=len(float_rows), limbs=limbs)
+    outs = pl.pallas_call(
+        kern,
+        grid=(nblocks,),
+        in_specs=[pl.BlockSpec((block_rows,), lambda i: (i,))
+                  for _ in args],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="segment_sum_window",       # the kernel's name in a trace
+    )(*args)
+    outs = list(outs)
+    return (outs.pop(0) if int_rows else None,
+            outs.pop(0) if float_rows else None)
+
+
+def _combine(partials, first, span: int, lanes: int, num_segments: int):
+    """Add block partials ``[d, nblocks * W]`` into ``d`` tables of
+    ``[num_segments]`` at ``first_b + j`` for ``j < span``: a 1-D
+    scatter-add per row (XLA's scatter of ``d``-wide rows runs several
+    times slower). Lanes past a block's own span hold zeros, and targets
+    past the table drop."""
+    d = partials.shape[0]
+    nblocks = first.shape[0]
+    part = partials.reshape(d, nblocks, lanes)[:, :, :span]
+    tgt = (jnp.maximum(first, 0)[:, None]
+           + jnp.arange(span, dtype=jnp.int32)).reshape(-1)
+    return [jax.ops.segment_sum(part[k].reshape(-1), tgt,
+                                num_segments=num_segments)
+            for k in range(d)]
+
+
+def _sorted_window_sums(columns, segment_ids, num_segments: int,
+                        block_rows: int, span: int, interpret: bool):
+    """:func:`segment_sums` on the sorted-window route, for columns whose
+    dtypes the kernel takes."""
+    n = segment_ids.shape[0]
+    if n == 0:
+        return [jnp.zeros((num_segments,) + c.shape[1:], c.dtype)
+                for c in columns]
+    # one sort, keyed on the ids, carries every column's rows; pad and
+    # out-of-range rows (-1) lead
+    rows_of = [c.reshape(n, _width(c.shape)) for c in columns]
+    operands = [_clean_ids(segment_ids, num_segments)]
+    for r in rows_of:
+        operands.extend(r[:, k] for k in range(r.shape[1]))
+    srt = jax.lax.sort(operands, num_keys=1, is_stable=False)
+    sorted_ids, it = srt[0], iter(srt[1:])
+    int_rows, float_rows, slots = [], [], []
+    limbs = 0
+    for c, r in zip(columns, rows_of):
+        sub = [next(it) for _ in range(r.shape[1])]
+        if _window_dtype(c.dtype) == "int":
+            limbs = max(limbs, c.dtype.itemsize)
+            slots.append(("int", len(int_rows), len(sub)))
+            int_rows.extend(
+                jax.lax.bitcast_convert_type(s, jnp.int32)
+                if c.dtype.itemsize == 4 else s.astype(jnp.int32)
+                for s in sub)
+        else:
+            slots.append(("float", len(float_rows), len(sub)))
+            float_rows.extend(s.astype(jnp.float32) for s in sub)
+    lanes = _window_lanes(span)
+    first, _ = _block_ends(sorted_ids, block_rows)
+    part_int, part_float = _window_partials(
+        sorted_ids, int_rows, float_rows, block_rows=block_rows,
+        lanes=lanes, limbs=limbs, interpret=interpret)
+    tables = {
+        route: _combine(p, first, span, lanes, num_segments)
+        for route, p in (("int", part_int), ("float", part_float))
+        if p is not None}
+    out = []
+    for c, (route, start, width) in zip(columns, slots):
+        t = jnp.stack(tables[route][start:start + width], axis=1)
+        if route == "int" and c.dtype.itemsize == 4:
+            t = jax.lax.bitcast_convert_type(t, c.dtype)
+        else:
+            t = t.astype(c.dtype)      # narrow ints wrap as their sums do
+        out.append(t.reshape((num_segments,) + c.shape[1:]))
+    return out
+
+
+def segment_sums(columns, segment_ids: jax.Array, num_segments: int,
+                 window: Optional[tuple] = None,
+                 impl: Optional[str] = None) -> list:
+    """:func:`segment_sum` of each of ``columns`` by the same ids.
+
+    ``window``: ``(block_rows, span)``, with ``span`` at least
+    :func:`sorted_block_span` of these ids at ``block_rows``, takes the
+    sorted-window route for every column of 32 bits or fewer: one sort
+    keyed on the ids carries them all, the ``segment_sum_window`` kernel
+    sums each block of sorted rows into a window of ``span`` groups from
+    the block's first id, and one scatter-add per column adds the block
+    partials into the table. A span short of the ids' true span drops
+    rows: it must come from :func:`sorted_block_span`, and
+    :func:`window_fits` must hold. Without a window, with ``impl="xla"``,
+    or for 64-bit and bool columns, each column takes :func:`segment_sum`
+    with ``impl``.
+
+    ``impl`` on the window route: ``"pallas"`` compiles the kernel for the
+    TPU, ``"interpret"`` runs it in Pallas's TPU interpreter (inside a
+    mesh too), None picks the first on TPU and the second elsewhere.
+    """
+    if impl not in (None, "pallas", "interpret", "xla"):
+        raise ValueError(f"Unknown segment_sum impl {impl!r}")
+    columns = [jnp.asarray(c) for c in columns]
+    segment_ids = jnp.asarray(segment_ids)
+    take = [window is not None and impl != "xla"
+            and _window_dtype(c.dtype) is not None for c in columns]
+    out = [None if t else segment_sum(c, segment_ids, num_segments,
+                                      impl=impl)
+           for c, t in zip(columns, take)]
+    picked = [c for c, t in zip(columns, take) if t]
+    if picked:
+        if impl is None:
+            impl = ("pallas" if jax.default_backend() == "tpu"
+                    else "interpret")
+        block_rows, span = window
+        if impl == "pallas" and block_rows % WINDOW_MIN_BLOCK_ROWS:
+            raise ValueError(
+                f"the window kernel reads 1-D blocks of whole (8, 128) "
+                f"tiles: block_rows must be a multiple of "
+                f"{WINDOW_MIN_BLOCK_ROWS}, got {block_rows}")
+        sums = iter(_sorted_window_sums(
+            picked, segment_ids, num_segments, block_rows, span,
+            interpret=(impl == "interpret")))
+        out = [next(sums) if t else o for o, t in zip(out, take)]
+    return out

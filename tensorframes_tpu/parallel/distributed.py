@@ -1555,23 +1555,30 @@ def _monoid_group_plan(dist: DistributedFrame, keys):
 
 
 def _monoid_agg_shard_fn(fetch_names, col_combiners, axis,
-                         prog_groups: int, seg_impl=None):
+                         prog_groups: int, seg_impl=None, window=None):
     """The per-shard monoid segment-reduce + collective fragment — ONE
     definition shared by ``_daggregate`` (jax AND native routes), the
     fused distributed plan's folded ``daggregate``, and the streaming
     mesh fold (``plan/dist.py``), so the four dispatch paths can never
-    drift."""
-    from ..ops.segment_reduce import segment_sum as _segsum
+    drift. The ``sum`` columns go to ``segment_sums`` together, which
+    takes the sorted-window route where ``window`` is given
+    (:func:`_sum_window`)."""
+    from ..ops.segment_reduce import segment_sums as _segsums
+
+    sum_names = [f for f in fetch_names if col_combiners[f] == "sum"]
 
     # the compiled program is named after this function (jit_<name>):
     # a stable name the device trace shows
     def daggregate_shard_fn(ids_local, *vals_local):
+        cols = dict(zip(fetch_names, vals_local))
+        summed = dict(zip(sum_names, _segsums(
+            [cols[f] for f in sum_names], ids_local, prog_groups,
+            window=window, impl=seg_impl)))
         outs = []
         for f, v in zip(fetch_names, vals_local):
             cname = col_combiners[f]
             if cname == "sum":
-                local = _segsum(v, ids_local, prog_groups,
-                                impl=seg_impl)
+                local = summed[f]
             else:
                 # mask pad/out-of-range rows to the combiner's neutral
                 # and clamp their id to 0 so XLA's segment primitive
@@ -1595,6 +1602,54 @@ def _monoid_agg_shard_fn(fetch_names, col_combiners, axis,
         return tuple(outs)
 
     return daggregate_shard_fn
+
+
+def _sum_window(dist: DistributedFrame, ids_key: tuple, prog_ids,
+                prog_groups: int, sum_arrays):
+    """The sorted-window route's ``(block_rows, span)`` for the ``sum``
+    columns of a dispatch, and whether a span bound was read.
+
+    No bound is read where the route does not apply (``window_wanted``:
+    off the TPU, where the one-hot kernel holds every group, or where no
+    column's dtype takes the window kernel): ``(None, False)``. Otherwise the block rows follow
+    from a shard's rows and the group count, and the largest block span
+    of the sorted ids is computed once and cached beside the ids (it
+    depends on the key alone). A span the window cannot hold gives
+    ``(None, True)``: the dispatch keeps scatter-add."""
+    from ..ops.segment_reduce import (window_block_rows, window_fits,
+                                      window_wanted)
+
+    if not window_wanted(prog_groups, sum_arrays):
+        return None, False
+    mesh = dist.mesh
+    block_rows = window_block_rows(
+        prog_ids.shape[0] // mesh.num_data_shards, prog_groups)
+    ckey = ("window",) + ids_key + (block_rows,)
+    hit = _group_ids_cache_get(dist, ckey)
+    if hit is None:
+        with span("daggregate.window_span"):
+            prog = _window_span_program(mesh.mesh, mesh.data_axis,
+                                        prog_groups, block_rows)
+            hit = (int(jax.device_get(prog(prog_ids))),)
+        _group_ids_cache_put(dist, ckey, hit)
+    if not window_fits(hit[0], block_rows, sum_arrays):
+        return None, True
+    return (block_rows, hit[0]), True
+
+
+@functools.lru_cache(maxsize=16)
+def _window_span_program(mesh, axis: str, num_segments: int,
+                         block_rows: int):
+    """One program: the largest block span of each shard's sorted ids,
+    the max over the data axis."""
+    from ..ops.segment_reduce import sorted_block_span
+
+    def window_span(ids):
+        return jax.lax.pmax(
+            sorted_block_span(ids, num_segments, block_rows), axis)
+
+    return jax.jit(shard_map(window_span, mesh=mesh, in_specs=P(axis),
+                             out_specs=P()))
 
 
 def _monoid_agg_result(schema: Schema, keys, fetch_names, tables,
@@ -1932,9 +1987,13 @@ def daggregate(fetches, dist: DistributedFrame, keys,
     Two paths, mirroring :func:`~tensorframes_tpu.api.aggregate`:
 
     - ``fetches`` is a mapping ``{column: combiner-name}`` (sum/min/max/
-      prod): one segment-reduce launch per column (the Pallas one-hot
-      matmul for float sums and, exactly, for integer sums of 32 bits or
-      fewer) + one ``psum``-family collective;
+      prod): a segment reduce per column (the Pallas one-hot matmul for
+      float sums and, exactly, for integer sums of 32 bits or fewer) +
+      one ``psum``-family collective. Past the groups the one-hot kernel
+      holds, the ``sum`` columns take the sorted-window route together
+      (one sort carrying them all, ``ops/segment_reduce.py``), given the
+      largest block span of the sorted ids, read once and cached beside
+      the ids (:func:`_sum_window`);
     - ``fetches`` is a computation (block-level ``<col>_input`` reduce,
       the UDAF contract): per-shard sort-by-id + segmented
       ``associative_scan`` whose pair-combiner IS the user computation on
@@ -2088,12 +2147,25 @@ def _daggregate(fetches, dist: DistributedFrame, keys,
             _native_mesh_fallback(e)
             tables = None
     if tables is None:
+        window, bounded = None, False
+        if salt_plan is None:
+            ids_key = (("device", tuple(keys), max_groups) if device_keys
+                       else ("host", tuple(keys)))
+            window, bounded = _sum_window(
+                dist, ids_key, prog_ids, prog_groups,
+                [a for f, a in zip(fetch_names, arrays)
+                 if col_combiners[f] == "sum"])
         # cache the jitted program (the closure is fresh per call, so
         # jax's own jit cache would miss and retrace every dispatch)
-        fn, fresh = _collective_program(pkey, lambda: jax.jit(shard_map(
-            _monoid_agg_shard_fn(fetch_names, col_combiners, axis,
-                                 prog_groups),
-            mesh=mesh.mesh, in_specs=in_specs, out_specs=out_specs)))
+        fn, fresh = _collective_program(
+            pkey + (window,), lambda: jax.jit(shard_map(
+                _monoid_agg_shard_fn(fetch_names, col_combiners, axis,
+                                     prog_groups, window=window),
+                mesh=mesh.mesh, in_specs=in_specs, out_specs=out_specs)))
+        if window is not None:
+            counters.inc("segment_sum.sorted_window")
+        elif bounded:
+            counters.inc("segment_sum.window_fallback")
         trace = current_trace()
         t0 = 0.0
         if trace is not None:

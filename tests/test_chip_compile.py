@@ -97,10 +97,14 @@ def _cell_sizes():
 
 def test_four_chip_group_ids_and_aggregate_fit(topo, monkeypatch):
     # 1e9 rows over a 2x2 mesh, 1e7 groups: the per-chip id build (the
-    # one-program build asks 15.8 GB of temp a chip and is refused) and the
-    # aggregate program each fit a chip's 16 GB, arguments and temp
+    # one-program build asks 15.8 GB of temp a chip and is refused), the
+    # window span bound and the aggregate program, on the sorted-window
+    # route and on its scatter-add fallback, each fit a chip's 16 GB,
+    # arguments and temp; the window route's temp stays within the 6.0 GB
+    # a chip the scatter-add program took
     from jax import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from tensorframes_tpu.ops.segment_reduce import window_block_rows
 
     n, cap = _cell_sizes()
     mesh = Mesh(np.array(topo.devices[:4]), ("data",))
@@ -109,22 +113,35 @@ def test_four_chip_group_ids_and_aggregate_fit(topo, monkeypatch):
     valid = _spec((n,), jnp.bool_, rows)
     tables = _spec((4 * (cap + 1),), jnp.int32, rows)
     local, merge = D._per_chip_id_programs(mesh, "data", cap + 1)
+    span = D._window_span_program(mesh, "data", cap + 1,
+                                  window_block_rows(n // 4, cap + 1))
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     names = ["v1", "v2", "v3"]
-    agg = jax.jit(shard_map(
-        D._monoid_agg_shard_fn(names, {c: "sum" for c in names}, "data",
-                               cap + 1),
-        mesh=mesh, in_specs=(P("data"),) * 4,
-        out_specs=tuple(P() for _ in names)))
+
+    def agg(window):
+        return jax.jit(shard_map(
+            D._monoid_agg_shard_fn(names, {c: "sum" for c in names}, "data",
+                                   cap + 1, window=window),
+            mesh=mesh, in_specs=(P("data"),) * 4,
+            out_specs=tuple(P() for _ in names)))
+
+    # a block of the cell's sorted ids spans about 25 rows a group into
+    # 1,024 rows: ~41 groups; 128 is the window's whole width
+    window = (window_block_rows(n // 4, cap + 1), 128)
+    cols = (keys, keys, keys, _spec((n,), jnp.float32, rows))
     for name, fn, args in (
             ("device_ids_local", local, (keys, valid)),
             ("device_ids_merge", merge, (keys, valid, tables)),
-            ("aggregate", agg, (keys, keys, keys,
-                                _spec((n,), jnp.float32, rows)))):
+            ("window_span", span, (keys,)),
+            ("aggregate_scatter", agg(None), cols),
+            ("aggregate_window", agg(window), cols)):
         compiled = fn.lower(*args).compile()
         mem = _memory(name, compiled)
         assert mem["argument_size_in_bytes"] + mem["temp_size_in_bytes"] < HBM
-        if name != "device_ids_local":
+        if name == "aggregate_window":
+            assert mem["temp_size_in_bytes"] <= 6.0e9
+            assert "segment_sum_window" in compiled.as_text()
+        if name not in ("device_ids_local", "window_span"):
             assert "all-reduce" in compiled.as_text()
 
 
@@ -141,20 +158,40 @@ def test_one_chip_id_program_is_unchanged(topo):
     assert "all-reduce" not in routed.as_text()
 
 
-@pytest.mark.parametrize("groups,pallas,dtype", [
-    (1_000, True, jnp.float32), (100_000, False, jnp.float32),
-    (1_000, True, jnp.int32)])
-def test_segment_sum_routes_and_compiles(one_chip, groups, pallas, dtype):
+@pytest.mark.parametrize("groups,route,dtype,n", [
+    (1_000, "onehot", jnp.float32, 1 << 20),
+    (100_000, "scatter", jnp.float32, 1 << 20),
+    (1_000, "onehot", jnp.int32, 1 << 20),
+    (1_000_000, "window", None, 100_000_000)])
+def test_segment_sum_routes_and_compiles(one_chip, groups, route, dtype, n):
     # the route is segment_sum's own rule on what the kernel holds in
-    # fast memory; 100k groups would not compile in minutes as a kernel
-    n = 1 << 20
-    assert pallas_fits(groups, 1) is pallas
-    impl = "pallas" if pallas else "xla"
+    # fast memory; 100k groups would not compile in minutes as a kernel.
+    # Past it, q5's columns (two int32, one f32) at its 1e8 rows and 1e6
+    # groups compile on the sorted-window route
+    from tensorframes_tpu.ops.segment_reduce import (segment_sums,
+                                                     window_block_rows)
+
+    assert pallas_fits(groups, 1) is (route == "onehot")
+    if route == "window":
+        window = (window_block_rows(n, groups), 128)
+        ints = _spec((n,), jnp.int32, one_chip)
+        compiled = jax.jit(lambda i, a, b, c: segment_sums(
+            [a, b, c], i, groups, window=window, impl="pallas")).lower(
+                ints, ints, ints, _spec((n,), jnp.float32, one_chip)).compile()
+        text = compiled.as_text()
+        assert "segment_sum_window" in text
+        # one sort carries the ids and every column: no per-column scatter
+        # of the rows
+        assert len(re.findall(r" sort\(", text)) == 1
+        assert [o.dtype for o in compiled.out_info] == [
+            jnp.int32, jnp.int32, jnp.float32]
+        return
+    impl = "pallas" if route == "onehot" else "xla"
     compiled = jax.jit(
         lambda v, i: segment_sum(v, i, groups, impl=impl)).lower(
             _spec((n, 1), dtype, one_chip),
             _spec((n,), jnp.int32, one_chip)).compile()
-    assert ("tpu_custom_call" in compiled.as_text()) is pallas
+    assert ("tpu_custom_call" in compiled.as_text()) is (route == "onehot")
     # rows ride the lanes: no [N, 1] -> [N, 128] padded copy in HBM
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 64 * n

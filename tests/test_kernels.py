@@ -5,6 +5,8 @@ from SURVEY.md §4 applied to kernels: same computation, two lowerings, equal
 outputs).
 """
 
+import zlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -186,11 +188,165 @@ class TestSegmentSum:
         fn(vals, ids).block_until_ready()  # cached program: no new count
         assert counters.get("segment_sum.onehot_int") - before == counted
 
+    @pytest.mark.parametrize("where", ["dropped_rows", "valid_rows"])
+    def test_nonfinite_values_stay_in_their_group(self, rng, where):
+        # NaN, inf and -inf on rows whose ids are dropped vanish; on valid
+        # rows they reach their own group alone, as scatter-add's do (a
+        # group with inf and -inf is NaN), not every group of the block
+        vals = rng.standard_normal((200, 2)).astype(np.float32)
+        ids = rng.integers(0, 9, 200).astype(np.int32)
+        rows = [3, 40, 41, 77, 150]
+        vals[rows, 0] = [np.nan, np.inf, -np.inf, np.inf, -np.inf]
+        vals[rows, 1] = [np.inf, np.nan, 1.0, -np.inf, np.nan]
+        if where == "dropped_rows":
+            ids[rows] = [-1, 9, -1, 12, -1]
+        else:
+            ids[rows] = [0, 1, 1, 2, 4]
+        out = segment_sum(jnp.asarray(vals), jnp.asarray(ids), 9,
+                          impl="interpret", block_rows=64)
+        ref = segment_sum(jnp.asarray(vals), jnp.asarray(ids), 9, impl="xla")
+        got, want = np.asarray(out), np.asarray(ref)
+        assert bool(np.isfinite(want).all()) == (where == "dropped_rows")
+        np.testing.assert_array_equal(np.isnan(got), np.isnan(want))
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
     def test_unknown_impl_rejected(self, rng):
         vals = jnp.asarray(rng.integers(-5, 5, (4, 2)), jnp.int32)
         ids = jnp.zeros(4, jnp.int32)
         with pytest.raises(ValueError, match="Unknown segment_sum impl"):
             segment_sum(vals, ids, 1, impl="bogus")
+
+
+def _window_case(case: str):
+    """``(columns, ids, num_segments, block_rows, fits)`` of a
+    sorted-window case; ``fits`` is whether the route takes it."""
+    from tensorframes_tpu.ops.segment_reduce import WINDOW_MAX
+
+    rng = np.random.default_rng(zlib.crc32(case.encode()))
+
+    def ints(dtype, n, d=None):
+        info = np.iinfo(dtype)
+        shape = (n,) if d is None else (n, d)
+        return rng.integers(info.min, info.max, shape, dtype=dtype,
+                            endpoint=True)
+
+    if case.startswith("span_"):
+        # block 0 holds ids 0..62 and one more, the block's 64th smallest
+        last = {"span_128": 127, "span_129": 128, "span_max": WINDOW_MAX - 1,
+                "span_max_plus_1": WINDOW_MAX}[case]
+        ids = np.concatenate([np.arange(63), [last],
+                              np.full(64, WINDOW_MAX + 50)])
+        ids = rng.permutation(ids).astype(np.int32)
+        n = len(ids)
+        cols = [ints(np.int32, n), rng.random(n).astype(np.float32)]
+        return cols, ids, WINDOW_MAX + 60, 64, case != "span_max_plus_1"
+    if case == "int8_int16_int32_wrap":
+        # full-range values, 300 rows a group: every sum wraps
+        n, groups = 3000, 10
+        ids = rng.integers(0, groups, n).astype(np.int32)
+        return ([ints(np.int8, n), ints(np.int16, n), ints(np.int32, n)],
+                ids, groups, 512, True)
+    if case == "unsigned":
+        n, groups = 2000, 300
+        ids = rng.integers(0, groups, n).astype(np.int32)
+        return ([ints(np.uint8, n), ints(np.uint16, n), ints(np.uint32, n)],
+                ids, groups, 128, True)
+    if case == "f32":
+        n, groups = 4000, 500
+        ids = rng.integers(0, groups, n).astype(np.int32)
+        return ([(rng.random(n) * 100).astype(np.float32)], ids, groups,
+                128, True)
+    if case == "d1":
+        n, groups = 1500, 400
+        ids = rng.integers(0, groups, n).astype(np.int32)
+        return [ints(np.int32, n)], ids, groups, 128, True
+    if case == "d3_mixed":
+        # an int32 column, a vector f32 column of width 2, an int16 column
+        n, groups = 2500, 700
+        ids = rng.integers(0, groups, n).astype(np.int32)
+        return ([ints(np.int32, n), rng.random((n, 2)).astype(np.float32),
+                 ints(np.int16, n)], ids, groups, 256, True)
+    if case == "pad_and_out_of_range":
+        n, groups = 2000, 300
+        ids = rng.integers(-1, groups + 40, n).astype(np.int32)
+        ids[:100] = -1
+        ids[100:150] = -7
+        return ([ints(np.int32, n), rng.random(n).astype(np.float32)], ids,
+                groups, 128, True)
+    if case.startswith("nonfinite_"):
+        # NaN, inf and -inf on pad rows (id -1, which sort to the front
+        # and share the first blocks with valid rows) or on valid rows,
+        # one group holding both inf and -inf
+        n, groups = 3000, 700
+        ids = rng.integers(0, groups, n).astype(np.int32)
+        vals = rng.random((n, 2)).astype(np.float32)
+        rows = rng.choice(n, 8, replace=False)
+        vals[rows, 0] = [np.nan, np.inf, -np.inf, np.inf, np.nan, -np.inf,
+                         np.inf, 1.0]
+        vals[rows, 1] = [np.inf, -np.inf, np.nan, 2.0, np.nan, -np.inf,
+                         np.inf, np.nan]
+        if case == "nonfinite_pad_rows":
+            ids[rows] = -1
+            ids[rng.choice(n, 300, replace=False)] = -1
+        else:
+            ids[rows[1]] = ids[rows[2]]
+        return ([ints(np.int32, n), vals], ids, groups, 128, True)
+    rows = {"one_row_a_group": 1, "two_rows_a_group": 2,
+            "many_rows_a_group": 600}[case]
+    groups = 3000 // rows
+    ids = rng.permutation(np.repeat(np.arange(groups), rows)).astype(
+        np.int32)
+    n = len(ids)
+    return ([ints(np.int32, n), rng.random(n).astype(np.float32)], ids,
+            groups, 128, True)
+
+
+WINDOW_CASES = ["int8_int16_int32_wrap", "unsigned", "f32", "d1", "d3_mixed",
+                "pad_and_out_of_range", "span_128", "span_129", "span_max",
+                "span_max_plus_1", "one_row_a_group", "two_rows_a_group",
+                "many_rows_a_group", "nonfinite_pad_rows",
+                "nonfinite_valid_rows"]
+
+
+@pytest.mark.parametrize("case", WINDOW_CASES)
+def test_segment_sums_sorted_window_matches_scatter_add(case):
+    """``segment_sums`` on the sorted-window route, the kernel in the
+    Pallas interpreter, against ``jax.ops.segment_sum``: integer sums bit
+    for bit (wraparound included), f32 sums within 1e-6 relative; rows
+    with ids out of range dropped, whatever their values; a NaN or inf
+    in its own group alone; a block span past the widest window takes
+    scatter-add."""
+    from tensorframes_tpu.ops.segment_reduce import (
+        segment_sums, sorted_block_span, window_fits)
+
+    cols, ids, groups, block_rows, fits = _window_case(case)
+    span = int(sorted_block_span(jnp.asarray(ids), groups, block_rows))
+    if case.startswith("span_"):
+        assert span == {"span_128": 128, "span_129": 129}.get(
+            case, 512 + (case == "span_max_plus_1"))
+    assert window_fits(span, block_rows, cols) is fits
+    out = segment_sums(cols, ids, groups,
+                       window=(block_rows, span) if fits else None,
+                       impl="interpret")
+    valid = (ids >= 0) & (ids < groups)
+    for c, got in zip(cols, out):
+        mask = valid.reshape((-1,) + (1,) * (c.ndim - 1))
+        assert got.dtype == c.dtype
+        assert got.shape == (groups,) + c.shape[1:]
+        if np.issubdtype(c.dtype, np.integer):
+            want = jax.ops.segment_sum(jnp.where(mask, c, 0),
+                                       np.where(valid, ids, 0), groups)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+        else:
+            # f32 sums against the exact sums (f64 on the CPU backend)
+            want = jax.ops.segment_sum(
+                jnp.where(mask, c.astype(np.float64), 0),
+                np.where(valid, ids, 0), groups)
+            got, want = np.asarray(got), np.asarray(want)
+            assert bool(np.isfinite(want).all()) == (
+                case != "nonfinite_valid_rows")
+            np.testing.assert_array_equal(np.isnan(got), np.isnan(want))
+            np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
 
 
 class TestShardMapVma:
