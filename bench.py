@@ -82,8 +82,8 @@ def _run() -> int:
         e2e_iter()
     e2e = N_ROWS / ((time.perf_counter() - t0) / 5)
 
-    # which executor backs the engine path (native C++ core vs in-process
-    # jax) — recorded with the result, not part of the measured loop above
+    # which executor backs the engine path — recorded with the result,
+    # not part of the measured loop above
     from tensorframes_tpu.engine.executor import default_executor
     executor = type(default_executor()).__name__
 

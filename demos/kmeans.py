@@ -210,76 +210,6 @@ def step_daggregate(dist, centers: np.ndarray) -> Tuple[np.ndarray, float]:
     return new_centers, float(dist_total)
 
 
-# -- variant E: the WHOLE loop device-resident in the native C++ core -------
-
-def kmeans_native_resident(dist, init_centers: np.ndarray,
-                           num_iters: int = 20) -> np.ndarray:
-    """Run ``num_iters`` k-means rounds as a native device-resident loop.
-
-    Variant C still pays one host round-trip per round (centroids out,
-    partials back). Here the loop state — the sharded feature matrix
-    (constant pass-through) and the replicated centroid table — lives in
-    device buffers held by the C++ core
-    (:meth:`NativeMeshExecutor.run_sharded_loop`): the features upload
-    ONCE, every round's assignment/segment-sum/psum/centroid-update runs
-    as one GSPMD dispatch feeding its output buffers straight into the
-    next, and only the final centroids return to the host. The
-    reference's executor loop re-marshalled every row through the JVM
-    per round (``DebugRowOps.scala:755-794``); this is its inversion.
-
-    Requires ``TFT_EXECUTOR=pjrt`` + ``libtfrpjrt.so``.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    from tensorframes_tpu.parallel import native_mesh
-
-    mesh = dist.mesh
-    ex = native_mesh.executor_for(mesh)
-    if ex is None:
-        raise RuntimeError(
-            "kmeans_native_resident needs TFT_EXECUTOR=pjrt and a built "
-            "native/libtfrpjrt.so")
-    axis = mesh.data_axis
-    feats = np.asarray(dist.columns["features"])
-    k, _m = np.shape(init_centers)
-    rows_per = feats.shape[0] // mesh.num_data_shards
-    n_valid = dist.num_rows
-
-    def build():
-        def step(features, centers):
-            me = jax.lax.axis_index(axis)
-            rowid = me * rows_per + jnp.arange(rows_per)
-            valid = (rowid < n_valid).astype(features.dtype)
-            d = _distances(features, centers)
-            a = jnp.argmin(d, axis=1)
-            onehot = (jax.nn.one_hot(a, k, dtype=features.dtype)
-                      * valid[:, None])
-            sums = jax.lax.psum(onehot.T @ features, axis)
-            counts = jax.lax.psum(onehot.sum(axis=0), axis)
-            new_c = jnp.where(
-                counts[:, None] > 0,
-                sums / jnp.maximum(counts, 1.0)[:, None], centers)
-            return (features, new_c)
-        return shard_map(step, mesh=mesh.mesh,
-                         in_specs=(P(axis, None), P()),
-                         out_specs=(P(axis, None), P()))
-
-    in_sh = [mesh.row_sharding(2), mesh.replicated()]
-    out_sh = [mesh.row_sharding(2), mesh.replicated()]
-    outs = ex.run_sharded_loop(
-        ("kmeans_resident", mesh.mesh, feats.shape, str(feats.dtype), k,
-         n_valid), build,
-        [feats, np.asarray(init_centers, feats.dtype)], in_sh, out_sh,
-        mesh, iters=num_iters)
-    if outs is None:
-        raise RuntimeError(
-            "kmeans resident program was not natively routable")
-    return outs[1]
-
-
 # -- driver loop (reference kmeans.py:148-163) ------------------------------
 
 def kmeans(df: tft.TensorFrame, init_centers: np.ndarray,
@@ -334,24 +264,6 @@ def main():
         centers, history = kmeans(dist, init, step=step)
         print(f"[{name}] converged after {len(history)} steps; "
               f"final distance {history[-1]:.3f}")
-
-    # variant E: the whole loop in the native C++ core, when available
-    import os
-
-    from tensorframes_tpu import native_pjrt
-
-    if native_pjrt.available() and os.environ.get("TFT_EXECUTOR") == "pjrt":
-        try:
-            centers = kmeans_native_resident(dist, init, num_iters=20)
-        except RuntimeError as e:
-            # executor_for can still decline (multi-process, client
-            # failure, too few native devices) after the cheap checks
-            print(f"[native_resident] skipped ({e})")
-        else:
-            print("[native_resident] centers:\n", np.asarray(centers))
-    else:
-        print("[native_resident] skipped (needs TFT_EXECUTOR=pjrt and "
-              "a built native/libtfrpjrt.so)")
 
 
 if __name__ == "__main__":

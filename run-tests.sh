@@ -207,18 +207,6 @@ fi
 echo "== building native runtime (libtfruntime.so) =="
 make -C native
 
-HAVE_TF=0
-if python -c "import tensorflow" >/dev/null 2>&1; then
-  HAVE_TF=1
-fi
-
-if [ "$HAVE_TF" = 1 ]; then
-  echo "== building native PJRT core (libtfrpjrt.so) =="
-  make -C native pjrt
-else
-  echo "== tensorflow C++ libs not present; skipping libtfrpjrt.so =="
-fi
-
 echo "== running test suite (timing-marked deadline tests deferred) =="
 python -m pytest tests/ -q -m 'not timing' "$@"
 
@@ -231,10 +219,4 @@ timing_rc=0
 python -m pytest tests/ -q -m timing "$@" || timing_rc=$?
 if [ "$timing_rc" -ne 0 ] && [ "$timing_rc" -ne 5 ]; then
   exit "$timing_rc"
-fi
-
-if [ "$HAVE_TF" = 1 ]; then
-  echo "== op suite again through the native PJRT core (TFT_EXECUTOR=pjrt) =="
-  TFT_EXECUTOR=pjrt exec python -m pytest tests/test_ops.py \
-    tests/test_demos.py -q
 fi

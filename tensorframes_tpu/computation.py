@@ -155,7 +155,14 @@ class Computation:
 
     Outputs are canonically **sorted by name**, matching the reference
     engine's output-column ordering contract (``DebugRowOps.scala:344-355``).
+
+    ``opaque`` marks a computation that wraps an exported or foreign
+    module (:meth:`from_stablehlo`, :meth:`deserialize`): it runs through
+    ``jax.export``'s ``call``, which has no batching rule, so the planners
+    leave it per-op instead of fusing it into a chain.
     """
+
+    opaque = False
 
     def __init__(self, fn: Callable, inputs: Sequence[TensorSpec],
                  outputs: Sequence[TensorSpec]):
@@ -244,19 +251,11 @@ class Computation:
 
     # -- serialization (StableHLO via jax.export) --------------------------
     def serialize(self) -> bytes:
-        """Serialize to portable bytes: a JSON header (names/dtypes/shapes
-        + native-execution metadata) + the raw StableHLO module (symbolic
-        dims for Unknowns) + the full ``jax.export`` blob. The analogue of
-        ``GraphDef.SerializeToString`` + ``ShapeDescription`` travelling
-        together.
-
-        The raw module section is what a jax-free executor host needs: the
-        native core refines its symbolic dims at concrete shapes and
-        compiles it without re-entering jax
-        (``native/pjrt_core.cpp:refine_to_hlo_proto``; the reference's
-        executors likewise ran shipped GraphDef bytes with no Python
-        graph-authoring stack, ``TensorFlowOps.scala:46-52``). Lowered for
-        both cpu and tpu so one blob runs on either host kind.
+        """Serialize to portable bytes: a JSON header (names/dtypes/shapes)
+        + the full ``jax.export`` blob (symbolic dims for Unknowns). The
+        analogue of ``GraphDef.SerializeToString`` + ``ShapeDescription``
+        travelling together. Lowered for both cpu and tpu so one blob runs
+        on either host kind.
         """
         avals, _ = _sym_avals(self.inputs, share_lead_symbol=True)
         names = self.input_names
@@ -278,22 +277,12 @@ class Computation:
                 "serializing for the local platform only", type(e).__name__,
                 e)
             exported = jax_export.export(jitted)(*avals)
-        module = exported.mlir_module_serialized
         blob = exported.serialize()
         header = json.dumps({
             "inputs": [s.to_json() for s in self.inputs],
             "outputs": [s.to_json() for s in self.outputs],
-            "native": {
-                "cc_version": exported.calling_convention_version,
-                "platforms": list(exported.platforms),
-                "module_len": len(module),
-                # the TRACED argument dtypes (x64-policy-dependent): what
-                # the module's parameters actually are, for jax-free hosts
-                "arg_dtypes": [str(np.dtype(a.dtype)) for a in avals],
-            },
         }).encode("utf-8")
-        return (_MAGIC + struct.pack("<I", len(header)) + header
-                + module + blob)
+        return _MAGIC + struct.pack("<I", len(header)) + header + blob
 
     @staticmethod
     def from_stablehlo(module, inputs: Sequence[TensorSpec],
@@ -317,12 +306,8 @@ class Computation:
         ``outputs``: explicit specs, or ``None`` to infer shapes/dtypes
         abstractly (named ``out_0``, ``out_1``, ... in module result
         order). ``platforms`` defaults to the current backend; it must
-        name the platform(s) the module was lowered for.
-
-        The imported computation runs on BOTH executors: the jax path
-        calls it through ``jax.export``'s calling convention, and the
-        native C++ core compiles the same bytecode via its jax-free
-        refine+compile pipeline (``_native_dynamic``).
+        name the platform(s) the module was lowered for. The imported
+        computation runs through ``jax.export``'s calling convention.
         """
         for s in inputs:
             if any(d is None or d < 0 for d in s.shape.dims):
@@ -404,14 +389,7 @@ class Computation:
             return {out_names[0]: res}
 
         comp = Computation(dict_fn, list(inputs), list(outputs))
-        comp._native_dynamic = {
-            "module": module,
-            "cc_version":
-                jax_export.maximum_supported_calling_convention_version,
-            "platforms": tuple(platforms),
-            "arg_dtypes": [str(np.dtype(_dt.device_dtype(s.dtype)))
-                           for s in inputs],
-        }
+        comp.opaque = True
         return comp
 
     @staticmethod
@@ -422,20 +400,12 @@ class Computation:
         (hlen,) = struct.unpack_from("<I", data, off)
         off += 4
         header = json.loads(data[off:off + hlen].decode("utf-8"))
-        payload = data[off + hlen:]
+        blob = data[off + hlen:]
         native = header.get("native")
-        native_dynamic = None
         if native:
-            mlen = native["module_len"]
-            native_dynamic = {
-                "module": payload[:mlen],
-                "cc_version": native["cc_version"],
-                "platforms": tuple(native["platforms"]),
-                "arg_dtypes": native.get("arg_dtypes"),
-            }
-            blob = payload[mlen:]
-        else:  # pre-native blobs: jax.export payload only
-            blob = payload
+            # older blobs carry a raw StableHLO module section ahead of
+            # the jax.export payload: skip it
+            blob = blob[native["module_len"]:]
         exported = jax_export.deserialize(blob)
         inputs = [TensorSpec.from_json(d) for d in header["inputs"]]
         outputs = [TensorSpec.from_json(d) for d in header["outputs"]]
@@ -453,9 +423,7 @@ class Computation:
             return {out_names[0]: res}
 
         comp = Computation(dict_fn, inputs, outputs)
-        # the raw dynamic module lets the native core compile this
-        # computation per signature without re-entering jax
-        comp._native_dynamic = native_dynamic
+        comp.opaque = True
         return comp
 
 

@@ -800,7 +800,7 @@ class PaddingExecutor:
     power-of-two buckets before delegating, and slices outputs back — so
     streams of odd-sized blocks share the inner executor's compiled
     programs (the same compile-signature bound ``BlockExecutor(pad_rows=
-    True)`` provides, but composable with e.g. the native PJRT executor).
+    True)`` provides, but composable with any executor that has ``run``).
     Only valid for row-local computations, like every padding path.
     """
 
@@ -859,29 +859,12 @@ _default_lock = threading.Lock()
 
 def default_executor() -> BlockExecutor:
     """Exact-shape executor: block-level computations may be cross-row
-    (e.g. ``z = x - mean(x)``), so padding would corrupt them.
-
-    ``TFT_EXECUTOR=pjrt`` routes the process default through the native
-    C++ PJRT core (``native_pjrt.PjrtBlockExecutor``) with the jax
-    in-process path as fallback if the native library is unavailable.
-    """
+    (e.g. ``z = x - mean(x)``), so padding would corrupt them."""
     global _default
     if _default is None:
         with _default_lock:
             if _default is None:
-                import os
-                if os.environ.get("TFT_EXECUTOR") == "pjrt":
-                    try:
-                        from ..native_pjrt import PjrtBlockExecutor
-                        _default = PjrtBlockExecutor()
-                    except Exception as e:  # fall back to the jax path
-                        _log.warning(
-                            "TFT_EXECUTOR=pjrt requested but the native "
-                            "core is unavailable (%s); using the jax "
-                            "executor", e)
-                        _default = BlockExecutor()
-                else:
-                    _default = BlockExecutor()
+                _default = BlockExecutor()
     return _default
 
 
@@ -889,20 +872,10 @@ def default_padding_executor() -> BlockExecutor:
     """Bucketed-padding executor for row-local computations (``map_rows``:
     rows are independent under vmap, so padding the row dim to power-of-two
     buckets is safe and bounds compile signatures to O(log max_rows) for
-    streams of odd-sized blocks — SURVEY.md §7 hard part #1).
-
-    Under ``TFT_EXECUTOR=pjrt`` the buckets wrap the native PJRT executor
-    (:class:`PaddingExecutor` composition), so map_rows runs through the
-    C++ core too."""
+    streams of odd-sized blocks — SURVEY.md §7 hard part #1)."""
     global _default_padding
     if _default_padding is None:
-        inner = default_executor()  # resolves TFT_EXECUTOR + fallback once
         with _default_lock:
             if _default_padding is None:
-                if type(inner) is BlockExecutor:
-                    _default_padding = BlockExecutor(pad_rows=True)
-                else:
-                    # native core default: share its ONE client (a second
-                    # PJRT client per process can be refused on TPU hosts)
-                    _default_padding = PaddingExecutor(inner)
+                _default_padding = BlockExecutor(pad_rows=True)
     return _default_padding

@@ -1243,8 +1243,8 @@ def aggregate(fetches: Fetches, grouped: GroupedFrame,
     ex = executor or default_executor()
     # the single-program fold runs comp.fn under in-process jax.jit, so it
     # only replaces the per-group dispatch loop when that IS the effective
-    # executor; an explicit executor= or a TFT_EXECUTOR=pjrt process
-    # default keeps the CompactionBuffer path through that executor
+    # executor; an explicit executor= keeps the CompactionBuffer path
+    # through that executor
     use_segmented_fold = type(ex) is BlockExecutor and not ex.pad_rows
     df = grouped.frame
     keys = grouped.keys
@@ -1289,18 +1289,16 @@ def aggregate(fetches: Fetches, grouped: GroupedFrame,
         blocks, keys)
     from .. import native as _native
 
-    use_fold = (use_segmented_fold
-                and getattr(comp, "_native_dynamic", None) is None)
+    use_fold = use_segmented_fold and not comp.opaque
 
     def block_partials(fetch_b, fact_b):
         """One partial row per group present, in segment order."""
         if use_fold:
             # ONE compiled device program for the block's groups — a
             # segmented associative_scan whose operator is the user
-            # computation on two-row blocks. A non-default executor
-            # (explicit, or TFT_EXECUTOR=pjrt) keeps the
-            # CompactionBuffer path so the computation runs through that
-            # executor; deserialized computations (exported.call) have
+            # computation on two-row blocks. An explicit executor keeps
+            # the CompactionBuffer path so the computation runs through
+            # that executor; opaque computations (exported.call) have
             # no vmap batching rule and also keep it.
             return _aggregate_segmented_fold(comp, fetch_names, fetch_b,
                                              fact_b, df.schema)

@@ -189,7 +189,7 @@ class ReadyResult:
     """A pre-computed pending: drains to a value already in hand.
 
     The generic fallback for executors without a ``submit`` half (e.g.
-    :class:`~..engine.executor.PaddingExecutor` wrapping a native core):
+    :class:`~..engine.executor.PaddingExecutor`):
     the block runs eagerly — through the executor's full resilient path —
     at submit time, so the stream stays correct (no overlap, same
     semantics).

@@ -10,8 +10,8 @@ package adds the query dimension on top of the existing primitives
 (every ``span``/``counters`` call site keeps working unchanged):
 
 - :mod:`.events` — :class:`QueryTrace` + contextvar correlation: every
-  public API forcing gets a unique query id; engine, pipeline,
-  resilience, and native-PJRT layers attach typed events (block
+  public API forcing gets a unique query id; engine, pipeline and
+  resilience layers attach typed events (block
   submit/compute/drain, retries with their classified error, OOM splits,
   pad/sync fallbacks, compile-cache hits/misses, occupancy samples).
   Finished traces land in a bounded ring buffer and an optional JSONL

@@ -10,7 +10,7 @@ missing dimension:
 - every public API forcing opens a :class:`QueryTrace` with a unique
   query id (``q<N>``) via :func:`query_trace`;
 - the trace rides a :mod:`contextvars` context variable, so any layer —
-  engine, pipeline, resilience, native PJRT — attaches typed events with
+  engine, pipeline, resilience — attaches typed events with
   plain :func:`add_event` calls and the correlation id survives the
   pipeline's worker threads (:func:`wrap_context` carries it across
   ``ThreadPoolExecutor`` boundaries);
@@ -604,9 +604,8 @@ def add_event(etype: str, name: Optional[str] = None,
 def wrap_context(fn: Callable) -> Callable:
     """Bind ``fn`` to the CALLER's context so the query correlation id
     survives a hop onto a worker thread (``contextvars`` do not propagate
-    into ``ThreadPoolExecutor`` tasks by themselves). Used by the native
-    PJRT submit path; any executor that dispatches on its own threads
-    should do the same."""
+    into ``ThreadPoolExecutor`` tasks by themselves). Any executor that
+    dispatches on its own threads should use it."""
     ctx = contextvars.copy_context()
 
     def bound(*a, **k):

@@ -21,8 +21,8 @@ Families:
   ``tft_query_latency_seconds{op="...",outcome="ok|error"}`` (one
   series per query op and outcome, observed at every traced query
   finish — failures never pollute the success-latency series) and
-  ``tft_compile_seconds{engine="jax|native|native_mesh"}`` (observed at
-  every compile-cache miss, always on);
+  ``tft_compile_seconds{engine="jax"}`` (observed at every
+  compile-cache miss, always on);
 - ``tft_trace_ring_events`` — events currently buffered in the ring.
 
 Security note: the endpoint binds ``127.0.0.1`` ONLY — metrics names leak

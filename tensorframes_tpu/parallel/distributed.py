@@ -170,7 +170,7 @@ def _trace_shards(trace, op: str, dist=None, mesh=None,
 
 
 def _trace_mesh_done(trace, outs, t0: float, op: str,
-                     native: bool = False, mesh=None) -> None:
+                     mesh=None) -> None:
     """Per-device readiness timings + the op-level mesh dispatch span.
 
     Readiness is measured by waiting on each device's output shard in
@@ -182,38 +182,37 @@ def _trace_mesh_done(trace, outs, t0: float, op: str,
     feed the elastic layer's skew tracker (the signal behind
     skew-adaptive repartitioning, ``parallel/elastic.py``).
     """
-    if not native:
-        try:
-            arr = next((a for a in outs
-                        if hasattr(a, "addressable_shards")), None)
-            if arr is not None:
-                shards = list(arr.addressable_shards)
-                by_start = {}
-                for sh in shards:
-                    idx = sh.index
-                    sl = idx[0] if idx else None
-                    start = (sl.start or 0) if isinstance(sl, slice) else 0
-                    by_start.setdefault(start, sh)
-                if len(by_start) > 1:  # row-sharded: data-shard order
-                    ordered = [by_start[k] for k in sorted(by_start)]
-                else:  # replicated result: one copy per device
-                    ordered = sorted(
-                        shards, key=lambda sh: getattr(sh.device, "id", 0))
-                durs = []
-                for i, sh in enumerate(ordered):
-                    jax.block_until_ready(sh.data)
-                    t = trace.clock()
-                    durs.append(max(t - t0, 0.0))
-                    trace.add("shard_compute", name=f"{op} d{i}", ts=t0,
-                              dur=durs[-1], device=i,
-                              track=DEVICE_TRACK_BASE + i)
-                if mesh is not None and len(durs) >= 2:
-                    _elastic.note_dispatch(mesh, op, durs)
-        except Exception as e:
-            get_logger("distributed").debug(
-                "per-device readiness trace failed for %s: %s", op, e)
+    try:
+        arr = next((a for a in outs
+                    if hasattr(a, "addressable_shards")), None)
+        if arr is not None:
+            shards = list(arr.addressable_shards)
+            by_start = {}
+            for sh in shards:
+                idx = sh.index
+                sl = idx[0] if idx else None
+                start = (sl.start or 0) if isinstance(sl, slice) else 0
+                by_start.setdefault(start, sh)
+            if len(by_start) > 1:  # row-sharded: data-shard order
+                ordered = [by_start[k] for k in sorted(by_start)]
+            else:  # replicated result: one copy per device
+                ordered = sorted(
+                    shards, key=lambda sh: getattr(sh.device, "id", 0))
+            durs = []
+            for i, sh in enumerate(ordered):
+                jax.block_until_ready(sh.data)
+                t = trace.clock()
+                durs.append(max(t - t0, 0.0))
+                trace.add("shard_compute", name=f"{op} d{i}", ts=t0,
+                          dur=durs[-1], device=i,
+                          track=DEVICE_TRACK_BASE + i)
+            if mesh is not None and len(durs) >= 2:
+                _elastic.note_dispatch(mesh, op, durs)
+    except Exception as e:
+        get_logger("distributed").debug(
+            "per-device readiness trace failed for %s: %s", op, e)
     trace.add("mesh_dispatch", name=op, ts=t0,
-              dur=max(trace.clock() - t0, 0.0), native=native)
+              dur=max(trace.clock() - t0, 0.0))
 
 
 class DistributedFrame:
@@ -355,9 +354,9 @@ class DistributedFrame:
         program per mesh stage with shard intermediates staying
         device-resident (terminal monoid ``dreduce_blocks`` /
         ``daggregate`` fold into the same program). Returns ``self``
-        when fusion cannot apply — ``TFT_FUSE=0``, the native ``pjrt``
-        executor, multi-process meshes, frames whose rows do not tile
-        the data axis — so chains then run eagerly per-op,
+        when fusion cannot apply — ``TFT_FUSE=0``, multi-process
+        meshes, frames whose rows do not tile the data axis — so chains
+        then run eagerly per-op,
         bit-identical by construction. See ``docs/plan.md``.
         """
         from ..plan import dist as _dplan
@@ -582,29 +581,6 @@ def _dmap_blocks(fetches, dist: DistributedFrame, trim: bool,
     out_schema = _ops._validate_map(comp, schema, block_level=True, trim=trim)
     mesh = dist.mesh
 
-    # TFT_EXECUTOR=pjrt: row-aligned maps run as ONE GSPMD-partitioned
-    # executable inside the native C++ core (trim/global programs and
-    # unsupported dtypes fall back to the jax dispatch below)
-    nm = _native_mesh(mesh) if not trim else None
-    if nm is not None:
-        try:
-            outs_np = nm.dmap(comp, dist)
-        except Exception as e:
-            _native_mesh_fallback(e)
-            outs_np = None
-        if outs_np is not None:
-            counters.inc("mesh.dispatches")
-            # per-key copy through __getitem__: dict()'s raw fast-path
-            # copy would bypass SpillableColumns' fault-back and hand a
-            # concurrently-spilled frame's None placeholders downstream
-            cols = {n: dist.columns[n] for n in dist.columns}
-            for spec in comp.outputs:
-                a = outs_np[spec.name]
-                cols[spec.name] = jax.device_put(
-                    a, mesh.row_sharding(a.ndim))
-            return DistributedFrame(mesh, out_schema, cols, dist.num_rows,
-                                    shard_valid=dist.shard_valid)
-
     jitted = _jitted(comp)
     policy = _default_policy()
 
@@ -647,8 +623,9 @@ def _dmap_blocks(fetches, dist: DistributedFrame, trim: bool,
         raise ValueError(
             f"row_aligned=True but the output has {n_out} rows and the "
             f"input {dist.padded_rows}")
-    # per-key copy through __getitem__ (see the native-mesh branch):
-    # dict() would bypass a spilled SpillableColumns' fault-back
+    # per-key copy through __getitem__: dict()'s raw fast-path copy
+    # would bypass SpillableColumns' fault-back and hand a
+    # concurrently-spilled frame's None placeholders downstream
     cols = {} if trim else {n: dist.columns[n] for n in dist.columns}
     for spec in comp.outputs:
         cols[spec.name] = out[spec.name]
@@ -749,39 +726,17 @@ def _dfilter(predicate, dist: DistributedFrame) -> DistributedFrame:
         return shard_map(shard_fn, mesh=mesh.mesh, in_specs=in_specs,
                          out_specs=out_specs)
 
-    # TFT_EXECUTOR=pjrt: per-shard mask + compaction as one GSPMD
-    # executable in the native core
-    outs = None
-    nm = _native_mesh(mesh)
-    if nm is not None:
-        in_shardings = (mesh.row_sharding(1),) + tuple(
-            mesh.row_sharding(a.ndim) for a in arrays)
-        out_shardings = tuple(
-            mesh.row_sharding(a.ndim) for a in arrays
-        ) + (mesh.row_sharding(1), mesh.row_sharding(1))
-        try:
-            outs_np = nm.run_sharded(
-                ("dfilter",) + key, build_prog,
-                [cnt_dev] + list(arrays), in_shardings,
-                list(out_shardings), mesh, owner=comp)
-        except Exception as e:
-            _native_mesh_fallback(e)
-            outs_np = None
-        if outs_np is not None:
-            outs = [jax.device_put(a, s)
-                    for a, s in zip(outs_np, out_shardings)]
-    if outs is None:
-        fn = cache.get(key)
-        if fn is None:
-            fn = jax.jit(build_prog())
-            cache[key] = fn
-        trace = current_trace()
-        t0 = (_trace_shards(trace, "dfilter", dist=dist)
-              if trace is not None else 0.0)
-        with span("dfilter.dispatch"):
-            outs = fn(cnt_dev, *arrays)
-        if trace is not None:
-            _trace_mesh_done(trace, list(outs), t0, "dfilter", mesh=mesh)
+    fn = cache.get(key)
+    if fn is None:
+        fn = jax.jit(build_prog())
+        cache[key] = fn
+    trace = current_trace()
+    t0 = (_trace_shards(trace, "dfilter", dist=dist)
+          if trace is not None else 0.0)
+    with span("dfilter.dispatch"):
+        outs = fn(cnt_dev, *arrays)
+    if trace is not None:
+        _trace_mesh_done(trace, list(outs), t0, "dfilter", mesh=mesh)
     counters.inc("mesh.dispatches")
     new_cols: Dict[str, jax.Array] = dict(zip(tensor_names, outs))
     counts = _read_global(outs[len(tensor_names)]).astype(np.int64)
@@ -1005,7 +960,7 @@ def _warn_dsort_gather(dist, S: int):
     device — the exact pathology columnsort exists to kill — so its
     silent return on an S>1 mesh (rows not tiling the data axis, e.g. a
     trim/global map result) must be visible. One warning per process,
-    like the native-mesh fallback."""
+    like the other mesh fallbacks."""
     global _dsort_gather_warned
     if _dsort_gather_warned:
         return
@@ -1256,25 +1211,6 @@ def _dsort_columnsort(dist, keys, descending, tensor_names, arrays,
     if want_order:
         out_shardings = out_shardings + (mesh.row_sharding(1),)
 
-    # TFT_EXECUTOR=pjrt: the whole columnsort pipeline — local sorts AND
-    # the all_to_all/ppermute exchanges — compiles as one GSPMD
-    # executable in the native C++ core
-    nm = _native_mesh(mesh)
-    if nm is not None:
-        in_shardings = (mesh.row_sharding(1),) + tuple(
-            mesh.row_sharding(a.ndim) for a in arrays)
-        try:
-            outs_np = nm.run_sharded(
-                ("dsort",) + ckey[1:], build_full,
-                [valid_dev] + list(arrays), in_shardings,
-                list(out_shardings), mesh)
-        except Exception as e:
-            _native_mesh_fallback(e)
-            outs_np = None
-        if outs_np is not None:
-            return tuple(jax.device_put(a, s)
-                         for a, s in zip(outs_np, out_shardings))
-
     fn = _dsort_cache.get(ckey)
     if fn is None:
         fn = jax.jit(build_full(), out_shardings=out_shardings)
@@ -1369,36 +1305,10 @@ def _collective_program(key: tuple, build):
     return fn, True
 
 
-_native_mesh_warned = False
-
-
-def _native_mesh(mesh: DeviceMesh):
-    """The native GSPMD mesh executor when ``TFT_EXECUTOR=pjrt`` routes
-    mesh ops through the C++ core, else ``None`` (the jax path)."""
-    import os
-
-    if os.environ.get("TFT_EXECUTOR") != "pjrt":
-        return None
-    from . import native_mesh
-
-    return native_mesh.executor_for(mesh)
-
-
-def _native_mesh_fallback(e: Exception):
-    global _native_mesh_warned
-    if not _native_mesh_warned:
-        from ..utils.logging import get_logger
-
-        get_logger("native_mesh").warning(
-            "native mesh dispatch failed (%s); falling back to the jax "
-            "path for this and subsequent calls that hit the same error",
-            e)
-        _native_mesh_warned = True
-
-
 def _collective_shard_fn(names, combs, axis):
     """The per-shard masked-reduce + collective program — ONE source of
-    truth shared by the jax ``shard_map`` path and the native GSPMD path."""
+    truth shared by the eager collective reduce and the fused plan's
+    folded reduce (``plan/dist.py``)."""
 
     def shard_fn(nv, *shards):
         outs = []
@@ -1442,36 +1352,25 @@ def _collective_reduce(col_combiners: Mapping[str, str],
     in_specs = (P(axis),) + tuple(
         P(axis, *([None] * (a.ndim - 1))) for a in arrays)
 
-    outs = None
-    nm = _native_mesh(mesh)
-    if nm is not None:
-        try:
-            outs = nm.dreduce_collective(
-                _collective_shard_fn(names, combs, axis), in_specs, names,
-                dist, dist.per_shard_valid(), key)
-        except Exception as e:
-            _native_mesh_fallback(e)
-            outs = None
-    if outs is None:
-        fn, fresh = _collective_program(key, lambda: jax.jit(shard_map(
-            _collective_shard_fn(names, combs, axis), mesh=mesh.mesh,
-            in_specs=in_specs, out_specs=tuple(P() for _ in arrays))))
-        nv_dev = jax.make_array_from_callback(
-            (mesh.num_data_shards,), mesh.row_sharding(1),
-            lambda idx: dist.per_shard_valid().astype(np.int32)[idx])
-        trace = current_trace()
-        t0 = 0.0
-        if trace is not None:
-            t0 = _trace_shards(trace, "dreduce_blocks", dist=dist)
-            for name in names:
-                trace.add("collective", name=combs[name].ici, ts=t0,
-                          column=name, op="dreduce_blocks")
-        with span("dreduce_blocks.collective_dispatch"), \
-                span("compile.dreduce_blocks") if fresh else _NO_SPAN:
-            outs = fn(nv_dev, *arrays)
-        if trace is not None:
-            _trace_mesh_done(trace, list(outs), t0, "dreduce_blocks",
-                             mesh=mesh)
+    fn, fresh = _collective_program(key, lambda: jax.jit(shard_map(
+        _collective_shard_fn(names, combs, axis), mesh=mesh.mesh,
+        in_specs=in_specs, out_specs=tuple(P() for _ in arrays))))
+    nv_dev = jax.make_array_from_callback(
+        (mesh.num_data_shards,), mesh.row_sharding(1),
+        lambda idx: dist.per_shard_valid().astype(np.int32)[idx])
+    trace = current_trace()
+    t0 = 0.0
+    if trace is not None:
+        t0 = _trace_shards(trace, "dreduce_blocks", dist=dist)
+        for name in names:
+            trace.add("collective", name=combs[name].ici, ts=t0,
+                      column=name, op="dreduce_blocks")
+    with span("dreduce_blocks.collective_dispatch"), \
+            span("compile.dreduce_blocks") if fresh else _NO_SPAN:
+        outs = fn(nv_dev, *arrays)
+    if trace is not None:
+        _trace_mesh_done(trace, list(outs), t0, "dreduce_blocks",
+                         mesh=mesh)
     counters.inc("mesh.dispatches")
     result = {}
     for name, a in zip(names, outs):
@@ -1555,14 +1454,14 @@ def _monoid_group_plan(dist: DistributedFrame, keys):
 
 
 def _monoid_agg_shard_fn(fetch_names, col_combiners, axis,
-                         prog_groups: int, seg_impl=None, window=None):
+                         prog_groups: int, window=None):
     """The per-shard monoid segment-reduce + collective fragment — ONE
-    definition shared by ``_daggregate`` (jax AND native routes), the
-    fused distributed plan's folded ``daggregate``, and the streaming
-    mesh fold (``plan/dist.py``), so the four dispatch paths can never
-    drift. The ``sum`` columns go to ``segment_sums`` together, which
-    takes the sorted-window route where ``window`` is given
-    (:func:`_sum_window`)."""
+    definition shared by ``_daggregate``, the fused distributed plan's
+    folded ``daggregate``, and the streaming mesh fold
+    (``plan/dist.py``), so the three dispatch paths can never drift. The
+    ``sum`` columns go to ``segment_sums`` together, which takes the
+    sorted-window route where ``window`` is given (:func:`_sum_window`).
+    """
     from ..ops.segment_reduce import segment_sums as _segsums
 
     sum_names = [f for f in fetch_names if col_combiners[f] == "sum"]
@@ -1573,7 +1472,7 @@ def _monoid_agg_shard_fn(fetch_names, col_combiners, axis,
         cols = dict(zip(fetch_names, vals_local))
         summed = dict(zip(sum_names, _segsums(
             [cols[f] for f in sum_names], ids_local, prog_groups,
-            window=window, impl=seg_impl)))
+            window=window)))
         outs = []
         for f, v in zip(fetch_names, vals_local):
             cname = col_combiners[f]
@@ -2016,15 +1915,6 @@ def daggregate(fetches, dist: DistributedFrame, keys,
     combine per-key dense ids in a mixed-radix int32 space, which bounds
     the cap at ``(cap+1)^k < 2^31``.
 
-    Under ``TFT_EXECUTOR=pjrt`` the aggregation program runs in the
-    native C++ core, whose dispatch marshals ids and value columns
-    through host numpy per call (the documented correctness-proof
-    trade, ``native_mesh`` module docstring) — so the device-residency
-    promises above (values stay on their shards; ``max_groups`` keys
-    never visit the host) hold on the default jax dispatch, not on the
-    native route. Latency-sensitive iterative workloads should keep the
-    jax path for this op.
-
     Skew: on the monoid host-key jax path, a key group holding more
     than ``TFT_HOT_KEY_FRACTION`` of the rows is **salted** across the
     data shards (``parallel/elastic.py``) — per-salt partials fold back
@@ -2116,67 +2006,40 @@ def _daggregate(fetches, dist: DistributedFrame, keys,
         P(axis, *([None] * (a.ndim - 1))) for a in arrays)
     out_specs = tuple(P() for _ in fetch_names)
 
-    # TFT_EXECUTOR=pjrt: the per-shard segment reduce + collective runs as
-    # ONE GSPMD executable in the native C++ core (the last mesh op to
-    # gain the route — reference property: every UDAF compaction ran in
-    # the C++ session, DebugRowOps.scala:617-662). The XLA scatter-add
-    # segment_sum flavor is forced: the Pallas flavor lowers to Mosaic
-    # custom calls the native core's backends cannot compile.
+    # one program per shape: the per-shard segment reduce + collective
     pkey = ("daggregate", mesh.mesh, axis, prog_groups,
             tuple((f, col_combiners[f]) for f in fetch_names),
             tuple((a.shape, str(a.dtype)) for a in arrays))
-    tables = trace = None
-    # salted programs stay on the jax path: the host-side fold below is
-    # the salting's second half, and the native route re-marshals anyway
-    nm = None if salt_plan is not None else _native_mesh(mesh)
-    if nm is not None:
-        def build_prog():
-            return shard_map(
-                _monoid_agg_shard_fn(fetch_names, col_combiners, axis,
-                                     prog_groups, seg_impl="xla"),
-                mesh=mesh.mesh, in_specs=in_specs, out_specs=out_specs)
-
-        in_shardings = [mesh.row_sharding(1)] + [
-            mesh.row_sharding(a.ndim) for a in arrays]
-        out_shardings = [mesh.replicated() for _ in fetch_names]
-        try:
-            tables = nm.run_sharded(pkey, build_prog,
-                                    [ids_dev] + list(arrays),
-                                    in_shardings, out_shardings, mesh)
-        except Exception as e:
-            _native_mesh_fallback(e)
-            tables = None
-    if tables is None:
-        window, bounded = None, False
-        if salt_plan is None:
-            ids_key = (("device", tuple(keys), max_groups) if device_keys
-                       else ("host", tuple(keys)))
-            window, bounded = _sum_window(
-                dist, ids_key, prog_ids, prog_groups,
-                [a for f, a in zip(fetch_names, arrays)
-                 if col_combiners[f] == "sum"])
-        # cache the jitted program (the closure is fresh per call, so
-        # jax's own jit cache would miss and retrace every dispatch)
-        fn, fresh = _collective_program(
-            pkey + (window,), lambda: jax.jit(shard_map(
-                _monoid_agg_shard_fn(fetch_names, col_combiners, axis,
-                                     prog_groups, window=window),
-                mesh=mesh.mesh, in_specs=in_specs, out_specs=out_specs)))
-        if window is not None:
-            counters.inc("segment_sum.sorted_window")
-        elif bounded:
-            counters.inc("segment_sum.window_fallback")
-        trace = current_trace()
-        t0 = 0.0
-        if trace is not None:
-            t0 = _trace_shards(trace, "daggregate", dist=dist)
-            for f in fetch_names:
-                trace.add("collective", name=COMBINERS[col_combiners[f]].ici,
-                          ts=t0, column=f, op="daggregate")
-        with span("daggregate.dispatch", {"chips": mesh.num_data_shards,
-                                          "table_groups": prog_groups}), \
-                span("compile.daggregate") if fresh else _NO_SPAN:
-            tables = fn(prog_ids, *arrays)
+    window, bounded = None, False
+    if salt_plan is None:
+        ids_key = (("device", tuple(keys), max_groups) if device_keys
+                   else ("host", tuple(keys)))
+        window, bounded = _sum_window(
+            dist, ids_key, prog_ids, prog_groups,
+            [a for f, a in zip(fetch_names, arrays)
+             if col_combiners[f] == "sum"])
+    # cache the jitted program (the closure is fresh per call, so
+    # jax's own jit cache would miss and retrace every dispatch)
+    fn, fresh = _collective_program(
+        pkey + (window,), lambda: jax.jit(shard_map(
+            _monoid_agg_shard_fn(fetch_names, col_combiners, axis,
+                                 prog_groups, window=window),
+            mesh=mesh.mesh, in_specs=in_specs, out_specs=out_specs)))
+    if window is not None:
+        counters.inc("segment_sum.sorted_window")
+    elif bounded:
+        counters.inc("segment_sum.window_fallback")
+    trace = current_trace()
+    t0 = 0.0
+    if trace is not None:
+        t0 = _trace_shards(trace, "daggregate", dist=dist)
+        for f in fetch_names:
+            trace.add("collective", name=COMBINERS[col_combiners[f]].ici,
+                      ts=t0, column=f, op="daggregate")
+    with span("daggregate.dispatch", {"chips": mesh.num_data_shards,
+                                      "table_groups": prog_groups}), \
+            span("compile.daggregate") if fresh else _NO_SPAN:
+        tables = fn(prog_ids, *arrays)
     counters.inc("mesh.dispatches")
     if mesh.num_data_shards > 1:
         counters.inc("mesh.allreduce_bytes", sum(
@@ -2409,35 +2272,6 @@ def _segmented_fold(comp, names, mesh: DeviceMesh, arrays, ids_dev,
         # single-row groups, where the scan never ran the combiner)
         return single_v(acc)
 
-    # TFT_EXECUTOR=pjrt: the whole generic-aggregation program — per-shard
-    # sort + segmented scan + scatter AND the cross-shard masked fold —
-    # compiles as one GSPMD executable in the native C++ core (cached on
-    # the Computation; un-routable programs latch to the jax path)
-    nm = _native_mesh(mesh)
-    if nm is not None:
-        def build_prog():
-            def prog(ids, *cols):
-                out = program(ids, *cols)
-                return tuple(out[f] for f in names)
-            return prog
-
-        in_shardings = [mesh.row_sharding(1)] + [
-            mesh.row_sharding(a.ndim) for a in arrays]
-        out_shardings = [mesh.replicated() for _ in names]
-        nkey = ("dagg_generic", mesh.mesh, axis, G,
-                tuple((f, a.shape, str(a.dtype))
-                      for f, a in zip(names, arrays)))
-        try:
-            outs = nm.run_sharded(nkey, build_prog,
-                                  [ids_dev] + list(arrays),
-                                  in_shardings, out_shardings, mesh,
-                                  owner=comp)
-        except Exception as e:
-            _native_mesh_fallback(e)
-            outs = None
-        if outs is not None:
-            return dict(zip(names, outs))
-
     cache = getattr(comp, "_tft_segfold_cache", None)
     if cache is None:
         cache = comp._tft_segfold_cache = OrderedDict()
@@ -2539,13 +2373,10 @@ def _generic_reduce(fetches, dist: DistributedFrame) -> Dict[str, np.ndarray]:
     on every shard's local rows in parallel (SPMD — pad-only shards compute
     a garbage partial that is statically sliced away), the ragged tail
     shard's valid prefix is re-reduced on its own, and the partials are
-    combined with one final stacked block-reduce. On the default jax
-    dispatch the only host transfer is the final one-cell result — the
-    reference's driver-collect analogue (``DebugRowOps.scala:511-512``),
-    with the per-shard data never leaving its device. (Under
-    ``TFT_EXECUTOR=pjrt`` the native route marshals the columns through
-    host numpy per call — the documented correctness-proof trade,
-    ``native_mesh`` module docstring.)
+    combined with one final stacked block-reduce. The only host transfer
+    is the final one-cell result — the reference's driver-collect
+    analogue (``DebugRowOps.scala:511-512``), with the per-shard data
+    never leaving its device.
     """
     schema = dist.schema
     comp = _cached_reduce_computation(fetches, schema, ("_input",),
@@ -2617,44 +2448,18 @@ def _generic_reduce(fetches, dist: DistributedFrame) -> Dict[str, np.ndarray]:
 
         return program
 
-    # TFT_EXECUTOR=pjrt: the whole generic reduce — per-shard partials,
-    # the ragged-tail re-reduce, and the final stacked combine — compiles
-    # as one GSPMD executable in the native C++ core
-    final = None
-    nm = _native_mesh(mesh)
-    if nm is not None:
-        def build_prog():
-            program = make_program()
-
-            def prog(*cols):
-                out = program(*cols)
-                return tuple(out[f] for f in names)
-            return prog
-
-        in_shardings = [mesh.row_sharding(a.ndim) for a in arrays]
-        out_shardings = [mesh.replicated() for _ in names]
-        try:
-            outs = nm.run_sharded(("dreduce_generic",) + key, build_prog,
-                                  arrays, in_shardings, out_shardings,
-                                  mesh, owner=comp)
-        except Exception as e:
-            _native_mesh_fallback(e)
-            outs = None
-        if outs is not None:
-            final = dict(zip(names, outs))
-    if final is None:
-        fn = cache.get(key)
-        if fn is None:
-            fn = cache[key] = jax.jit(make_program())
-        trace = current_trace()
-        t0 = (_trace_shards(trace, "dreduce_blocks", dist=dist)
-              if trace is not None else 0.0)
-        with span("dreduce_blocks.generic_dispatch"):
-            final = fn(*arrays)
-        counters.inc("mesh.dispatches")
-        if trace is not None:
-            _trace_mesh_done(trace, [final[f] for f in names], t0,
-                             "dreduce_blocks", mesh=mesh)
+    fn = cache.get(key)
+    if fn is None:
+        fn = cache[key] = jax.jit(make_program())
+    trace = current_trace()
+    t0 = (_trace_shards(trace, "dreduce_blocks", dist=dist)
+          if trace is not None else 0.0)
+    with span("dreduce_blocks.generic_dispatch"):
+        final = fn(*arrays)
+    counters.inc("mesh.dispatches")
+    if trace is not None:
+        _trace_mesh_done(trace, [final[f] for f in names], t0,
+                         "dreduce_blocks", mesh=mesh)
     out = {}
     for f in fetch_names:
         v = np.asarray(final[f])

@@ -29,13 +29,13 @@ Legality is proof-driven like PR 10: a map records only when its
 computation is PROVEN row-preserving (symbolic eval under the shared
 row symbol, ``optimize._row_preserving``), a filter only when its mask
 provably has block length. Anything else — trim/global maps, generic
-(non-monoid) reductions, ``dsort``, the native ``TFT_EXECUTOR=pjrt``
-route, multi-process meshes — materializes the pending chain and takes
-the unchanged per-op path. ``TFT_FUSE=0`` makes ``lazy()`` the identity,
-so the kill switch is bit-identical by construction; a fused execution
-failure the elastic layer cannot recover (an OOM, a permanent fault)
-replays the chain per-op (``dplan.fallbacks``) — fused execution never
-fails a query the per-op d-ops survive.
+(non-monoid) reductions, ``dsort``, multi-process meshes — materializes
+the pending chain and takes the unchanged per-op path. ``TFT_FUSE=0``
+makes ``lazy()`` the identity, so the kill switch is bit-identical by
+construction; a fused execution failure the elastic layer cannot
+recover (an OOM, a permanent fault) replays the chain per-op
+(``dplan.fallbacks``) — fused execution never fails a query the per-op
+d-ops survive.
 
 The elastic machinery applies at the FUSED boundary: the whole forcing
 runs through :func:`~..parallel.elastic.elastic_call`, so a classified
@@ -330,16 +330,12 @@ class LazyDistributedFrame:
 def lazy_frame(dist):
     """``DistributedFrame.lazy()`` backend: a recording view over
     ``dist``, or ``dist`` itself when recording cannot help
-    (``TFT_FUSE=0``, the native ``pjrt`` executor, multi-process meshes,
-    frames whose rows do not tile the data axis)."""
-    import os
-
+    (``TFT_FUSE=0``, multi-process meshes, frames whose rows do not
+    tile the data axis)."""
     if getattr(dist, "_tft_lazy_dist", False):
         return dist
     if not fuse_enabled():
         return dist
-    if os.environ.get("TFT_EXECUTOR") == "pjrt":
-        return dist  # the native route keeps the per-op dispatches
     if jax.process_count() > 1:
         return dist
     S = dist.mesh.num_data_shards
@@ -383,7 +379,7 @@ def record_map(fetches, lazy: LazyDistributedFrame, trim: bool,
     # call time (schema mismatches must not move to force time)
     out_schema = _ops._validate_map(comp, lazy.schema, block_level=True,
                                     trim=False)
-    if getattr(comp, "_native_dynamic", None) is not None:
+    if comp.opaque:
         return None
     if not _row_preserving(comp):
         return None  # the per-op runtime row-count check owns this
@@ -624,7 +620,7 @@ def _filter_fragment(comp, alive: Tuple[str, ...], mesh, cnt, env):
 def _agg_shard_fn(fetch_names, col_combiners, axis, prog_groups: int):
     """The per-shard monoid segment-reduce + collective — literally
     ``_daggregate``'s own fragment (``_monoid_agg_shard_fn``, one
-    definition for the eager, native, fused, and streaming routes)."""
+    definition for the eager, fused, and streaming routes)."""
     return _dist()._monoid_agg_shard_fn(fetch_names, dict(col_combiners),
                                         axis, prog_groups)
 

@@ -401,11 +401,6 @@ def build_plan(frame) -> Optional[ExecPlan]:
     unplanned, not failed."""
     if not enabled():
         return None
-    from ..engine.executor import BlockExecutor, default_executor
-    if type(default_executor()) is not BlockExecutor:
-        # a non-default process executor (native PJRT core) keeps the
-        # per-op path: fused chaining relies on keep_device dispatches
-        return None
     lin = linearize(frame)
     if lin is None:
         return None
@@ -431,7 +426,7 @@ def build_plan(frame) -> Optional[ExecPlan]:
     # back wholesale (all-or-nothing keeps error contracts identical)
     for o in ops:
         if o.kind == "map_blocks":
-            if getattr(o.comp, "_native_dynamic", None) is not None:
+            if o.comp.opaque:
                 return None  # foreign/static modules stay per-op
             if o.trim:
                 if not _uniform_lead(o.comp):
@@ -439,8 +434,7 @@ def build_plan(frame) -> Optional[ExecPlan]:
             elif not _row_preserving(o.comp):
                 return None
         elif o.kind == "map_rows":
-            if o.vcomp is None \
-                    or getattr(o.comp, "_native_dynamic", None) is not None:
+            if o.vcomp is None or o.comp.opaque:
                 return None
         elif o.kind == "filter":
             if not _mask_shaped(o.comp):

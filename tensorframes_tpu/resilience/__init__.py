@@ -34,10 +34,9 @@ can fail:
 Consumers: ``parallel/cluster.py`` (bootstrap timeout, retry, graceful
 single-process degradation), ``engine/executor.py`` (dispatch retry,
 exact-shape fallback from bucketed compiles, OOM split-block re-dispatch),
-``native_pjrt.py`` (native core dispatch retry), and ``serve/`` — the
-multi-tenant scheduler's load rejections (:class:`QueueFull`,
-:class:`OverQuota`, :class:`AdmissionDeadline`) are classified here so
-clients and retry loops see ``rejected`` / ``over_quota`` /
+and ``serve/`` — the multi-tenant scheduler's load rejections
+(:class:`QueueFull`, :class:`OverQuota`, :class:`AdmissionDeadline`) are
+classified here so clients and retry loops see ``rejected`` / ``over_quota`` /
 ``deadline_admission`` kinds instead of anonymous RuntimeErrors. The
 degradation matrix — what falls back versus what fails fast — is
 documented in ``docs/resilience.md``.

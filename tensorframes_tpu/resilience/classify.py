@@ -23,7 +23,7 @@ Three buckets, three responses:
   diagnostics): fail fast, loudly, once.
 
 Classification is string-based on purpose: the error types that matter
-(``XlaRuntimeError``, ``PjrtCoreError``, grpc errors) cross a C++/Python
+(``XlaRuntimeError``, grpc errors) cross a C++/Python
 boundary where the *status word* in the message is the stable contract,
 not the Python class.
 """

@@ -27,7 +27,6 @@ dispatch     ``engine.executor.BlockExecutor`` block dispatch
 pad_compile  ``engine.executor.PaddingExecutor`` bucketed-compile path
 oom          ``engine.executor.BlockExecutor`` dispatch, OOM-shaped
 drain        ``engine.executor.PendingBlock.drain`` pipelined readback
-pjrt_execute ``native_pjrt.PjrtBlockExecutor`` native-core dispatch
 dmap         ``parallel.distributed.dmap_blocks`` mesh dispatch
 batch        ``stream.runtime.StreamHandle`` per-batch processing
 device       ``parallel.elastic.elastic_call`` mesh-op dispatch boundary
@@ -101,7 +100,6 @@ _SITES = {
     "pad_compile": "engine.executor.PaddingExecutor bucketed compile",
     "oom": "engine.executor.BlockExecutor dispatch, OOM-shaped",
     "drain": "engine.executor.PendingBlock.drain pipelined readback",
-    "pjrt_execute": "native_pjrt.PjrtBlockExecutor native-core dispatch",
     "dmap": "parallel.distributed.dmap_blocks mesh dispatch",
     "batch": "stream.runtime.StreamHandle per-batch processing",
     "device": "parallel.elastic.elastic_call dispatch (device-loss "

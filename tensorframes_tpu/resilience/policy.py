@@ -1,7 +1,7 @@
 """Retry policy + deadlines: the control knobs of the resilience layer.
 
 :class:`RetryPolicy` is the one retry loop in the package — cluster
-bootstrap, engine dispatch, and the native core all call
+bootstrap, engine dispatch, and mesh dispatch all call
 :meth:`RetryPolicy.call` rather than hand-rolling ``for attempt in
 range(...)`` loops, so backoff, deadline accounting, counter export and
 log narration behave identically at every layer.

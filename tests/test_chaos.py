@@ -70,7 +70,7 @@ import chaos_soak  # noqa: E402
 # test_site_table_matches_literals pins this list to the real table
 ALL_SITES = ("batch", "cluster_init", "compile", "device", "disk",
              "dispatch", "dmap", "drain", "oom", "pad_compile",
-             "perf", "pjrt_execute", "preempt", "worker")
+             "perf", "preempt", "worker")
 
 
 @pytest.fixture(autouse=True)

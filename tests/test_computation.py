@@ -1,13 +1,17 @@
 """Computation IR tests: tracing, shape inference, serialization,
 analyze_graph validation (the TFInitializationSuite/analyzeGraph analogue)."""
 
+import json
+import struct
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import export as jax_export
 
 from tensorframes_tpu import dtypes as dt
 from tensorframes_tpu.computation import (
-    Computation, TensorSpec, analyze_graph)
+    _MAGIC, Computation, TensorSpec, analyze_graph)
 from tensorframes_tpu.shape import Shape, Unknown
 
 
@@ -82,6 +86,36 @@ def test_serialize_roundtrip():
     out = c2({"x": x})
     np.testing.assert_allclose(np.asarray(out["z"]), x * 2 + 1)
     np.testing.assert_allclose(np.asarray(out["m"]), x.min(axis=0))
+
+
+def test_deserialize_skips_a_raw_module_section():
+    # older blobs carry a "native" header entry and the raw StableHLO
+    # module between the header and the jax.export payload
+    c = Computation.trace(
+        lambda x: {"z": x * 2 + 1, "m": jnp.min(x, axis=0)},
+        specs(x=(dt.double, Shape(Unknown, 2))))
+    blob = c.serialize()
+    off = len(_MAGIC)
+    (hlen,) = struct.unpack_from("<I", blob, off)
+    header = json.loads(blob[off + 4:off + 4 + hlen].decode("utf-8"))
+    payload = blob[off + 4 + hlen:]
+    exported = jax_export.deserialize(payload)
+    module = exported.mlir_module_serialized
+    header["native"] = {
+        "cc_version": exported.calling_convention_version,
+        "platforms": list(exported.platforms),
+        "module_len": len(module),
+        "arg_dtypes": [str(np.dtype(a.dtype)) for a in exported.in_avals],
+    }
+    head = json.dumps(header).encode("utf-8")
+    old = _MAGIC + struct.pack("<I", len(head)) + head + module + payload
+    c2 = Computation.deserialize(old)
+    assert c2.opaque
+    assert c2.output_names == ["m", "z"]
+    x = np.arange(8.0).reshape(4, 2)
+    got, want = c2({"x": x}), c({"x": jnp.asarray(x)})
+    for n in ("m", "z"):
+        np.testing.assert_array_equal(np.asarray(got[n]), np.asarray(want[n]))
 
 
 def test_deserialize_rejects_garbage():

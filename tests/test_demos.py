@@ -60,27 +60,6 @@ def test_kmeans_device_resident_step_matches(km_data):
     assert got_d == pytest.approx(want_d, rel=1e-5)
 
 
-def test_kmeans_native_resident_loop_matches(km_data, monkeypatch):
-    # the whole iteration loop in the native C++ core with device-held
-    # loop state (variant E) must match iterating the numpy step
-    from tensorframes_tpu import native_pjrt
-    from tensorframes_tpu.parallel.distributed import distribute
-    from tensorframes_tpu.parallel.mesh import local_mesh
-
-    if not native_pjrt.available():
-        pytest.skip("libtfrpjrt.so not built")
-    monkeypatch.setenv("TFT_EXECUTOR", "pjrt")
-    df, init, _ = km_data
-    pts = np.concatenate([b.dense("features") for b in df.blocks()])
-    dist = distribute(df, local_mesh(4))
-    iters = 7
-    got = km.kmeans_native_resident(dist, init, num_iters=iters)
-    want = np.asarray(init, np.float64)
-    for _ in range(iters):
-        want, _ = _numpy_step(pts, want)
-    np.testing.assert_allclose(got, want, rtol=1e-6)
-
-
 # -- harmonic / geometric mean ----------------------------------------------
 
 def test_harmonic_mean_per_key():

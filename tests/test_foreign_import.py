@@ -5,7 +5,8 @@ Python serialized a GraphDef and the engine ran it (``core.py:37-40``,
 ``TensorFlowOps.scala:46-52``). The analogue here: a module produced by
 ANY exporter (plain ``jax.jit(...).lower()``, not this library's
 ``serialize``) enters through ``builder.map_blocks_builder`` with explicit
-specs and runs on both executors.
+specs and runs through ``jax.export``'s calling convention. Such modules
+are opaque to the planners: chains that hold one stay per-op.
 """
 
 import numpy as np
@@ -18,7 +19,10 @@ import tensorframes_tpu as tft
 from tensorframes_tpu import builder, dtypes as _dt
 from tensorframes_tpu.computation import Computation, TensorSpec
 from tensorframes_tpu.engine import ops as _ops
-from tensorframes_tpu.shape import Shape
+from tensorframes_tpu.parallel import distribute, dmap_blocks, local_mesh
+from tensorframes_tpu.plan.optimize import build_plan
+from tensorframes_tpu.shape import Shape, Unknown
+from tensorframes_tpu.utils.tracing import counters
 
 
 def _foreign_module_text(n=6, dtype=jnp.float64):
@@ -67,8 +71,6 @@ class TestFromStablehlo:
                                    np.arange(6.0) * 2.0 + 2.0)
 
     def test_unknown_dims_rejected(self):
-        from tensorframes_tpu.shape import Unknown
-
         with pytest.raises(ValueError, match="unknown dims"):
             Computation.from_stablehlo(
                 _foreign_module_text(),
@@ -87,23 +89,42 @@ class TestFromStablehlo:
             builder.map_blocks_builder(df).graph(b"\x00\x01garbage")
 
 
-class TestForeignOnNativeExecutor:
-    @pytest.fixture
-    def native(self):
-        from tensorframes_tpu import native_pjrt
+_ROWS = 8
 
-        if not native_pjrt.available():
-            pytest.skip("libtfrpjrt.so not built")
-        return native_pjrt
 
-    def test_map_blocks_via_pjrt_core(self, native):
-        comp = Computation.from_stablehlo(
-            _foreign_module_text(),
-            [TensorSpec("x", _dt.double, Shape(6))],
-            [TensorSpec("z", _dt.double, Shape(6))])
-        assert comp._native_dynamic is not None  # jax-free compile path
-        ex = native.PjrtBlockExecutor(backend="cpu")
-        df = tft.frame({"x": np.arange(6.0)}, num_partitions=1)
-        rows = _ops.map_blocks(comp, df, executor=ex).collect()
-        assert [r["z"] for r in rows] == [v * 2.0 + 1.0
-                                          for v in np.arange(6.0)]
+def _opaque(source):
+    if source == "from_stablehlo":
+        return Computation.from_stablehlo(
+            _foreign_module_text(_ROWS),
+            [TensorSpec("x", _dt.double, Shape(_ROWS))],
+            [TensorSpec("z", _dt.double, Shape(_ROWS))])
+    traced = Computation.trace(
+        lambda x: {"z": x * 2.0 + 1.0},
+        [TensorSpec("x", _dt.double, Shape(Unknown))])
+    return Computation.deserialize(traced.serialize())
+
+
+@pytest.mark.parametrize("source", ["from_stablehlo", "deserialize"])
+@pytest.mark.parametrize("chain", ["host", "mesh"])
+def test_opaque_computation_stays_per_op(source, chain):
+    comp = _opaque(source)
+    assert comp.opaque
+    df = tft.frame({"x": np.arange(float(_ROWS))}, num_partitions=1)
+    want = np.arange(float(_ROWS)) * 2.0 + 1.0
+    if chain == "host":
+        lazy = df.map_blocks(comp).map_blocks(lambda z: {"w": z + 1.0})
+        assert build_plan(lazy) is None
+        eager = _ops.map_blocks(comp, df).collect()
+        rows = lazy.collect()
+        assert lazy._plan_info is None
+        assert [r["z"] for r in rows] == [r["z"] for r in eager]
+        assert [r["w"] for r in rows] == list(want + 1.0)
+    else:
+        dist = distribute(df, local_mesh(4))
+        before = counters.get("dplan.recorded_ops")
+        out = dmap_blocks(comp, dist.lazy())
+        assert counters.get("dplan.recorded_ops") == before
+        rows = out.collect_frame().collect()
+        eager = dmap_blocks(comp, dist).collect_frame().collect()
+        assert [r["z"] for r in rows] == [r["z"] for r in eager]
+    assert [r["z"] for r in rows] == list(want)

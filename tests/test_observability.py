@@ -122,9 +122,9 @@ class TestCorrelation:
 
     def test_pipeline_worker_thread_events_attach_to_query(self,
                                                            monkeypatch):
-        """An executor that dispatches on its own worker thread (the
-        native-PJRT submit pattern, via wrap_context) records events that
-        land on the submitting query's trace."""
+        """An executor that dispatches on its own worker thread (via
+        wrap_context) records events that land on the submitting query's
+        trace."""
         _depth(monkeypatch, 3)
         tracing.enable()
         inner = BlockExecutor()

@@ -15,7 +15,6 @@ import functools
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 import tensorframes_tpu as tft
@@ -186,29 +185,6 @@ def test_folded_daggregate_is_unchanged(tpu_route):
     for c in ("k", "v1", "v2"):
         assert np.array_equal(got[c], want[c])
     np.testing.assert_allclose(got["w"], want["w"], rtol=1e-6)
-
-
-def test_native_route_keeps_scatter_add(tpu_route, monkeypatch):
-    """The native core's program (``TFT_EXECUTOR=pjrt``) is built with
-    ``seg_impl="xla"`` and no window: no sort, no span read."""
-    built = []
-
-    class Native:
-        def run_sharded(self, key, build_prog, args, in_sh, out_sh, mesh):
-            prog = build_prog()
-            built.append(jax.make_jaxpr(prog)(*args))
-            return list(jax.jit(prog)(*args))
-
-    monkeypatch.setattr(D, "_native_mesh", lambda mesh: Native())
-    keys = _keys("uniform_2e4")
-    df = _frame(keys)
-    dist = distribute(df, local_mesh(4))
-    out, moved = _counted(lambda: daggregate(AGG, dist, "k",
-                                             max_groups=20_000))
-    assert moved == [0, 0]
-    assert built and "= sort[" not in str(built[0])
-    assert "scatter-add" in str(built[0])
-    _assert_equal_sums(_tables(out), _reference(df))
 
 
 def test_off_the_tpu_scatter_add_is_kept():
