@@ -46,7 +46,10 @@ Three routes, by the group count:
   the ids alone, so a caller computes it once per id set
   (``daggregate`` caches it beside its group ids, on TPU only:
   :func:`window_wanted`). Floats and integers of 32 bits or fewer take
-  it, each as in the one-hot kernel.
+  it, each as in the one-hot kernel. Integer columns of one value a row
+  whose range is known and narrow ride in the sort key beside the id
+  (:func:`sort_key_plan`): the sort carries fewer operands, and each
+  packed value unpacks to itself, so integer sums stay exact.
 - **scatter-add** (``jax.ops.segment_sum``): off the TPU, for 64-bit
   integers and bools, past ``pallas_fits`` without a window, and where
   the span is wider than a window may be (:func:`window_fits`: skew, or
@@ -61,7 +64,7 @@ inf to that value's own group alone, as scatter-add does.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -72,7 +75,8 @@ from ..utils.tracing import counters
 from ._pallas_mesh import interpret_blocked_by_vma, vma_union
 
 __all__ = ["segment_sum", "segment_sums", "pallas_fits", "sorted_block_span",
-           "window_block_rows", "window_fits", "window_wanted"]
+           "window_block_rows", "window_fits", "window_wanted",
+           "packable", "sort_key_plan", "SortKeyPlan"]
 
 # v5e's default scoped VMEM limit; the kernel's blocks must fit inside it
 VMEM_BUDGET_BYTES = 16 << 20
@@ -352,6 +356,102 @@ def window_fits(span: int, block_rows: int, columns) -> bool:
         span, max(d.values()), block_rows) <= VMEM_BUDGET_BYTES)
 
 
+def packable(column) -> bool:
+    """Whether ``column`` (an array or shape struct) may ride in the
+    sorted-window route's sort key, given its range: an integer of 32
+    bits or fewer, one value a row."""
+    return _window_dtype(column.dtype) == "int" and _width(column.shape) == 1
+
+
+# the sort key is one 32-bit word
+SORT_KEY_BITS = 32
+
+
+class SortKeyPlan(NamedTuple):
+    """How the sorted-window route packs its sort key: ``id + 1`` (-1,
+    invalid, becomes 0 and leads) in the top ``id_bits`` bits, then each
+    packed column's ``value - min`` in ``widths[k]`` bits, in column
+    order. ``widths`` holds one entry a column: its bits, or None where
+    the column stays a payload operand of the sort. Widths, not the
+    minimums, so that frames whose ranges differ only in where they
+    start share one compiled program; the minimums are operands."""
+
+    id_bits: int
+    widths: tuple
+
+    @property
+    def packed(self) -> int:
+        return sum(w is not None for w in self.widths)
+
+    @property
+    def key_bits(self) -> int:
+        return self.id_bits + sum(w for w in self.widths if w is not None)
+
+    @property
+    def dtype(self):
+        """uint32 where every bit is taken, else int32."""
+        return jnp.uint32 if self.key_bits == SORT_KEY_BITS else jnp.int32
+
+
+def sort_key_plan(num_segments: int, ranges) -> SortKeyPlan:
+    """Pack integer columns into the sort key by their ranges.
+
+    ``ranges`` holds one entry a column: its exact ``(min, max)`` over the
+    rows that count, or None for a column that cannot ride in the key
+    (:func:`packable`). The id takes ``bit_length(num_segments)`` bits;
+    each column, in order, takes ``bit_length(max - min)`` bits (0 for a
+    constant column) where the key's total stays within 32, and stays a
+    payload operand where it would not."""
+    id_bits = int(num_segments).bit_length()
+    bits, widths = id_bits, []
+    for r in ranges:
+        w = None if r is None else (int(r[1]) - int(r[0])).bit_length()
+        if w is not None and bits + w <= SORT_KEY_BITS:
+            bits += w
+        else:
+            w = None
+        widths.append(w)
+    return SortKeyPlan(id_bits, tuple(widths))
+
+
+def _as_u32(rows):
+    """An integer row of 32 bits or fewer as uint32 bits: signed values
+    sign-extended, so that ``value - min`` wraps to the true distance."""
+    if jnp.issubdtype(rows.dtype, jnp.signedinteger):
+        return jax.lax.bitcast_convert_type(rows.astype(jnp.int32),
+                                            jnp.uint32)
+    return rows.astype(jnp.uint32)
+
+
+def _packed_key(ids, packed_rows, mins, plan: SortKeyPlan):
+    """The sort key of :class:`SortKeyPlan`: ``ids`` clean (-1 invalid),
+    ``packed_rows`` the packed columns' rows and ``mins`` their uint32
+    minimums. An invalid row's fields are 0, whatever its values."""
+    valid = ids >= 0
+    key = (ids + 1).astype(jnp.uint32)
+    widths = [w for w in plan.widths if w is not None]
+    for k, (rows, w) in enumerate(zip(packed_rows, widths)):
+        field = jnp.where(valid, _as_u32(rows) - mins[k], jnp.uint32(0))
+        key = (key << w) | field
+    return jax.lax.bitcast_convert_type(key, plan.dtype)
+
+
+def _unpack_key(key, mins, plan: SortKeyPlan):
+    """The ids (-1 where invalid) and each packed column's int32 rows
+    (the value's int32 bits) of sorted keys of :func:`_packed_key`."""
+    key = jax.lax.bitcast_convert_type(key, jnp.uint32)
+    widths = [w for w in plan.widths if w is not None]
+    shift = sum(widths)
+    ids = (key >> shift).astype(jnp.int32) - 1
+    rows = []
+    for k, w in enumerate(widths):
+        shift -= w
+        field = (key >> shift) & jnp.uint32((1 << w) - 1)
+        rows.append(jax.lax.bitcast_convert_type(field + mins[k],
+                                                 jnp.int32))
+    return ids, rows
+
+
 def _width(shape) -> int:
     d = 1
     for t in shape[1:]:
@@ -428,7 +528,8 @@ def _window_kernel(ids_ref, *refs, rows: int, block_rows: int, lanes: int,
 
 
 def _window_partials(sorted_ids, int_rows, float_rows, *, block_rows: int,
-                     lanes: int, limbs: int, interpret: bool):
+                     lanes: int, limbs: int, interpret: bool,
+                     fuse_inputs: bool = False):
     """Per-block window partials of sorted rows by the
     ``segment_sum_window`` kernel: ``[d, nblocks * W]`` for the int32 rows
     and for the f32 rows (None where there are none). ``interpret`` runs
@@ -455,7 +556,8 @@ def _window_partials(sorted_ids, int_rows, float_rows, *, block_rows: int,
         out_specs=out_specs,
         out_shape=out_shape,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
+            dimension_semantics=("parallel",),
+            allow_input_fusion=[True] * len(args) if fuse_inputs else None),
         interpret=pltpu.InterpretParams() if interpret else False,
         name="segment_sum_window",       # the kernel's name in a trace
     )(*args)
@@ -481,32 +583,46 @@ def _combine(partials, first, span: int, lanes: int, num_segments: int):
 
 
 def _sorted_window_sums(columns, segment_ids, num_segments: int,
-                        block_rows: int, span: int, interpret: bool):
+                        block_rows: int, span: int, interpret: bool,
+                        plan: Optional[SortKeyPlan] = None, mins=None):
     """:func:`segment_sums` on the sorted-window route, for columns whose
-    dtypes the kernel takes."""
+    dtypes the kernel takes; ``plan`` and ``mins`` pack columns into the
+    sort key (:func:`sort_key_plan`)."""
     n = segment_ids.shape[0]
     if n == 0:
         return [jnp.zeros((num_segments,) + c.shape[1:], c.dtype)
                 for c in columns]
-    # one sort, keyed on the ids, carries every column's rows; pad and
-    # out-of-range rows (-1) lead
+    widths = plan.widths if plan is not None else (None,) * len(columns)
+    # one sort, keyed on the ids and the packed columns, carries the
+    # other columns' rows; pad and out-of-range rows (-1) lead
     rows_of = [c.reshape(n, _width(c.shape)) for c in columns]
-    operands = [_clean_ids(segment_ids, num_segments)]
-    for r in rows_of:
-        operands.extend(r[:, k] for k in range(r.shape[1]))
+    ids = _clean_ids(segment_ids, num_segments)
+    packed = [r[:, 0] for r, w in zip(rows_of, widths) if w is not None]
+    operands = [_packed_key(ids, packed, mins, plan) if packed else ids]
+    for r, w in zip(rows_of, widths):
+        if w is None:
+            operands.extend(r[:, k] for k in range(r.shape[1]))
     srt = jax.lax.sort(operands, num_keys=1, is_stable=False)
-    sorted_ids, it = srt[0], iter(srt[1:])
+    if packed:
+        sorted_ids, unpacked = _unpack_key(srt[0], mins, plan)
+    else:
+        sorted_ids, unpacked = srt[0], []
+    it, unpacked = iter(srt[1:]), iter(unpacked)
     int_rows, float_rows, slots = [], [], []
     limbs = 0
-    for c, r in zip(columns, rows_of):
-        sub = [next(it) for _ in range(r.shape[1])]
+    for c, r, w in zip(columns, rows_of, widths):
+        if w is not None:
+            sub = [next(unpacked)]           # already the value's int32
+        else:
+            sub = [next(it) for _ in range(r.shape[1])]
         if _window_dtype(c.dtype) == "int":
             limbs = max(limbs, c.dtype.itemsize)
             slots.append(("int", len(int_rows), len(sub)))
-            int_rows.extend(
-                jax.lax.bitcast_convert_type(s, jnp.int32)
-                if c.dtype.itemsize == 4 else s.astype(jnp.int32)
-                for s in sub)
+            if w is None:
+                sub = [jax.lax.bitcast_convert_type(s, jnp.int32)
+                       if c.dtype.itemsize == 4 else s.astype(jnp.int32)
+                       for s in sub]
+            int_rows.extend(sub)
         else:
             slots.append(("float", len(float_rows), len(sub)))
             float_rows.extend(s.astype(jnp.float32) for s in sub)
@@ -514,7 +630,8 @@ def _sorted_window_sums(columns, segment_ids, num_segments: int,
     first, _ = _block_ends(sorted_ids, block_rows)
     part_int, part_float = _window_partials(
         sorted_ids, int_rows, float_rows, block_rows=block_rows,
-        lanes=lanes, limbs=limbs, interpret=interpret)
+        lanes=lanes, limbs=limbs, interpret=interpret,
+        fuse_inputs=bool(packed))
     tables = {
         route: _combine(p, first, span, lanes, num_segments)
         for route, p in (("int", part_int), ("float", part_float))
@@ -532,7 +649,7 @@ def _sorted_window_sums(columns, segment_ids, num_segments: int,
 
 def segment_sums(columns, segment_ids: jax.Array, num_segments: int,
                  window: Optional[tuple] = None,
-                 impl: Optional[str] = None) -> list:
+                 impl: Optional[str] = None, mins=None) -> list:
     """:func:`segment_sum` of each of ``columns`` by the same ids.
 
     ``window``: ``(block_rows, span)``, with ``span`` at least
@@ -546,6 +663,12 @@ def segment_sums(columns, segment_ids: jax.Array, num_segments: int,
     :func:`window_fits` must hold. Without a window, with ``impl="xla"``,
     or for 64-bit and bool columns, each column takes :func:`segment_sum`
     with ``impl``.
+
+    ``window`` may add a third entry, a :class:`SortKeyPlan` over
+    ``columns``: the columns it packs ride in the sort key, and ``mins``
+    (uint32, one a packed column, in order; a signed minimum as its
+    int32 bits) are their minimums. Every row with a valid id must hold a
+    value in its column's range; the others may hold anything.
 
     ``impl`` on the window route: ``"pallas"`` compiles the kernel for the
     TPU, ``"interpret"`` runs it in Pallas's TPU interpreter (inside a
@@ -565,14 +688,24 @@ def segment_sums(columns, segment_ids: jax.Array, num_segments: int,
         if impl is None:
             impl = ("pallas" if jax.default_backend() == "tpu"
                     else "interpret")
-        block_rows, span = window
+        block_rows, span = window[:2]
+        plan = window[2] if len(window) > 2 else None
         if impl == "pallas" and block_rows % WINDOW_MIN_BLOCK_ROWS:
             raise ValueError(
                 f"the window kernel reads 1-D blocks of whole (8, 128) "
                 f"tiles: block_rows must be a multiple of "
                 f"{WINDOW_MIN_BLOCK_ROWS}, got {block_rows}")
+        if plan is not None:
+            if any(w is not None and not packable(c)
+                   for c, w in zip(columns, plan.widths)):
+                raise ValueError(
+                    "a sort key plan packs only integer columns of 32 "
+                    "bits or fewer, one value a row")
+            plan = plan._replace(widths=tuple(
+                w for w, t in zip(plan.widths, take) if t))
         sums = iter(_sorted_window_sums(
             picked, segment_ids, num_segments, block_rows, span,
-            interpret=(impl == "interpret")))
+            interpret=(impl == "interpret"), plan=plan,
+            mins=None if mins is None else jnp.asarray(mins, jnp.uint32)))
         out = [next(sums) if t else o for o, t in zip(out, take)]
     return out

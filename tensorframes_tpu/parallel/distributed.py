@@ -27,7 +27,7 @@ from __future__ import annotations
 import contextlib
 import functools
 from collections import OrderedDict
-from typing import Dict, Mapping, Optional
+from typing import Dict, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -245,6 +245,10 @@ class DistributedFrame:
         # a long-lived frame swept over many key tuples / caps must not
         # retain HBM indefinitely (same policy as _dsort_cache).
         self._group_ids_cache: "OrderedDict[tuple, tuple]" = OrderedDict()
+        # each integer column's exact (min, max) over the valid rows, as
+        # the sorted-window route reads it (_sort_key_plan): Python ints,
+        # a pair a column, kept apart from the capped id cache
+        self._column_ranges: Dict[str, Tuple[int, int]] = {}
 
     @property
     def padded_rows(self) -> int:
@@ -1355,9 +1359,7 @@ def _collective_reduce(col_combiners: Mapping[str, str],
     fn, fresh = _collective_program(key, lambda: jax.jit(shard_map(
         _collective_shard_fn(names, combs, axis), mesh=mesh.mesh,
         in_specs=in_specs, out_specs=tuple(P() for _ in arrays))))
-    nv_dev = jax.make_array_from_callback(
-        (mesh.num_data_shards,), mesh.row_sharding(1),
-        lambda idx: dist.per_shard_valid().astype(np.int32)[idx])
+    nv_dev = _valid_counts(dist)
     trace = current_trace()
     t0 = 0.0
     if trace is not None:
@@ -1461,18 +1463,23 @@ def _monoid_agg_shard_fn(fetch_names, col_combiners, axis,
     (``plan/dist.py``), so the three dispatch paths can never drift. The
     ``sum`` columns go to ``segment_sums`` together, which takes the
     sorted-window route where ``window`` is given (:func:`_sum_window`).
+    A window whose third entry, a sort key plan (:func:`_sort_key_plan`),
+    packs columns makes the fragment take the packed columns' minimums
+    as its second operand, replicated.
     """
     from ..ops.segment_reduce import segment_sums as _segsums
 
     sum_names = [f for f in fetch_names if col_combiners[f] == "sum"]
+    packs = window is not None and len(window) > 2
 
     # the compiled program is named after this function (jit_<name>):
     # a stable name the device trace shows
-    def daggregate_shard_fn(ids_local, *vals_local):
+    def daggregate_shard_fn(ids_local, *args):
+        mins, vals_local = (args[0], args[1:]) if packs else (None, args)
         cols = dict(zip(fetch_names, vals_local))
         summed = dict(zip(sum_names, _segsums(
             [cols[f] for f in sum_names], ids_local, prog_groups,
-            window=window)))
+            window=window, mins=mins)))
         outs = []
         for f, v in zip(fetch_names, vals_local):
             cname = col_combiners[f]
@@ -1548,6 +1555,79 @@ def _window_span_program(mesh, axis: str, num_segments: int,
             sorted_block_span(ids, num_segments, block_rows), axis)
 
     return jax.jit(shard_map(window_span, mesh=mesh, in_specs=P(axis),
+                             out_specs=P()))
+
+
+def _sort_key_plan(dist: DistributedFrame, names, arrays,
+                   num_segments: int):
+    """The sorted-window route's sort key plan for the ``sum`` columns
+    ``names`` (``arrays``) of a dispatch, and the packed columns'
+    minimums as uint32, replicated over the mesh (None where none packs).
+
+    Each integer column of one value a row has its exact range over the
+    frame's valid rows read once, for every such column not yet read in
+    one program over the mesh (span ``daggregate.column_ranges``), and
+    cached on the frame: a column's range does not depend on the key.
+    The plan then packs what fits beside the ids
+    (``ops/segment_reduce.sort_key_plan``)."""
+    from ..ops.segment_reduce import packable, sort_key_plan
+
+    ranges = dist._column_ranges
+    want = [(n, a) for n, a in zip(names, arrays)
+            if packable(a) and n not in ranges]
+    attrs = {"columns": len(want)}
+    with span("daggregate.column_ranges", attrs) if want else _NO_SPAN:
+        if want:
+            mesh = dist.mesh
+            prog = _column_ranges_program(mesh.mesh, mesh.data_axis)
+            got = jax.device_get(prog(_valid_counts(dist),
+                                      *[a for _, a in want]))
+            for (n, _), (lo, hi) in zip(want, got):
+                ranges[n] = (int(lo), int(hi))
+        plan = sort_key_plan(num_segments, [
+            ranges[n] if packable(a) else None
+            for n, a in zip(names, arrays)])
+        attrs.update(packed=plan.packed, key_bits=plan.key_bits)
+    if not plan.packed:
+        return plan, None
+    mins = np.array([ranges[n][0] & 0xFFFFFFFF
+                     for n, w in zip(names, plan.widths) if w is not None],
+                    np.uint32)
+    return plan, jax.make_array_from_callback(
+        mins.shape, dist.mesh.replicated(), lambda idx: mins[idx])
+
+
+def _valid_counts(dist: DistributedFrame) -> jax.Array:
+    """Each shard's valid-row count, sharded over the data axis: pads are
+    masked wherever they fall (a multi-host frame pads per process, not
+    in a global suffix)."""
+    mesh = dist.mesh
+    return jax.make_array_from_callback(
+        (mesh.num_data_shards,), mesh.row_sharding(1),
+        lambda idx: dist.per_shard_valid().astype(np.int32)[idx])
+
+
+@functools.lru_cache(maxsize=16)
+def _column_ranges_program(mesh, axis: str):
+    """One program: each column's exact ``[min, max]`` over the valid
+    rows of every shard, the min and max over the data axis. Narrow
+    integers are widened to int32, uint32 kept."""
+
+    def column_ranges(nv, *cols):
+        valid = jnp.arange(cols[0].shape[0]) < nv[0]
+        out = []
+        for c in cols:
+            c = c.reshape(-1)
+            if c.dtype != jnp.uint32:
+                c = c.astype(jnp.int32)
+            info = jnp.iinfo(c.dtype)
+            lo = jnp.min(jnp.where(valid, c, info.max))
+            hi = jnp.max(jnp.where(valid, c, info.min))
+            out.append(jnp.stack([jax.lax.pmin(lo, axis),
+                                  jax.lax.pmax(hi, axis)]))
+        return tuple(out)
+
+    return jax.jit(shard_map(column_ranges, mesh=mesh, in_specs=P(axis),
                              out_specs=P()))
 
 
@@ -1892,7 +1972,9 @@ def daggregate(fetches, dist: DistributedFrame, keys,
       holds, the ``sum`` columns take the sorted-window route together
       (one sort carrying them all, ``ops/segment_reduce.py``), given the
       largest block span of the sorted ids, read once and cached beside
-      the ids (:func:`_sum_window`);
+      the ids (:func:`_sum_window`); integer columns whose exact range,
+      read once and cached on the frame, is narrow ride in the sort's
+      key (:func:`_sort_key_plan`);
     - ``fetches`` is a computation (block-level ``<col>_input`` reduce,
       the UDAF contract): per-shard sort-by-id + segmented
       ``associative_scan`` whose pair-combiner IS the user computation on
@@ -2010,14 +2092,24 @@ def _daggregate(fetches, dist: DistributedFrame, keys,
     pkey = ("daggregate", mesh.mesh, axis, prog_groups,
             tuple((f, col_combiners[f]) for f in fetch_names),
             tuple((a.shape, str(a.dtype)) for a in arrays))
-    window, bounded = None, False
+    window, bounded, mins = None, False, None
     if salt_plan is None:
         ids_key = (("device", tuple(keys), max_groups) if device_keys
                    else ("host", tuple(keys)))
-        window, bounded = _sum_window(
-            dist, ids_key, prog_ids, prog_groups,
-            [a for f, a in zip(fetch_names, arrays)
-             if col_combiners[f] == "sum"])
+        sum_names = [f for f in fetch_names if col_combiners[f] == "sum"]
+        sum_arrays = [a for f, a in zip(fetch_names, arrays)
+                      if col_combiners[f] == "sum"]
+        window, bounded = _sum_window(dist, ids_key, prog_ids, prog_groups,
+                                      sum_arrays)
+        if window is not None:
+            plan, mins = _sort_key_plan(dist, sum_names, sum_arrays,
+                                        prog_groups)
+            if mins is not None:
+                # the plan, widths only, keys the program; the minimums
+                # are its operand, so frames that differ only in those
+                # share one compile
+                window += (plan,)
+                in_specs = in_specs[:1] + (P(),) + in_specs[1:]
     # cache the jitted program (the closure is fresh per call, so
     # jax's own jit cache would miss and retrace every dispatch)
     fn, fresh = _collective_program(
@@ -2027,6 +2119,9 @@ def _daggregate(fetches, dist: DistributedFrame, keys,
             mesh=mesh.mesh, in_specs=in_specs, out_specs=out_specs)))
     if window is not None:
         counters.inc("segment_sum.sorted_window")
+        if mins is not None:
+            counters.inc("segment_sum.packed_key")
+            counters.inc("segment_sum.packed_columns", plan.packed)
     elif bounded:
         counters.inc("segment_sum.window_fallback")
     trace = current_trace()
@@ -2039,7 +2134,7 @@ def _daggregate(fetches, dist: DistributedFrame, keys,
     with span("daggregate.dispatch", {"chips": mesh.num_data_shards,
                                       "table_groups": prog_groups}), \
             span("compile.daggregate") if fresh else _NO_SPAN:
-        tables = fn(prog_ids, *arrays)
+        tables = fn(prog_ids, *([] if mins is None else [mins]), *arrays)
     counters.inc("mesh.dispatches")
     if mesh.num_data_shards > 1:
         counters.inc("mesh.allreduce_bytes", sum(

@@ -162,29 +162,44 @@ def test_one_chip_id_program_is_unchanged(topo):
     (1_000, "onehot", jnp.float32, 1 << 20),
     (100_000, "scatter", jnp.float32, 1 << 20),
     (1_000, "onehot", jnp.int32, 1 << 20),
-    (1_000_000, "window", None, 100_000_000)])
+    (1_000_000, "window", None, 100_000_000),
+    (1_000_000, "window_packed", None, 100_000_000)])
 def test_segment_sum_routes_and_compiles(one_chip, groups, route, dtype, n):
     # the route is segment_sum's own rule on what the kernel holds in
     # fast memory; 100k groups would not compile in minutes as a kernel.
     # Past it, q5's columns (two int32, one f32) at its 1e8 rows and 1e6
-    # groups compile on the sorted-window route
+    # groups compile on the sorted-window route, with v1 (1..5) and v2
+    # (1..15) packed into the sort key or not
     from tensorframes_tpu.ops.segment_reduce import (segment_sums,
+                                                     sort_key_plan,
                                                      window_block_rows)
 
     assert pallas_fits(groups, 1) is (route == "onehot")
-    if route == "window":
+    if route.startswith("window"):
         window = (window_block_rows(n, groups), 128)
+        mins = None
+        if route == "window_packed":
+            window += (sort_key_plan(groups, [(1, 5), (1, 15), None]),)
+            mins = _spec((2,), jnp.uint32, one_chip)
         ints = _spec((n,), jnp.int32, one_chip)
-        compiled = jax.jit(lambda i, a, b, c: segment_sums(
-            [a, b, c], i, groups, window=window, impl="pallas")).lower(
-                ints, ints, ints, _spec((n,), jnp.float32, one_chip)).compile()
+        compiled = jax.jit(lambda i, a, b, c, m: segment_sums(
+            [a, b, c], i, groups, window=window, impl="pallas",
+            mins=m)).lower(ints, ints, ints,
+                           _spec((n,), jnp.float32, one_chip),
+                           mins).compile()
         text = compiled.as_text()
         assert "segment_sum_window" in text
         # one sort carries the ids and every column: no per-column scatter
-        # of the rows
-        assert len(re.findall(r" sort\(", text)) == 1
+        # of the rows; packed, it carries the key and v3 alone
+        sorts = re.findall(r"\) sort\(([^)]*)\)", text)
+        assert len(sorts) == 1
+        assert sorts[0].count(",") + 1 == (2 if mins else 4)
         assert [o.dtype for o in compiled.out_info] == [
             jnp.int32, jnp.int32, jnp.float32]
+        # the sorted rows are the temp; packed, the kernel reads the key
+        # and unpacks it in its input fusions: no unpacked row is kept
+        temp = compiled.memory_analysis().temp_size_in_bytes
+        assert temp < (9 if mins else 17) * n
         return
     impl = "pallas" if route == "onehot" else "xla"
     compiled = jax.jit(
