@@ -58,15 +58,20 @@ def submit(dframe: TensorFrame, fetches=None, *, tenant: str = "default",
 
 
 def map_blocks(fetches, dframe: TensorFrame, trim: bool = False,
-               executor=None) -> TensorFrame:
+               executor=None, bind=None) -> TensorFrame:
     """Transforms a DataFrame into another DataFrame block by block.
 
     Appends new columns (trim=False) or discards the inputs and returns only
     the computation's outputs (trim=True), in which case the number of rows
     may differ from the input block's. Lazy. Reference: ``core.py:172-218``.
     ``executor`` overrides the process-default :class:`BlockExecutor`.
+    ``bind``: a pytree of arrays (a model's weights) that the compiled
+    block program takes as an argument; ``fetches`` receives it as its
+    first parameter, before the columns. A second pytree of the same
+    shapes and dtypes reuses the executable.
     """
-    return _ops.map_blocks(fetches, dframe, trim=trim, executor=executor)
+    return _ops.map_blocks(fetches, dframe, trim=trim, executor=executor,
+                           bind=bind)
 
 
 def map_rows(fetches, dframe: TensorFrame, executor=None) -> TensorFrame:

@@ -19,6 +19,7 @@ throwaway C++ session).
 
 from __future__ import annotations
 
+import copy
 import inspect
 import json
 import struct
@@ -160,13 +161,24 @@ class Computation:
     module (:meth:`from_stablehlo`, :meth:`deserialize`): it runs through
     ``jax.export``'s ``call``, which has no batching rule, so the planners
     leave it per-op instead of fusing it into a chain.
+
+    ``bound`` (a pytree of ``jax.ShapeDtypeStruct``) makes ``fn`` take a
+    second argument of that structure, such as a model's weights:
+    :meth:`bind` gives a view of the computation that carries the values,
+    and the executor passes them to the compiled block program as an
+    argument, so the program holds no copy of them and one executable
+    serves every value set of those shapes (``base`` is the unbound
+    computation the executor keys it on).
     """
 
     opaque = False
+    bound_values = None
+    _base = None
 
     def __init__(self, fn: Callable, inputs: Sequence[TensorSpec],
-                 outputs: Sequence[TensorSpec]):
-        self._fn = fn  # dict[str, Array] -> dict[str, Array]
+                 outputs: Sequence[TensorSpec], bound=None):
+        self._fn = fn  # dict[str, Array] (, bound) -> dict[str, Array]
+        self.bound = bound
         self.inputs: Tuple[TensorSpec, ...] = tuple(inputs)
         self.outputs: Tuple[TensorSpec, ...] = tuple(
             sorted(outputs, key=lambda s: s.name))
@@ -192,12 +204,42 @@ class Computation:
         missing = [n for n in self.input_names if n not in arrays]
         if missing:
             raise ValueError(f"Missing computation inputs: {missing}")
-        return dict(self._fn({n: arrays[n] for n in self.input_names}))
+        feeds = {n: arrays[n] for n in self.input_names}
+        extra = () if self.bound is None else (self.bound_values,)
+        return dict(self._fn(feeds, *extra))
 
     @property
     def fn(self) -> Callable:
-        """The raw dict->dict JAX-traceable callable (for jit/shard_map)."""
+        """The raw dict->dict JAX-traceable callable (for jit/shard_map);
+        with ``bound`` it takes the bound values as a second argument."""
         return self._fn
+
+    @property
+    def base(self) -> "Computation":
+        """The computation a :meth:`bind` view was made from (itself
+        otherwise)."""
+        return self if self._base is None else self._base
+
+    def bind(self, values) -> "Computation":
+        """A view of this computation that carries ``values``, a pytree
+        of the structure, shapes and dtypes of ``bound``."""
+        if self.bound is None:
+            raise ValueError("this computation takes no bound argument")
+        want = jax.tree_util.tree_structure(self.bound)
+        got = jax.tree_util.tree_structure(values)
+        if got != want:
+            raise ValueError(f"bound values have the structure {got}; "
+                             f"the computation was traced with {want}")
+        for w, v in zip(jax.tree_util.tree_leaves(self.bound),
+                        jax.tree_util.tree_leaves(values)):
+            if tuple(v.shape) != tuple(w.shape) or v.dtype != w.dtype:
+                raise ValueError(
+                    f"a bound leaf is {v.dtype}{list(v.shape)}; the "
+                    f"computation was traced with {w.dtype}{list(w.shape)}")
+        view = copy.copy(self)
+        view.bound_values = values
+        view._base = self.base
+        return view
 
     def __repr__(self):
         ins = ", ".join(map(repr, self.inputs))
@@ -210,7 +252,8 @@ class Computation:
               input_specs: Mapping[str, Tuple[_dt.DType, Shape]] | Sequence[TensorSpec],
               output_shapes: Optional[Mapping[str, Shape]] = None,
               share_lead_symbol: bool = True,
-              takes_dict: Optional[bool] = None) -> "Computation":
+              takes_dict: Optional[bool] = None,
+              bound=None) -> "Computation":
         """Capture a Python function as a Computation.
 
         ``fn`` takes named arrays (one kw/positional arg per input, in
@@ -219,7 +262,9 @@ class Computation:
         Output shapes are inferred abstractly; ``output_shapes`` are optional
         driver-provided hints (the ``ShapeDescription`` analogue, reference
         ``ShapeDescription.scala:12-17``) used when symbolic inference cannot
-        determine a shape.
+        determine a shape. With ``bound`` (a pytree of
+        ``jax.ShapeDtypeStruct``), ``fn`` takes the bound values as its
+        first argument, before the named arrays.
         """
         if isinstance(input_specs, Mapping):
             specs = [TensorSpec(n, dt, sh) for n, (dt, sh) in input_specs.items()]
@@ -227,17 +272,17 @@ class Computation:
             specs = list(input_specs)
 
         if takes_dict is None:
-            takes_dict = _fn_takes_dict(fn, len(specs))
+            takes_dict = bound is None and _fn_takes_dict(fn, len(specs))
         kw_only = _keyword_only_names(fn)
 
-        def dict_fn(d: Mapping[str, jnp.ndarray]) -> Dict[str, jnp.ndarray]:
+        def dict_fn(d: Mapping[str, jnp.ndarray], *b) -> Dict[str, jnp.ndarray]:
             if takes_dict:
-                out = fn(dict(d))
+                out = fn(*b, dict(d))
             else:
                 args = [d[s.name] for s in specs if s.name not in kw_only]
                 kwargs = {s.name: d[s.name] for s in specs
                           if s.name in kw_only}
-                out = fn(*args, **kwargs)
+                out = fn(*b, *args, **kwargs)
             if not isinstance(out, Mapping):
                 name = getattr(fn, "__name__", "output")
                 if name == "<lambda>":
@@ -246,8 +291,8 @@ class Computation:
             return {k: jnp.asarray(v) for k, v in out.items()}
 
         out_specs = _infer_outputs(dict_fn, specs, share_lead_symbol,
-                                   output_shapes)
-        return Computation(dict_fn, specs, out_specs)
+                                   output_shapes, bound)
+        return Computation(dict_fn, specs, out_specs, bound)
 
     # -- serialization (StableHLO via jax.export) --------------------------
     def serialize(self) -> bytes:
@@ -499,7 +544,8 @@ def _fn_takes_dict(fn: Callable, n_inputs: int) -> bool:
 
 def _infer_outputs(dict_fn: Callable, specs: Sequence[TensorSpec],
                    share_lead_symbol: bool,
-                   output_shapes: Optional[Mapping[str, Shape]]) -> List[TensorSpec]:
+                   output_shapes: Optional[Mapping[str, Shape]],
+                   bound=None) -> List[TensorSpec]:
     """Abstractly evaluate the computation to get output specs.
 
     Strategy 1: symbolic dims (exact propagation of the unknown row dim).
@@ -509,9 +555,11 @@ def _infer_outputs(dict_fn: Callable, specs: Sequence[TensorSpec],
     reference's hint mechanism existed for exactly this reason).
     """
     avals, any_symbolic = _sym_avals(specs, share_lead_symbol)
+    extra = () if bound is None else (bound,)
     out = None
     try:
-        out = jax.eval_shape(dict_fn, dict(zip([s.name for s in specs], avals)))
+        out = jax.eval_shape(dict_fn, dict(zip([s.name for s in specs], avals)),
+                             *extra)
     except Exception:
         # Only symbolic-dim-hostile computations may fall back; a failure on
         # fully-concrete avals is a real error in the user computation.
@@ -525,7 +573,8 @@ def _infer_outputs(dict_fn: Callable, specs: Sequence[TensorSpec],
             dims = tuple(SENTINEL if not isinstance(d, int) else d
                          for d in aval.shape)
             conc.append(jax.ShapeDtypeStruct(dims, aval.dtype))
-        out = jax.eval_shape(dict_fn, {s.name: a for s, a in zip(specs, conc)})
+        out = jax.eval_shape(dict_fn, {s.name: a for s, a in zip(specs, conc)},
+                             *extra)
         inferred = {name: Shape(tuple(Unknown if d == SENTINEL else d
                                       for d in out[name].shape))
                     for name in out}

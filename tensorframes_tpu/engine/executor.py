@@ -69,7 +69,8 @@ def current_computation_interner():
 
 def _intern(comp: Computation) -> Computation:
     f = _comp_interner
-    if f is None:
+    if f is None or comp.bound is not None:
+        # a bound computation's executables are keyed on its base already
         return comp
     try:
         out = f(comp)
@@ -497,7 +498,17 @@ class BlockExecutor:
         created the jitted wrapper (a compile-cache miss): the caller
         times the first dispatch and attributes it as compile time
         (jax compiles lazily at first call, so the wrapper's creation
-        itself costs nothing)."""
+        itself costs nothing). ``fn`` takes the block's arrays; a bound
+        computation's values ride into the program as its second
+        argument, and its executables are cached on its unbound
+        ``base``, whose bound shapes and dtypes are fixed."""
+        fn, fresh = self._jitted(comp.base, sig, donate)
+        vals = comp.bound_values
+        if vals is None:
+            return fn, fresh
+        return (lambda arrays: fn(arrays, vals)), fresh
+
+    def _jitted(self, comp: Computation, sig: Tuple, donate: bool):
         # Double-checked locking: the lock-free fast path is safe under
         # the GIL (a dict read racing a dict write sees either the old or
         # the new table, never a torn one); EVERY mutation of the

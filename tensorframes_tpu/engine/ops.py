@@ -43,7 +43,7 @@ from ..observability.events import traced_query
 from ..schema import Field, Schema
 from ..shape import Shape, Unknown
 from ..utils.logging import get_logger
-from ..utils.tracing import span
+from ..utils.tracing import counters, span
 from .compaction import CompactionBuffer, DEFAULT_BUFFER_SIZE
 from .executor import (BlockExecutor, default_executor,
                        default_padding_executor)
@@ -119,13 +119,15 @@ def _field_spec(field: Field, block_level: bool, context: str) -> Shape:
 
 
 def _map_computation(fetches: Fetches, schema: Schema,
-                     block_level: bool) -> Computation:
+                     block_level: bool, bound=None) -> Computation:
     if isinstance(fetches, Computation):
         return fetches
     if _is_dsl(fetches):
         return _dsl_to_computation(fetches, schema, block_level)
     if callable(fetches):
         names = _callable_input_names(fetches)
+        if bound is not None:
+            names = names[1:]  # the first parameter takes the bound values
         specs = []
         for n in names:
             field = schema.get(n)
@@ -135,7 +137,7 @@ def _map_computation(fetches: Fetches, schema: Schema,
                     f"columns: {schema.names}")
             specs.append(TensorSpec(
                 n, field.dtype, _field_spec(field, block_level, "map")))
-        return Computation.trace(fetches, specs)
+        return Computation.trace(fetches, specs, bound=bound)
     raise TypeError(f"Unsupported fetches object: {type(fetches)}")
 
 
@@ -350,16 +352,42 @@ def empty_fetch_columns(b: Block, outputs) -> Block:
     return Block(cols, 0)
 
 
+def _place_bound(values):
+    """``values`` on the device, under a ``map_blocks.bind`` span
+    (``attrs``: the bytes moved from the host, 0 when every leaf is
+    already a device array, and the number of leaves)."""
+    leaves = jax.tree_util.tree_leaves(values)
+    with span("map_blocks.bind") as sp:
+        placed = jax.device_put(values)
+        if sp is not None:
+            sp.attrs = {"bytes": sum(int(np.asarray(a).nbytes)
+                                     for a in leaves
+                                     if not isinstance(a, jax.Array)),
+                        "leaves": len(leaves)}
+    return placed
+
+
 def map_blocks(fetches: Fetches, df: TensorFrame, trim: bool = False,
-               executor: Optional[BlockExecutor] = None) -> TensorFrame:
+               executor: Optional[BlockExecutor] = None,
+               bind=None) -> TensorFrame:
     """Transform a frame block-by-block, appending (or, with ``trim``,
-    replacing with) the computation's outputs. Lazy."""
+    replacing with) the computation's outputs. Lazy.
+
+    ``bind``: a pytree of arrays, such as a model's weights, passed to the
+    compiled block program as an argument: ``fetches`` takes it as its
+    first parameter and the columns after it. One executable serves every
+    pytree of the same shapes and dtypes, and holds no copy of it."""
     ex = executor or default_executor()
     # the canonical computation is cached per fetches object (weakly):
     # repeated chains over the same fetches share one comp — and with
     # it every downstream jit/program cache AND the plan-fingerprint
     # result cache's op identity (docs/adaptive.md)
-    comp = cached_map_computation(fetches, df.schema, block_level=True)
+    bound = None if bind is None else jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype), bind)
+    comp = cached_map_computation(fetches, df.schema, block_level=True,
+                                  bound=bound)
+    if bind is not None:
+        comp = comp.bind(_place_bound(bind))
     out_schema = _validate_map(comp, df.schema, block_level=True, trim=trim)
     in_names = comp.input_names
     fetch_names = comp.output_names
@@ -391,6 +419,8 @@ def map_blocks(fetches: Fetches, df: TensorFrame, trim: bool = False,
             return empty_block()
         with span("map_blocks.block"):
             arrays = {n: b.dense(n) for n in in_names}
+            if bind is not None:
+                counters.inc("map_blocks.bound_dispatches")
             # trim may legally change the row count; padding would corrupt
             # it, and non-row-local computations must see the true block.
             out = ex.run(comp, arrays, pad_ok=not trim)
@@ -400,6 +430,8 @@ def map_blocks(fetches: Fetches, df: TensorFrame, trim: bool = False,
         if b.num_rows == 0:
             return empty_block()  # finished: flows through the window
         arrays = {n: b.dense(n) for n in in_names}
+        if bind is not None:
+            counters.inc("map_blocks.bound_dispatches")
         return _pipeline.submit(ex, comp, arrays, pad_ok=not trim)
 
     rows_h, bytes_h = _memory.propagate_hints(df, out_schema)
@@ -415,7 +447,8 @@ def map_blocks(fetches: Fetches, df: TensorFrame, trim: bool = False,
                       bytes_hint=None if trim else bytes_h)
     if executor is None:
         # record the logical-plan node (docs/plan.md); an explicit
-        # executor= pins the per-op path, so no node is attached
+        # executor= pins the per-op path, so no node is attached. A
+        # bound comp carries its values into the plan's fused program.
         from ..plan.nodes import MapBlocksNode, attach, node_for
         attach(out, MapBlocksNode(node_for(df), out_schema, comp, trim))
     return out
@@ -545,7 +578,7 @@ def map_rows(fetches: Fetches, df: TensorFrame,
 # ---------------------------------------------------------------------------
 
 def cached_map_computation(fetches, schema: Schema,
-                           block_level: bool) -> Computation:
+                           block_level: bool, bound=None) -> Computation:
     """`_map_computation` with reuse keyed weakly by the fetches object —
     the map-side twin of :func:`cached_reduce_computation` (a fresh
     Computation per call would defeat every per-Computation jit/program
@@ -554,12 +587,17 @@ def cached_map_computation(fetches, schema: Schema,
     Computation — the per-fetches dict is only read/written under
     ``_comp_cache_lock`` and the insert is a ``setdefault``, so the loser
     of a trace race adopts the winner's object (tracing itself runs
-    outside the lock)."""
+    outside the lock). With ``bound``, the key holds its structure,
+    shapes and dtypes too."""
     sig = ("map", block_level,
            tuple((f.name, f.dtype.name,
                   tuple(f.block_shape.dims) if f.block_shape is not None
                   else None)
                  for f in schema))
+    if bound is not None:
+        sig += (jax.tree_util.tree_structure(bound),
+                tuple((tuple(a.shape), str(a.dtype))
+                      for a in jax.tree_util.tree_leaves(bound)))
     try:
         with _comp_cache_lock:
             per = _fetches_comp_cache.setdefault(fetches, {})
@@ -569,7 +607,8 @@ def cached_map_computation(fetches, schema: Schema,
         comp = None
     if comp is not None:
         return comp
-    comp = _map_computation(fetches, schema, block_level=block_level)
+    comp = _map_computation(fetches, schema, block_level=block_level,
+                            bound=bound)
     if per is not None:
         with _comp_cache_lock:
             comp = per.setdefault(sig, comp)

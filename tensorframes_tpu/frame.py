@@ -520,9 +520,10 @@ class TensorFrame:
 
     # -- fluent op sugar (reference dsl/Implicits.scala:12-123) ------------
     def map_blocks(self, fetches, trim: bool = False,
-                   executor=None) -> "TensorFrame":
+                   executor=None, bind=None) -> "TensorFrame":
         from . import api
-        return api.map_blocks(fetches, self, trim=trim, executor=executor)
+        return api.map_blocks(fetches, self, trim=trim, executor=executor,
+                              bind=bind)
 
     def map_rows(self, fetches, executor=None) -> "TensorFrame":
         from . import api

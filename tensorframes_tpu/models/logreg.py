@@ -142,6 +142,19 @@ class LogisticRegression:
                                                   {"w": 0, "b": 0}), repl))
 
 
+def _frame_fn(owner, key, kw_fn, names):
+    """``owner``'s block function for ``key``: :func:`_named_args_fn` of
+    ``kw_fn`` the first time, the same object after. ``map_blocks``
+    caches the traced computation, and with it the executable, on the
+    function object, so a later call under ``key`` with bound values
+    of the same shapes compiles nothing."""
+    memo = owner.__dict__.setdefault("_frame_fns", {})
+    fn = memo.get(key)
+    if fn is None:
+        fn = memo[key] = _named_args_fn(kw_fn, names)
+    return fn
+
+
 def _named_args_fn(kw_fn, names):
     """Build a positional function whose parameter names are ``names`` —
     the engine derives computation inputs from parameter names

@@ -2,9 +2,9 @@
 
 The reference's north star ("ResNet-50 frozen-graph batch inference over
 image-tensor DataFrame column", ``BASELINE.json``) maps a frozen network over
-blocks of rows — exactly ``map_blocks(trim=True)`` with the network's
-parameters closed over as constants, the way the reference would broadcast a
-frozen ``GraphDef``.
+blocks of rows — exactly ``map_blocks(trim=True)``, with the network's
+parameters bound to the block program as an argument where the reference
+would broadcast a frozen ``GraphDef``.
 
 Pure-JAX implementation, NHWC layout (TPU-native: channels-last feeds the
 MXU's 128-lane minor dimension), inference-mode batch norm folded to a
@@ -127,13 +127,15 @@ class ResNet50:
     def infer_via_frame(self, params: Params, df, image_col: str = "image",
                         trim: bool = True):
         """Batch inference through ``map_blocks``: the frozen parameters
-        ride into the computation as closed-over constants (the broadcast-
-        the-frozen-graph pattern). Returns a lazy frame with a ``logits``
-        column."""
+        are bound to the block program as an argument, so every call on
+        parameters of the same shapes runs one executable. Returns a lazy
+        frame with a ``logits`` column."""
         apply = self.apply
 
-        def fn_impl(**cols):
+        def fn_impl(params, **cols):
             return {"logits": apply(params, cols[image_col])}
 
-        from .logreg import _named_args_fn
-        return df.map_blocks(_named_args_fn(fn_impl, [image_col]), trim=trim)
+        from .logreg import _frame_fn
+        fn = _frame_fn(self, ("infer", image_col), fn_impl,
+                       ["params", image_col])
+        return df.map_blocks(fn, trim=trim, bind=params)

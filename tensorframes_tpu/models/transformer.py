@@ -311,18 +311,19 @@ class TransformerLM:
                            trim: bool = True):
         """Batch decoding through ``map_blocks``: prompts live in a frame
         column (``[S0]`` int cells), completions come back as a
-        ``completion`` column (``[S0 + max_new_tokens]``) — the
-        broadcast-the-frozen-graph pattern the other zoo models use for
-        inference, here driving the KV-cache decode loop per block.
+        ``completion`` column (``[S0 + max_new_tokens]``), the KV-cache
+        decode loop running per block. The parameters and ``rng`` are
+        bound to the block program as arguments, so a later call with
+        the same settings and shapes runs the same executable.
 
         Sampling (``temperature > 0``) folds the block's token content
         into ``rng`` so different blocks draw independent streams; blocks
         with byte-identical prompts reproduce the same completion
         (deterministic by content — re-running the frame gives the same
         result, the laziness contract's requirement)."""
-        def fn_impl(**cols):
+        def fn_impl(bound, **cols):
             toks = cols[prompt_col].astype(jnp.int32)
-            key = rng
+            key = bound["rng"]
             if key is not None:
                 mix = jnp.sum(
                     toks.astype(jnp.uint32)
@@ -330,13 +331,16 @@ class TransformerLM:
                        .reshape(toks.shape)
                        * np.uint32(2654435761) + np.uint32(1)))
                 key = jax.random.fold_in(key, mix.astype(jnp.uint32))
-            out = self.generate(params, toks, max_new_tokens,
+            out = self.generate(bound["params"], toks, max_new_tokens,
                                 temperature=temperature, rng=key)
             return {"completion": out}
 
-        from .logreg import _named_args_fn
-        return df.map_blocks(_named_args_fn(fn_impl, [prompt_col]),
-                             trim=trim)
+        from .logreg import _frame_fn
+        fn = _frame_fn(self, ("generate", prompt_col, int(max_new_tokens),
+                              float(temperature), rng is None),
+                       fn_impl, ["bound", prompt_col])
+        return df.map_blocks(fn, trim=trim,
+                             bind={"params": params, "rng": rng})
 
     @staticmethod
     def _xent(logits: jax.Array, targets: jax.Array) -> jax.Array:

@@ -6,7 +6,9 @@ k-blocks, maintaining the online-softmax statistics (running max ``m``,
 normalizer ``l``, unnormalized accumulator ``acc``) in VMEM scratch, and
 writes the normalized output once at the last k-step. Matmuls hit the MXU in
 f32 accumulation regardless of the input dtype (bf16 in, f32 acc, input
-dtype out).
+dtype out); the softmax statistics are f32, and the probabilities meet ``v``
+in its dtype. ``v`` may be narrower or wider than ``q``/``k`` (latent
+attention scores over 192 dims and returns 128).
 
 This is the single-device kernel; sequence parallelism composes *around* it:
 :func:`~tensorframes_tpu.parallel.ring.ring_attention` rotates k/v shards
@@ -52,7 +54,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
     def _update():
         q = q_ref[0]  # [block_q, d]
         k = k_ref[0]  # [block_k, d]
-        v = v_ref[0]  # [block_k, d]
+        v = v_ref[0]  # [block_k, dv]
         scores = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # [bq, bk]
@@ -74,7 +76,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         p = jnp.where(mask, p, 0.0)              # exp(-1e30-…) underflows, but be exact
         l_new = alpha * l_prev + jnp.sum(p, axis=-1)
         acc_ref[:] = acc_ref[:] * alpha[:, None] + jax.lax.dot_general(
-            p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_ref[:] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
         l_ref[:] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
@@ -107,7 +109,7 @@ def _pad_to(x, axis: int, multiple: int):
 def _pallas_attention(q, k, v, *, causal: bool, scale: float,
                       block_q: int, block_k: int, interpret: bool):
     bh, sq, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[2]
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     qp = _pad_to(q, 1, block_q)
@@ -124,22 +126,23 @@ def _pallas_attention(q, k, v, *, causal: bool, scale: float,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        out_specs=pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(
-            (bh, qp.shape[1], d), q.dtype,
+            (bh, qp.shape[1], dv), q.dtype,
             # shard_map(check_vma=True) requires declaring the mesh axes the
             # output varies over — the attention output varies like q/k/v
             vma=vma_union(q, k, v)),
         scratch_shapes=[
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # m
             pltpu.VMEM((block_q, _LANES), jnp.float32),  # l
-            pltpu.VMEM((block_q, d), jnp.float32),       # acc
+            pltpu.VMEM((block_q, dv), jnp.float32),      # acc
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=interpret,
+        name="flash_attention",          # the kernel's name in a trace
     )(qp, kp, vp)
     return out[:, :sq]
 
@@ -151,8 +154,9 @@ def _xla_attention(q, k, v, *, causal: bool, scale: float):
         sq, sk = scores.shape[-2:]
         mask = jnp.arange(sq)[:, None] >= jnp.arange(sk)[None, :]
         scores = jnp.where(mask, scores, _NEG_INF)
-    p = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bqk,bkd->bqd", p, v.astype(p.dtype)).astype(q.dtype)
+    p = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+    return jnp.einsum("bqk,bkd->bqd", p, v,
+                      preferred_element_type=jnp.float32).astype(q.dtype)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -160,7 +164,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
                     impl: Optional[str] = None) -> jax.Array:
-    """Exact attention, ``[B, S, H, D]`` layout (matching the model zoo).
+    """Exact attention, ``[B, S, H, D]`` layout (matching the model zoo);
+    ``v`` is ``[B, S, H, Dv]`` and so is the result.
 
     ``impl``: ``"pallas"`` (TPU kernel), ``"xla"`` (plain jnp reference),
     ``"interpret"`` (Pallas interpreter — CPU tests), or None to pick
@@ -171,12 +176,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     if impl == "interpret" and interpret_blocked_by_vma(q, k, v):
         impl = "xla"  # see ops/_pallas_mesh.py: interpreter can't do vma
     b, sq, h, d = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[3]
     if scale is None:
         scale = float(1.0 / (d ** 0.5))
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
+    vf = v.transpose(0, 2, 1, 3).reshape(b * h, sk, dv)
     if impl == "xla":
         o = _xla_attention(qf, kf, vf, causal=causal, scale=scale)
     elif impl in ("pallas", "interpret"):
@@ -185,4 +190,4 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                               interpret=(impl == "interpret"))
     else:
         raise ValueError(f"Unknown flash_attention impl {impl!r}")
-    return o.reshape(b, h, sq, d).transpose(0, 2, 1, 3)
+    return o.reshape(b, h, sq, dv).transpose(0, 2, 1, 3)

@@ -466,6 +466,8 @@ def _node_fp(node, validators, comps, depth: int) -> Optional[tuple]:
             return None
         return ("join", left, right, node.on, node.how, node.strategy)
     if kind == "map_blocks":
+        if node.comp.bound is not None:
+            return None  # its bound values are no part of the identity
         return ("mb", id(node.comp), node.trim)
     if kind == "map_rows":
         return ("mr", id(node.comp))
@@ -530,6 +532,8 @@ def _portable_node_fp(node) -> Optional[tuple]:
                 node.row_group_offset, node.row_group_limit,
                 node.columns, node.num_partitions)
     if kind in ("map_blocks", "map_rows", "filter"):
+        if node.comp.bound is not None:
+            return None  # its bound values are no part of the identity
         from ..serve.cache import computation_signature
         sig = computation_signature(node.comp)
         if sig is None:

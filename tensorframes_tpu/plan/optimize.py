@@ -67,6 +67,7 @@ def _abstract_outputs(comp: Computation):
     """``(eval_shape outputs, shared lead symbol)`` under symbolic
     avals, or ``None`` for symbolic-hostile / row-dim-free computations.
     Cached on the computation — one abstract eval per comp per process."""
+    comp = comp.base  # a bound view proves what its computation does
     cached = getattr(comp, "_tft_plan_absout", False)
     if cached is not False:
         return cached
@@ -80,8 +81,10 @@ def _abstract_outputs(comp: Computation):
                 lead = av.shape[0]
                 break
         if lead is not None:
+            extra = () if comp.bound is None else (comp.bound,)
             out = jax.eval_shape(
-                comp.fn, {s.name: a for s, a in zip(comp.inputs, avals)})
+                comp.fn, {s.name: a for s, a in zip(comp.inputs, avals)},
+                *extra)
             res = (out, lead)
     except Exception as e:
         # not an error: the computation simply stays unfused
@@ -182,22 +185,33 @@ _composed_lock = threading.Lock()
 Member = Tuple
 
 
+def _bound(m: Member) -> bool:
+    return m[0] != "sel" and m[1].bound is not None
+
+
 def _compose(members: Sequence[Member], in_specs: List[TensorSpec],
              out_specs: List[TensorSpec]) -> Computation:
+    """The members' program as one computation. ``members`` hold
+    unbound computations; the bound members' values ride in as the
+    composed computation's own bound argument, a tuple in member
+    order."""
     mem = tuple(members)
     data_names = tuple(s.name for s in out_specs if s.name != MASK)
     has_mask = any(s.name == MASK for s in out_specs)
+    bound = tuple(m[1].bound for m in mem if _bound(m)) or None
 
-    def fused_fn(d):
+    def fused_fn(d, *b):
         env = dict(d)
         mask = None
+        values = iter(b[0] if b else ())
         for m in mem:
             if m[0] == "sel":
                 keep = set(m[1])
                 env = {k: v for k, v in env.items() if k in keep}
                 continue
             comp = m[1]
-            out = comp.fn({n: env[n] for n in comp.input_names})
+            extra = (next(values),) if comp.bound is not None else ()
+            out = comp.fn({n: env[n] for n in comp.input_names}, *extra)
             if m[0] == "f":
                 mask = out[comp.output_names[0]]
             elif m[0] == "mb" and m[2]:
@@ -209,7 +223,7 @@ def _compose(members: Sequence[Member], in_specs: List[TensorSpec],
             res[MASK] = mask
         return res
 
-    return Computation(fused_fn, in_specs, out_specs)
+    return Computation(fused_fn, in_specs, out_specs, bound)
 
 
 def _member_key(m: Member):
@@ -222,6 +236,19 @@ def _member_key(m: Member):
 
 def _composed_cached(members: Sequence[Member], in_specs: List[TensorSpec],
                      out_specs: List[TensorSpec]) -> Computation:
+    """The composed computation, cached on the members' unbound
+    computations (so every value set of a bound member shares one
+    program) and bound to this chain's values."""
+    values = tuple(m[1].bound_values for m in members if _bound(m))
+    members = [m if m[0] == "sel" else (m[0], m[1].base) + tuple(m[2:])
+               for m in members]
+    comp = _composed_unbound(members, in_specs, out_specs)
+    return comp if comp.bound is None else comp.bind(values)
+
+
+def _composed_unbound(members: Sequence[Member],
+                      in_specs: List[TensorSpec],
+                      out_specs: List[TensorSpec]) -> Computation:
     anchor = next((m[1] for m in members if m[0] != "sel"), None)
     if anchor is None:
         return _compose(members, in_specs, out_specs)

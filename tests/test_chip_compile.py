@@ -224,6 +224,36 @@ def test_flash_attention_compiles(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_latent_attention_compiles(one_chip):
+    # the dsv2lite cell's attention: q and k 192 wide, v 128, causal over
+    # 4,096 tokens in 512-row blocks
+    q = _spec((4, 4096, 16, 192), jnp.bfloat16, one_chip)
+    v = _spec((4, 4096, 16, 128), jnp.bfloat16, one_chip)
+    compiled = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=512, block_k=512, scale=0.11472,
+        impl="pallas")).lower(q, q, v).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.out_info.shape == (4, 4096, 16, 128)
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)])
+def test_moe_gmm_compiles_at_the_expert_widths(one_chip, k, n):
+    # the dsv2lite cell's grouped matmuls: 16,384 tokens x 6 experts in
+    # 256-row tiles over 64 experts, gate/up (2048 -> 1408) and down
+    from tensorframes_tpu.ops import moe_gmm
+    from tensorframes_tpu.ops.moe_gmm import max_tiles
+
+    tiles = max_tiles(16384 * 6, 64, 256)
+    x = _spec((tiles * 256, k), jnp.bfloat16, one_chip)
+    w = _spec((64, k, n), jnp.bfloat16, one_chip)
+    te = _spec((tiles,), jnp.int32, one_chip)
+    used = _spec((), jnp.int32, one_chip)
+    compiled = jax.jit(lambda x, w, te, used: moe_gmm(
+        x, w, te, used, tm=256)).lower(x, w, te, used).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 def test_map_blocks_block_program_compiles(one_chip):
     df = tft.frame({"x": np.arange(8.0)})
     comp = cached_map_computation(lambda x: {"z": x + 3.0}, df.schema,

@@ -60,6 +60,20 @@ class TestFlashAttention:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
 
+    @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                           (jnp.bfloat16, 2e-2)])
+    def test_value_narrower_than_query_and_key(self, rng, dtype, tol):
+        # latent attention: scores over 24 dims, values of 16
+        q, k, _ = _qkv(rng, d=24, dtype=dtype)
+        v = jnp.asarray(rng.standard_normal((2, 64, 2, 16)), dtype)
+        ref = flash_attention(q, k, v, causal=True, scale=0.3, impl="xla")
+        out = flash_attention(q, k, v, causal=True, scale=0.3,
+                              impl="interpret", block_q=16, block_k=16)
+        assert out.shape == (2, 64, 2, 16) and out.dtype == dtype
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   rtol=tol, atol=tol)
+
     def test_single_block(self, rng):
         q, k, v = _qkv(rng, s=8)
         ref = flash_attention(q, k, v, impl="xla")
