@@ -15,16 +15,24 @@ values ``[d, N]``), so a scalar column is not padded to 128 lanes in HBM.
 Out-of-range ids (e.g. -1 pad rows) produce an all-zero one-hot column and
 contribute nothing, for free.
 
-Integer values of 32 bits or fewer are summed exactly by the same kernel.
-Each value, read as int32, is split into byte limbs: the low limbs are the
-unsigned bytes ``(w >> 8l) & 0xFF``, the top limb the arithmetic
-``w >> 8(L-1)``. Every limb and every one-hot entry is exact in bf16, so
-one bf16 pass with f32 accumulation is exact within a block while
-``block_rows * 255 < 2^24``. Each limb's partial is converted to int32,
-shifted by ``8l`` and added into an int32 output block; these operations
+Each block step makes one MXU dot, in bf16 with f32 accumulation: the
+one-hot, exact in bf16, against one stacked operand whose rows are all
+exact in bf16 too (:func:`_block_sums`). Integer values of 32 bits or
+fewer are summed exactly. Each value, read as int32, is split into byte
+limbs, each a row: the low limbs are the unsigned bytes ``(w >> 8l) &
+0xFF``, the top limb the arithmetic ``w >> 8(L-1)``. A limb's partial is
+exact in f32 while ``block_rows * 255 < 2^24``; it is converted to int32,
+shifted by ``8l`` and added into an int32 output block. These operations
 wrap modulo 2^32 as the sum does, so the result equals the int32
-scatter-add bit for bit, overflow included. 64-bit integers and bools
-take ``jax.ops.segment_sum``.
+scatter-add bit for bit, overflow included. A float value, read as f32,
+is split by truncation into three pieces exact in bf16 whose sum is the
+value (:func:`_split3`), each a row; the three f32 partials are added
+after the dot. The products are exact, so float sums are f32 sums of the
+values, as scatter-add's are, in another order; a value below 2^-103
+loses bits below f32's smallest normal, as the TPU flushes denormals.
+64-bit integers and bools take ``jax.ops.segment_sum``. Each program
+built counts the stacked operand's rows, before padding, in
+``segment_sum.dot_rows``.
 
 Three routes, by the group count:
 
@@ -92,8 +100,9 @@ def _round_up(v: int, m: int) -> int:
 
 def _vmem_bytes(num_segments: int, d: int, block_rows: int) -> int:
     """Fast-memory footprint of one grid step, in (8, 128) f32 tiles:
-    double-buffered id and value blocks, the compare mask and the f32
-    one-hot ``[S, bn]``, and the ``[d, S]`` accumulator plus its partial."""
+    double-buffered id and value blocks, the compare mask and the bf16
+    one-hot ``[S, bn]`` (each counted at 4 bytes), and the ``[d, S]``
+    accumulator plus its partial."""
     bn = _round_up(block_rows, 128)
     inputs = 2 * (8 + _round_up(d, 8)) * bn * 4
     onehot = 2 * _round_up(num_segments, 8) * bn * 4
@@ -106,51 +115,102 @@ def pallas_fits(num_segments: int, d: int, block_rows: int = 512) -> bool:
     return _vmem_bytes(num_segments, d, block_rows) <= VMEM_BUDGET_BYTES
 
 
-def _limb_sums(vals, hit, limbs: int, acc=None):
-    """``[d, S]`` int32 sums of int32 ``vals`` ``[d, bn]`` over the
-    one-hot ``hit`` ``[S, bn]``, added to ``acc`` where given: one exact
-    bf16 pass per byte limb, summed in wrapping int32."""
-    onehot = hit.astype(jnp.bfloat16)
-    for limb in range(limbs):
-        part = vals >> (8 * limb)
-        if limb < limbs - 1:
-            part = part & 0xFF
-        partial = jax.lax.dot_general(
-            part.astype(jnp.float32).astype(jnp.bfloat16), onehot,
-            (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        term = partial.astype(jnp.int32) << (8 * limb)
-        acc = term if acc is None else acc + term
-    return acc
+# the stacked operand's rows are padded to bf16's (16, 128) tile
+_BF16_SUBLANES = 16
+# the rows a float column takes in the stacked operand: three pieces of
+# its finite values, then its NaN, inf and -inf counts
+_FLOAT_ROWS = 6
 
 
-def _float_sums(vals, valid, hit):
-    """``[d, S]`` f32 sums of the ``valid`` rows of ``vals`` ``[d, bn]``
-    over the one-hot ``hit`` ``[S, bn]``.
+def _dot_rows(n_int: int, limbs: int, n_float: int) -> int:
+    """Rows of :func:`_block_sums`' stacked operand, before padding: one a
+    byte limb of each int column, six a float column."""
+    return n_int * limbs + _FLOAT_ROWS * n_float
 
-    In the one-hot dot a 0 x inf or 0 x NaN term would make every group
-    of the block NaN. So the dot takes such a value as 0, and three 0/1
-    rows a column, dotted beside the values in the same pass, count each
-    group's NaN, inf and -inf; each kind found is added to that group
-    alone, where IEEE addition gives what a sum in row order gives (inf +
-    -inf is NaN)."""
-    vals = vals.astype(jnp.float32)
-    d = vals.shape[0]
-    finite = jnp.abs(vals) < jnp.inf          # false for inf and NaN
-    kinds = ((vals != vals, jnp.nan), (vals == jnp.inf, jnp.inf),
-             (vals == -jnp.inf, -jnp.inf))
-    rows = jnp.concatenate(
-        [jnp.where(valid & finite, vals, 0.0)]
-        + [jnp.where(valid & kind, 1.0, 0.0) for kind, _ in kinds], axis=0)
+
+def _top16(x):
+    """f32 ``x`` with the low 16 bits of its word cleared: ``x`` truncated
+    toward 0 to bf16's 8 significant bits, exact in bf16."""
+    bits = jax.lax.bitcast_convert_type(x, jnp.int32)
+    return jax.lax.bitcast_convert_type(bits & jnp.int32(-65536),
+                                        jnp.float32)
+
+
+def _split3(x):
+    """Three pieces of finite f32 ``x``, each exact in bf16, with ``(b0 +
+    b1) + b2 == x``: ``b0`` is ``x`` truncated to bf16, ``b1`` the residual
+    truncated, ``b2`` the rest (at most 8 significant bits). Truncation,
+    not rounding, so that no piece overflows: a value past bf16's largest
+    would round to inf. Where denormals flush to 0, as on the TPU, a piece
+    below f32's smallest normal 2^-126 (only of a value below 2^-103) is
+    lost: an absolute error under 2^-126."""
+    b0 = _top16(x)
+    r = x - b0
+    b1 = _top16(r)
+    return b0, b1, r - b1
+
+
+def _block_sums(hit, *, limbs: int = 0, ints=None, floats=None,
+                valid=None):
+    """One block's sums over the one-hot ``hit`` ``[S, bn]`` in one MXU
+    dot: ``([d_int, S] int32, [d_float, S] f32)`` sums of the int32
+    ``ints`` and of the ``valid`` rows of the f32 ``floats`` (each ``[d,
+    bn]`` or None, which gives None).
+
+    The one-hot is exact in bf16, and so is every row of the stacked
+    operand ``[R, bn]`` (:func:`_dot_rows`) dotted against it with f32
+    accumulation:
+
+    - an int column's ``limbs`` byte limbs, the unsigned ``(w >> 8l) &
+      0xFF`` below the arithmetic top ``w >> 8(L-1)``: exact in f32 while
+      ``bn * 255 < 2^24``, then converted to int32, shifted by ``8l`` and
+      summed in wrapping int32, as the scatter-add wraps;
+    - a float column's three pieces of its finite valid values
+      (:func:`_split3`), whose f32 sums are added after the dot; a 0 x inf
+      or 0 x NaN term would make every group of the block NaN, so the
+      pieces take such a value as 0, and three 0/1 rows count each
+      group's NaN, inf and -inf; each kind found is added to that group
+      alone, where IEEE addition gives what a sum in row order gives (inf
+      + -inf is NaN)."""
+    rows = []
+    if ints is not None:
+        for limb in range(limbs):
+            part = ints >> (8 * limb)
+            if limb < limbs - 1:
+                part = part & 0xFF
+            rows.append(part.astype(jnp.float32))
+    kinds = ()
+    if floats is not None:
+        finite = jnp.abs(floats) < jnp.inf    # false for inf and NaN
+        kinds = ((floats != floats, jnp.nan), (floats == jnp.inf, jnp.inf),
+                 (floats == -jnp.inf, -jnp.inf))
+        rows.extend(_split3(jnp.where(valid & finite, floats, 0.0)))
+        rows.extend(jnp.where(valid & kind, 1.0, 0.0) for kind, _ in kinds)
+    n = sum(r.shape[0] for r in rows)
+    pad = (-n) % _BF16_SUBLANES
+    if pad:
+        rows.append(jnp.zeros((pad, hit.shape[1]), jnp.float32))
     out = jax.lax.dot_general(
-        rows, hit.astype(jnp.float32),
-        (((1,), (1,)), ((), ())),          # contract the row dim: [4d, S]
-        precision=jax.lax.Precision.HIGHEST,  # exact f32: this is an
-        preferred_element_type=jnp.float32)   # aggregation, not attention
-    sums = out[:d]
-    for k, (_, fill) in enumerate(kinds, 1):
-        sums = sums + jnp.where(out[k * d:(k + 1) * d] > 0, fill, 0.0)
-    return sums
+        jnp.concatenate(rows, axis=0).astype(jnp.bfloat16),
+        hit.astype(jnp.bfloat16),
+        (((1,), (1,)), ((), ())),              # contract the rows: [R, S]
+        preferred_element_type=jnp.float32)
+    int_sums = float_sums = None
+    at = 0
+    if ints is not None:
+        d = ints.shape[0]
+        for limb in range(limbs):
+            term = out[at:at + d].astype(jnp.int32) << (8 * limb)
+            int_sums = term if int_sums is None else int_sums + term
+            at += d
+    if floats is not None:
+        d = floats.shape[0]
+        piece = [out[at + k * d:at + (k + 1) * d]
+                 for k in range(_FLOAT_ROWS)]
+        float_sums = piece[0] + (piece[1] + piece[2])
+        for count, (_, fill) in zip(piece[3:], kinds):
+            float_sums = float_sums + jnp.where(count > 0, fill, 0.0)
+    return int_sums, float_sums
 
 
 def _kernel(ids_ref, vals_ref, out_ref, *, block_rows: int,
@@ -166,11 +226,12 @@ def _kernel(ids_ref, vals_ref, out_ref, *, block_rows: int,
     seg = jax.lax.broadcasted_iota(jnp.int32, (num_segments, block_rows), 0)
     hit = ids == seg                       # [S, bn]
     if limbs:
-        out_ref[:] = _limb_sums(vals, hit, limbs, out_ref[:])
+        sums, _ = _block_sums(hit, limbs=limbs, ints=vals)
     else:
         valid = (ids >= 0) & (ids < num_segments)
-        out_ref[:] = out_ref[:] + _float_sums(vals, valid, hit).astype(
-            out_ref.dtype)
+        _, sums = _block_sums(hit, floats=vals.astype(jnp.float32),
+                              valid=valid)
+    out_ref[:] = out_ref[:] + sums
 
 
 def _pallas_segment_sum(values, segment_ids, num_segments: int,
@@ -201,6 +262,8 @@ def _pallas_segment_sum(values, segment_ids, num_segments: int,
     # under shard_map(check_vma=True) the out_shape must declare which mesh
     # axes it varies over; the reduction output varies wherever its inputs do
     vma = vma_union(values, segment_ids)
+    counters.inc("segment_sum.dot_rows",
+                 _dot_rows(d if limbs else 0, limbs, 0 if limbs else d))
     kern = functools.partial(_kernel, block_rows=block_rows,
                              num_segments=num_segments, limbs=limbs)
     out = pl.pallas_call(
@@ -308,8 +371,9 @@ def _window_lanes(span: int) -> int:
 def _window_vmem_bytes(span: int, d: int, block_rows: int) -> int:
     """Fast-memory footprint of one window-kernel step, generously:
     double-buffered id and value row blocks (each counted at 8 sublanes),
-    the values stacked, the compare mask and its f32 and bf16 one-hots
-    ``[W, bn]``, and the double-buffered ``[d, W]`` partials."""
+    the values stacked, the compare mask and its bf16 one-hot ``[W, bn]``
+    (three copies at 4 bytes), and the double-buffered ``[d, W]``
+    partials."""
     w = _window_lanes(span)
     rows = 2 * (1 + d) * 8 * block_rows * 4
     stacked = _round_up(d, 8) * block_rows * 4
@@ -518,13 +582,15 @@ def _window_kernel(ids_ref, *refs, rows: int, block_rows: int, lanes: int,
         vals = [r[:].reshape(1, block_rows) for r in group]
         return vals[0] if len(vals) == 1 else jnp.concatenate(vals, axis=0)
 
+    # invalid rows, and garbage past the rows, have a zero one-hot column
+    # and finite limbs; pad rows (id -1) may hold any float, NaN included
+    int_sums, float_sums = _block_sums(
+        hit, limbs=limbs, ints=stacked(ins[:n_int]) if n_int else None,
+        floats=stacked(ins[n_int:]) if n_float else None, valid=valid)
     if n_int:
-        # invalid rows, and garbage past the rows, have a zero one-hot
-        # column and finite limbs
-        outs[0][:] = _limb_sums(stacked(ins[:n_int]), hit, limbs)
+        outs[0][:] = int_sums
     if n_float:
-        # pad rows (id -1) may hold any value, NaN included
-        outs[-1][:] = _float_sums(stacked(ins[n_int:]), valid, hit)
+        outs[-1][:] = float_sums
 
 
 def _window_partials(sorted_ids, int_rows, float_rows, *, block_rows: int,
@@ -545,6 +611,8 @@ def _window_partials(sorted_ids, int_rows, float_rows, *, block_rows: int,
                 (len(rows_), nblocks * lanes), dtype, vma=vma))
             out_specs.append(pl.BlockSpec((len(rows_), lanes),
                                           lambda i: (0, i)))
+    counters.inc("segment_sum.dot_rows",
+                 _dot_rows(len(int_rows), limbs, len(float_rows)))
     kern = functools.partial(
         _window_kernel, rows=n, block_rows=block_rows, lanes=lanes,
         n_int=len(int_rows), n_float=len(float_rows), limbs=limbs)

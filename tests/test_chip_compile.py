@@ -200,6 +200,8 @@ def test_segment_sum_routes_and_compiles(one_chip, groups, route, dtype, n):
         # and unpacks it in its input fusions: no unpacked row is kept
         temp = compiled.memory_analysis().temp_size_in_bytes
         assert temp < (9 if mins else 17) * n
+        if mins:
+            assert temp <= H2O_TEMP_BYTES["q5_packed"]
         return
     impl = "pallas" if route == "onehot" else "xla"
     compiled = jax.jit(
@@ -214,6 +216,28 @@ def test_segment_sum_routes_and_compiles(one_chip, groups, route, dtype, n):
         # the kernel splits limbs from the block it reads: no [4, N] limb
         # copy, nor even one int32 copy of the column
         assert temp < 4 * n
+    assert compiled.out_info.dtype == dtype
+
+
+# temp bytes of the h2o cells' kernel programs when their kernels made a
+# dot a byte limb and a HIGHEST f32 dot: one stacked bf16 dot a block
+# step may take no more
+H2O_TEMP_BYTES = {"q4_int32": 800_091_648, "q4_float32": 800_091_648,
+                  "q5_packed": 825_219_584}
+
+
+@pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32])
+def test_q4_onehot_program_compiles_within_its_temp(one_chip, dtype):
+    # q4: one column of 1e8 rows summed into 100 groups by the one-hot
+    # kernel
+    n = 100_000_000
+    compiled = jax.jit(
+        lambda v, i: segment_sum(v, i, 100, impl="pallas")).lower(
+            _spec((n, 1), dtype, one_chip),
+            _spec((n,), jnp.int32, one_chip)).compile()
+    assert "segment_sum_onehot" in compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= H2O_TEMP_BYTES[f"q4_{jnp.dtype(dtype).name}"]
     assert compiled.out_info.dtype == dtype
 
 

@@ -8,6 +8,7 @@ outputs).
 import zlib
 
 import jax
+import jax.extend.core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -361,6 +362,148 @@ def test_segment_sums_sorted_window_matches_scatter_add(case):
                 case != "nonfinite_valid_rows")
             np.testing.assert_array_equal(np.isnan(got), np.isnan(want))
             np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+
+
+def _dots(jaxpr):
+    """Every ``dot_general`` of a jaxpr, sub-jaxprs (a Pallas kernel's
+    body among them) included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(eqn)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                if isinstance(sub, jax.extend.core.ClosedJaxpr):
+                    found += _dots(sub.jaxpr)
+                elif isinstance(sub, jax.extend.core.Jaxpr):
+                    found += _dots(sub)
+    return found
+
+
+def _dot_mix(mix: str):
+    """``(fn, args, rows)`` of a mix of columns through a kernel route:
+    ``rows`` the stacked operand's height, before padding."""
+    from tensorframes_tpu.ops.segment_reduce import segment_sums, sort_key_plan
+
+    n, groups = 4096, 3000
+    rng = np.random.default_rng(zlib.crc32(mix.encode()))
+    ids = jnp.asarray(rng.integers(0, groups, n), jnp.int32)
+    window = (1024, 128)
+    if mix == "onehot_int":
+        return (lambda v, i: segment_sum(v, i, 9, impl="interpret"),
+                (jnp.arange(n, dtype=jnp.int32), ids % 9), 4)
+    if mix == "onehot_float":
+        return (lambda v, i: segment_sum(v, i, 9, impl="interpret"),
+                (jnp.ones((n, 2), jnp.float32), ids % 9), 12)
+    if mix == "window_ints":
+        cols = [jnp.ones(n, dt) for dt in (jnp.int8, jnp.int16, jnp.int32,
+                                           jnp.uint32)]
+        return (lambda i, *c: segment_sums(c, i, groups, window=window,
+                                           impl="interpret"),
+                (ids, *cols), 16)
+    if mix == "window_float":
+        return (lambda i, c: segment_sums([c], i, groups, window=window,
+                                          impl="interpret"),
+                (ids, jnp.ones(n, jnp.float32)), 6)
+    # q5: v1 and v2 packed into the sort key, v3 a payload
+    window += (sort_key_plan(groups, [(1, 5), (1, 15), None]),)
+    return (lambda i, a, b, c, m: segment_sums(
+                [a, b, c], i, groups, window=window, impl="interpret",
+                mins=m),
+            (ids, jnp.ones(n, jnp.int32), jnp.ones(n, jnp.int32),
+             jnp.ones(n, jnp.float32), jnp.asarray([1, 1], jnp.uint32)), 14)
+
+
+@pytest.mark.parametrize("mix", ["onehot_int", "onehot_float", "window_ints",
+                                 "window_float", "window_q5_packed"])
+def test_block_sums_make_one_bf16_dot(mix):
+    # each kernel's block step is one MXU dot of bf16 operands with f32
+    # accumulation, whatever the columns: int limbs, float pieces and the
+    # non-finite counts stacked as rows of one operand; each program built
+    # counts that operand's rows
+    from tensorframes_tpu.utils.tracing import counters
+
+    fn, args, rows = _dot_mix(mix)
+    before = counters.get("segment_sum.dot_rows")
+    dots = _dots(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert counters.get("segment_sum.dot_rows") - before == rows
+    assert len(dots) == 1
+    assert [v.aval.dtype for v in dots[0].invars] == [jnp.bfloat16] * 2
+    assert dots[0].params["preferred_element_type"] == jnp.float32
+    assert dots[0].params["precision"] is None
+
+
+def _split_values():
+    """Finite f32 values whose pieces never fall below the smallest
+    normal (magnitudes 0 or at least 2^-102), and values just above the
+    smallest normal, whose can."""
+    rng = np.random.default_rng(7)
+    big = np.float32(3.4028235e38)
+    pow2 = np.ldexp(np.float32(1), np.arange(-102, 128)).astype(np.float32)
+    bits = rng.integers(0, 1 << 32, 20000, dtype=np.uint64).astype(
+        np.uint32).view(np.float32)
+    bits = bits[np.isfinite(bits) & (np.abs(bits) >= 2.0 ** -102)]
+    wide = np.concatenate([
+        [big, -big, 0.0, -0.0, 1.0, -1.0], pow2, -pow2, bits,
+        rng.standard_normal(1000) * 1e6]).astype(np.float32)
+    tiny = np.finfo(np.float32).tiny
+    near = (tiny * (1 + np.arange(0, 1 << 18, 97) / (1 << 23)))
+    near = np.concatenate([near, -near]).astype(np.float32)
+    return wide, near
+
+
+def test_split3_pieces_are_exact_in_bf16_and_sum_to_the_value():
+    from tensorframes_tpu.ops.segment_reduce import _split3
+
+    wide, near = _split_values()
+    for x in (wide, near):
+        pieces = [np.asarray(p) for p in jax.jit(_split3)(jnp.asarray(x))]
+        for p in pieces:
+            assert np.isfinite(p).all()
+            normal = (p == 0) | (np.abs(p) >= np.finfo(np.float32).tiny)
+            as_bf16 = np.asarray(jnp.asarray(p[normal]).astype(jnp.bfloat16)
+                                 .astype(jnp.float32))
+            np.testing.assert_array_equal(as_bf16, p[normal])
+        b0, b1, b2 = pieces
+        if x is wide:
+            np.testing.assert_array_equal((b0 + b1) + b2, x)
+        else:
+            # where denormals flush, a piece below 2^-126 is lost: the
+            # value is kept within 2^-126
+            flushed = [np.where(np.abs(p) < np.finfo(np.float32).tiny, 0, p)
+                       for p in pieces]
+            err = np.abs(((flushed[0] + flushed[1]) + flushed[2])
+                         .astype(np.float64) - x.astype(np.float64))
+            assert (err < 2.0 ** -126).all()
+
+
+@pytest.mark.parametrize("route", ["onehot", "window"])
+def test_float_block_sums_within_f32_accumulation(route):
+    # one 4,096-row block of values over six decades: each group's sum
+    # against float64 is within f32 summation's error bound, (n - 1) u
+    # sum |x| of its n rows, and far inside the h2o cells' 1e-4 limit
+    from tensorframes_tpu.ops.segment_reduce import segment_sums
+
+    n, groups = 4096, 64
+    rng = np.random.default_rng(11)
+    x = (10.0 ** rng.uniform(-3, 3, n) * rng.choice([-1, 1], n)).astype(
+        np.float32)
+    ids = rng.integers(0, groups, n).astype(np.int32)
+    if route == "onehot":
+        got = segment_sum(jnp.asarray(x), jnp.asarray(ids), groups,
+                          impl="interpret", block_rows=n)
+    else:
+        got, = segment_sums([x], ids, groups, window=(n, 128),
+                            impl="interpret")
+    got = np.asarray(got, np.float64)
+    want = np.zeros(groups)
+    np.add.at(want, ids, x.astype(np.float64))
+    mass = np.zeros(groups)
+    np.add.at(mass, ids, np.abs(x.astype(np.float64)))
+    rows = np.bincount(ids, minlength=groups)
+    u = 2.0 ** -24
+    assert (np.abs(got - want) <= (rows - 1) * u * mass).all()
+    assert (np.abs(got - want) <= 1e-5 * mass).all()
 
 
 class TestShardMapVma:
